@@ -18,11 +18,8 @@ def _cpu_destined() -> bool:
     or jax config) — the only case the timeout mutation below targets."""
     if "cpu" in _os.environ.get("JAX_PLATFORMS", ""):
         return True
-    try:
-        import jax as _j
-        return "cpu" in (_j.config.jax_platforms or "")
-    except Exception:  # noqa: BLE001 — unknown platform: leave flags alone
-        return False
+    import jax as _j
+    return "cpu" in (_j.config.jax_platforms or "")
 
 
 # XLA:CPU aborts the process when a collective participant waits >40 s
@@ -34,20 +31,11 @@ def _cpu_destined() -> bool:
 # backend initialises, hence at import — and only for cpu-destined
 # processes, so a TPU job's (or an embedding application's) environment
 # is never mutated behind its back.  The injection itself lives in
-# runtime.xla_flags (the one site allowed to mutate XLA_FLAGS) and is
-# GATED on jaxlib version: builds that predate the flags treat them as
-# fatal unknown flags and abort at first backend init.
+# runtime.xla_flags (the one site allowed to mutate XLA_FLAGS).
 from dislib_tpu.runtime import xla_flags as _xla_flags
 
 if _cpu_destined():
     _xla_flags.inject_cpu_collective_timeouts()
-
-# API-drift shims (jax.shard_map alias on older jaxlibs) — a preempted job
-# may resume on a host imaged with a different toolchain, so importability
-# across jax versions is part of the resilience contract
-from dislib_tpu.runtime.compat import ensure_jax_compat as _ensure_jax_compat
-
-_ensure_jax_compat()
 
 from dislib_tpu.parallel.mesh import init, get_mesh, set_mesh
 from dislib_tpu.data.array import (

@@ -31,8 +31,8 @@ TPU-native redesign — NOT a block-of-futures translation:
   expression (:class:`_LazyExpr`); the first host-forcing access
   (``collect()``, ``force()``, any internal ``_data`` read, ``float()``,
   a snapshot fetch) compiles and runs the WHOLE chain as ONE cached XLA
-  program (``_exec_program``).  On a backend whose per-dispatch host RTT
-  is ~70 ms (BENCH_local_r05), a k-op chain costs one RTT instead of k.
+  program (``_exec_program``): a k-op chain costs one dispatch round
+  trip instead of k.
   ``DSLIB_EAGER=1`` restores per-op dispatch for debugging, and chains
   force themselves after ``DSLIB_FUSION_CAP`` nodes (default 96) so a
   long Python loop cannot build an unboundedly large program.  Fused and
@@ -533,7 +533,14 @@ class Array:
         q = _mesh.pad_quantum()
         pshape = _padded_shape(shape, q)
         if tuple(shape) != pshape:
-            data = _place(data, pshape, tuple(shape))
+            if isinstance(data, np.ndarray):
+                # host data pads on the HOST, so each device receives only
+                # its own shard below — never the whole operand (and its
+                # padded copy) staged on the default device first
+                data = np.pad(data, [(0, p - s)
+                                     for p, s in zip(pshape, shape)])
+            else:
+                data = _place(data, pshape, tuple(shape))
         data = jax.device_put(data, _mesh.data_sharding())
         return cls(data, shape, reg_shape=reg_shape, sparse=sparse)
 
@@ -976,18 +983,16 @@ def array(x, block_size=None, dtype=None) -> Array:
     sparse = sp.issparse(x)
     if sparse:
         x = x.toarray()
-    on_device = isinstance(x, jax.Array)
-    if not on_device:
+    if not isinstance(x, jax.Array):
         x = np.asarray(x)
     if x.ndim == 1:
         x = x.reshape(1, -1)
     if x.ndim != 2:
         raise ValueError("ds-arrays are 2-dimensional")
-    if on_device:
-        # device input: same dtype policy, applied without a host round-trip
-        x = _coerce_dtype(x, dtype)
-    else:
-        x = jnp.asarray(_coerce_dtype(x, dtype))
+    # one dtype policy for both inputs: a device array is coerced without
+    # a host round-trip, and a host array STAYS on the host until
+    # `_from_logical` hands its shards to their devices
+    x = _coerce_dtype(x, dtype)
     if block_size is None:
         block_size = _default_block_size(x.shape, None)
     block_size = _check_block_size(x.shape, block_size)
@@ -1047,16 +1052,28 @@ def random_array(shape, block_size=None, random_state=None,
     q = _mesh.pad_quantum()
     pshape = _padded_shape(shape, q)
     data = _random_uniform(jax.random.PRNGKey(seed), pshape,
-                           tuple(int(s) for s in shape), np.dtype(dtype).name)
-    data = jax.device_put(data, _mesh.data_sharding())
-    return Array(data, shape, reg_shape=block_size)
+                           tuple(int(s) for s in shape), np.dtype(dtype).name,
+                           _mesh.data_sharding())
+    return Array(_canonical(data), shape, reg_shape=block_size)
 
 
-@partial(_pjit, static_argnames=("pshape", "shape", "dtype"),
+# Device-generated constructors take the target sharding as a jit STATIC
+# and constrain their result to it, so every device produces only its own
+# shard (the mesh rides the cache key: a re-init retraces, never replays a
+# stale layout) — the whole operand never sits on the default device.
+
+def _canonical(data):
+    """Re-label an already canonically laid-out result with THE canonical
+    sharding object: jit hands back an equivalent but normalized spec, and
+    `ensure_canonical` compares shardings by equality.  Moves no data."""
+    return jax.device_put(data, _mesh.data_sharding())
+
+
+@partial(_pjit, static_argnames=("pshape", "shape", "dtype", "sharding"),
          name="random_uniform")
-def _random_uniform(key, pshape, shape, dtype):
+def _random_uniform(key, pshape, shape, dtype, sharding):
     vals = jax.random.uniform(key, pshape, dtype=dtype)
-    return _zero_pad(vals, shape)
+    return lax.with_sharding_constraint(_zero_pad(vals, shape), sharding)
 
 
 def _seed_from(random_state):
@@ -1071,24 +1088,23 @@ def _seed_from(random_state):
 
 def zeros(shape, block_size=None, dtype=jnp.float32) -> Array:
     """All-zeros ds-array (reference: ds.zeros)."""
-    q = _mesh.pad_quantum()
-    pshape = _padded_shape(shape, q)
-    data = jax.device_put(jnp.zeros(pshape, dtype), _mesh.data_sharding())
-    return Array(data, shape, reg_shape=block_size)
+    return full(shape, 0.0, block_size, dtype)
 
 
 def full(shape, fill_value, block_size=None, dtype=jnp.float32) -> Array:
     """Constant-filled ds-array (reference: ds.full)."""
     q = _mesh.pad_quantum()
     pshape = _padded_shape(shape, q)
-    data = _full_op(pshape, tuple(int(s) for s in shape), float(fill_value), dtype)
-    data = jax.device_put(data, _mesh.data_sharding())
-    return Array(data, shape, reg_shape=block_size)
+    data = _full_op(pshape, tuple(int(s) for s in shape), float(fill_value),
+                    dtype, _mesh.data_sharding())
+    return Array(_canonical(data), shape, reg_shape=block_size)
 
 
-@partial(_pjit, static_argnames=("pshape", "shape", "dtype"), name="full")
-def _full_op(pshape, shape, fill_value, dtype):
-    return _zero_pad(jnp.full(pshape, fill_value, dtype), shape)
+@partial(_pjit, static_argnames=("pshape", "shape", "dtype", "sharding"),
+         name="full")
+def _full_op(pshape, shape, fill_value, dtype, sharding):
+    return lax.with_sharding_constraint(
+        _zero_pad(jnp.full(pshape, fill_value, dtype), shape), sharding)
 
 
 def ones(shape, block_size=None, dtype=jnp.float32) -> Array:
@@ -1106,15 +1122,18 @@ def eye(n, m=None, block_size=None, dtype=jnp.float32) -> Array:
     m = n if m is None else m
     q = _mesh.pad_quantum()
     pshape = _padded_shape((n, m), q)
-    data = jax.device_put(_eye_op(pshape, (int(n), int(m)), dtype), _mesh.data_sharding())
-    return Array(data, (n, m), reg_shape=block_size)
+    data = _eye_op(pshape, (int(n), int(m)), dtype, _mesh.data_sharding())
+    return Array(_canonical(data), (n, m), reg_shape=block_size)
 
 
-@partial(_pjit, static_argnames=("pshape", "shape", "dtype"), name="eye")
-def _eye_op(pshape, shape, dtype):
+@partial(_pjit, static_argnames=("pshape", "shape", "dtype", "sharding"),
+         name="eye")
+def _eye_op(pshape, shape, dtype, sharding):
     r = lax.broadcasted_iota(jnp.int32, pshape, 0)
     c = lax.broadcasted_iota(jnp.int32, pshape, 1)
-    return jnp.where((r == c) & (r < min(shape)), jnp.ones((), dtype), jnp.zeros((), dtype))
+    return lax.with_sharding_constraint(
+        jnp.where((r == c) & (r < min(shape)), jnp.ones((), dtype),
+                  jnp.zeros((), dtype)), sharding)
 
 
 def rechunk(x: Array, new_blocks=None, mesh=None, *, schedule="auto",

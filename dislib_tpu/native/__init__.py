@@ -6,6 +6,7 @@ edit triggers a rebuild).  Every entry point returns None / raises
 `NativeUnavailable` cleanly when the toolchain or the parse is unusable, and
 callers in `dislib_tpu.data.io` fall back to the pure-NumPy path — the
 native layer is a performance component, never a correctness dependency.
+Why a build or load failed is kept: :func:`build_error` returns it.
 
 Set ``DSLIB_NO_NATIVE=1`` to disable entirely (forces the NumPy paths).
 """
@@ -25,6 +26,7 @@ _SRC = os.path.join(_HERE, "fastio.cpp")
 _lock = threading.Lock()
 _lib = None
 _tried = False
+_build_error: str | None = None
 
 
 class NativeUnavailable(RuntimeError):
@@ -61,8 +63,10 @@ def _build_and_load():
 
 
 def get_lib():
-    """The loaded native library, or None if unavailable/disabled."""
-    global _lib, _tried
+    """The loaded native library, or None if unavailable/disabled (the
+    NumPy parsers are the documented alternative; :func:`build_error`
+    says why the native one is absent)."""
+    global _lib, _tried, _build_error
     if os.environ.get("DSLIB_NO_NATIVE"):
         return None
     with _lock:
@@ -70,9 +74,22 @@ def get_lib():
             _tried = True
             try:
                 _lib = _build_and_load()
-            except Exception:          # no toolchain / build failure → fallback
-                _lib = None
+            except subprocess.CalledProcessError as e:   # g++ rejected it
+                tail = (e.stderr or b"").decode(errors="replace")[-400:]
+                _build_error = f"g++ exited {e.returncode}: {tail.strip()}"
+            except (OSError, subprocess.TimeoutExpired,
+                    AttributeError) as e:
+                # no g++ / unwritable dir / unloadable .so / build past
+                # 120 s / a .so missing an entry point
+                _build_error = f"{type(e).__name__}: {e}"
     return _lib
+
+
+def build_error() -> str | None:
+    """Why the native library is absent after :func:`get_lib` returned
+    None (``None`` while it loaded, was never tried, or is disabled by
+    ``DSLIB_NO_NATIVE``)."""
+    return _build_error
 
 
 def _take(lib, ptr, count, dtype):

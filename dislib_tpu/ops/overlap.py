@@ -40,9 +40,10 @@ Routing (``DSLIB_OVERLAP``, the ``DSLIB_MATMUL_ALGO`` pattern): ``db``
 double-buffered with the hot inner compute (SUMMA's panel GEMM, the ring
 ε-pass ``distances_sq``) lowered through a Pallas kernel
 (``ops/pallas_kernels``) — for backends where XLA refuses to schedule
-the overlap out of the plain HLO.  ``pallas`` degrades to ``db`` with a
-warning when the backend can't run Pallas; ``seq`` is always available.
-The resolved schedule threads through every kernel as a jit STATIC, so
+the overlap out of the plain HLO.  A requested schedule is the schedule
+that runs: on a TPU a Pallas kernel Mosaic refuses is an error at the
+call site, never a quiet switch to another schedule.  The resolved
+schedule threads through every kernel as a jit STATIC, so
 flipping the env var retraces instead of being silently ignored (the
 precision-policy contract).
 """
@@ -50,7 +51,6 @@ precision-policy contract).
 from __future__ import annotations
 
 import os
-import warnings
 
 from jax import lax
 
@@ -67,10 +67,9 @@ _ALIASES = {
 def resolve(explicit=None) -> str:
     """The overlap-schedule routing rule: an explicit value wins,
     otherwise ``DSLIB_OVERLAP``, otherwise the double-buffered default.
-    Returns a canonical schedule name from :data:`SCHEDULES`; ``pallas``
-    falls back to ``db`` (with a one-time warning) when the backend
-    can't run the Pallas kernels — the sequential schedule never routes
-    implicitly: it is the explicit opt-out."""
+    Returns a canonical schedule name from :data:`SCHEDULES` — the
+    sequential schedule never routes implicitly: it is the explicit
+    opt-out."""
     raw = explicit if explicit is not None \
         else os.environ.get("DSLIB_OVERLAP", "db")
     key = _ALIASES.get(str(raw).lower())
@@ -78,32 +77,7 @@ def resolve(explicit=None) -> str:
         raise ValueError(
             f"unknown overlap schedule {raw!r}: expected one of "
             f"{SCHEDULES} (DSLIB_OVERLAP accepts the same values)")
-    if key == "pallas":
-        from dislib_tpu.ops import pallas_kernels as _pk
-        if not _pk.available():
-            _warn_pallas_unavailable()
-            return "db"
     return key
-
-
-# pallas-degradation dedupe registry (the ``__warningregistry__`` shape:
-# one key per distinct warning).  Every dispatch site funnels through
-# :func:`resolve` with a DIFFERENT caller frame, so stacklevel-keyed
-# registry entries — or no dedupe at all — would fire once per site per
-# filter reset; this module-owned registry makes it exactly once per
-# process, independent of the active warning filters.  Tests clear it to
-# re-observe the warning (pinned in tests/test_overlap).
-_WARN_REGISTRY: dict = {}
-
-
-def _warn_pallas_unavailable():
-    if "pallas_unavailable" in _WARN_REGISTRY:
-        return
-    _WARN_REGISTRY["pallas_unavailable"] = 1
-    warnings.warn(
-        "DSLIB_OVERLAP=pallas requested but the backend can't run the "
-        "Pallas kernels — falling back to the double-buffered XLA "
-        "schedule ('db')", RuntimeWarning, stacklevel=3)
 
 
 def overlapped(schedule: str) -> bool:

@@ -1,24 +1,39 @@
-"""Pallas fallback kernels for the overlap schedules' hot inner loops.
+"""Pallas kernels for the overlap schedules' hot inner loops.
 
 ``DSLIB_OVERLAP=pallas`` routes the two FLOP-dominant inner computations
 of the panel pipelines — SUMMA's per-panel GEMM and the ring ε-pass's
-``distances_sq`` — through explicit Pallas kernels instead of plain HLO.
-The escape hatch exists for backends where XLA's scheduler refuses to
-hide the panel collective under the previous panel's compute (verified
-by the compiled-HLO audit in ``tests/test_overlap``): a Pallas call is
-an opaque compute region the latency-hiding scheduler treats as one
-unit, so the pipelined loop's independent collective can slide past it.
+``distances_sq`` — through explicit Pallas kernels instead of plain HLO,
+and the forest fit's level histogram through a one-hot GEMM.  A Pallas
+call is an opaque compute region the latency-hiding scheduler treats as
+one unit, so the pipelined loop's independent collective can slide past
+it.
 
 Contract (mirrors ``ops/precision``): operands are rounded to the
 policy's compute dtype, contractions accumulate in the policy's
 accumulation dtype, outputs match what the plain-HLO path produces — the
 Pallas route changes the SCHEDULE, not the numerics contract (values are
 allclose-tested, not bit-tested: a different GEMM tiling reassociates
-sums).  On non-TPU backends the kernels run in Pallas interpret mode —
-semantically identical, which keeps the whole router testable on the CPU
-rig; :func:`available` probes the backend once and the overlap router
-degrades ``pallas`` → ``db`` (with a warning) when the probe fails, so
-the sequential and double-buffered XLA schedules are always available.
+sums).
+
+Every kernel tiles ALL of its operands into aligned VMEM blocks (sublane
+multiples of the dtype's packing, lane multiples of 128) and grids the
+contraction, zero-padding ragged dims in the wrapper and cropping the
+result, so the block shapes do not depend on the caller's shard sizes.
+Outputs carry the operands' varying-mesh-axes (``vma``), so the kernels
+run inside the callers' ``shard_map(check_vma=True)``.  Two idioms keep
+the INTERPRETER green under that check (Mosaic does not re-check kernel
+bodies): each output tile is seeded from an aliased zero operand rather
+than zeroed in the kernel, because the interpreter would otherwise start
+the output as a mesh-invariant value; and every kernel body computes
+inside a ``pl.when`` branch (:func:`_accumulate`), because the
+interpreter type-checks top-level kernel ops against the mesh — where a
+constant or a value leaving a branch counts as mesh-invariant and may
+not meet a varying block — but evaluates a branch whole.  On a TPU the
+kernels are compiled by Mosaic and a kernel Mosaic refuses is an error at
+the call site (the kernel's ``name`` is in the message) — there is no
+probe and no fallback schedule.  Everywhere else they run in Pallas
+interpret mode, semantically identical, which keeps the router testable
+on the CPU rig.
 
 Kernels keep the library's precision-lint contract: no hardcoded compute
 dtypes — every cast routes through ``ops/precision`` or derives from a
@@ -29,12 +44,18 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
 
 from dislib_tpu.ops import precision as px
 
-# grid tile target for the row-tiled kernels: MXU-friendly on chip, and
-# a no-op cap for the small interpreted blocks on host rigs
-_TILE_ROWS = 128
+# VMEM block edges: rows/cols of an output tile and the contraction step.
+# 256x256 out + two 256x512 operand tiles, double-buffered, stay a few MiB
+# — far inside the v5e's 16 MiB scoped-VMEM default at every dtype.
+_BM = 256
+_BN = 256
+_BK = 512
+_LANES = 128
 
 
 def _interpret() -> bool:
@@ -43,120 +64,175 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-_AVAILABLE: bool | None = None
+def _round_up(x: int, q: int) -> int:
+    return -(-x // q) * q
 
 
-def available() -> bool:
-    """One cached probe: can this process run a Pallas kernel at all?
-    (Import failure, an old jaxlib, or a backend without interpret
-    support all land here as False — the overlap router then degrades
-    ``pallas`` to the plain double-buffered schedule.)"""
-    global _AVAILABLE
-    if _AVAILABLE is None:
-        try:
-            import numpy as np
-            x = jnp.ones((8, 4), px.compute_dtype(px.FLOAT32))
-            out = panel_gemm(x, x.T, px.FLOAT32)
-            _AVAILABLE = bool(abs(float(np.asarray(out)[0, 0]) - 4.0) < 1e-6)
-        except Exception:  # noqa: BLE001 — any failure means "not here"
-            _AVAILABLE = False
-    return _AVAILABLE
+def _sublanes(dtype) -> int:
+    """Rows per packed vreg tile: 8 for 4-byte, 16 for 2-byte, 32 for
+    1-byte dtypes (8-byte x64 dtypes only ever run interpreted)."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
 
 
-def _row_block(m: int) -> int:
-    """Largest divisor of ``m`` ≤ the tile target (grid blocks must tile
-    the row dim exactly; padded dims are quantum multiples, so this is
-    almost always the target itself)."""
-    for b in range(min(m, _TILE_ROWS), 0, -1):
-        if m % b == 0:
-            return b
-    return m
+def _block(dim: int, target: int, quantum: int) -> int:
+    """Block edge for a dim: the target, or the whole (quantum-rounded)
+    dim when it is smaller.  The wrapper pads the dim to a multiple."""
+    return min(target, _round_up(dim, quantum))
+
+
+def _pad2(x, rows: int, cols: int):
+    pr, pc = rows - x.shape[0], cols - x.shape[1]
+    return x if not (pr or pc) else jnp.pad(x, ((0, pr), (0, pc)))
+
+
+def _vary_alike(*operands):
+    """The operands, each cast to vary over the union of all their
+    varying mesh axes (a no-op outside ``shard_map``): the kernel body
+    mixes them, and under ``check_vma=True`` mixed values must agree —
+    SUMMA hands a rows-varying A panel and a cols-varying B panel."""
+    union = frozenset().union(*(jax.typeof(o).vma for o in operands))
+    return tuple(
+        o if jax.typeof(o).vma == union else
+        lax.pcast(o, tuple(sorted(union - jax.typeof(o).vma)), to="varying")
+        for o in operands)
+
+
+def _accumulator(shape, dtype, like):
+    """(zeros, out_shape) for an accumulating kernel: the output aliases
+    the zero operand, so it starts out varying over the same mesh axes as
+    ``like`` (none outside ``shard_map``) on the interpreter too, where
+    a fresh output buffer would be mesh-invariant and fail
+    ``check_vma=True`` at the first accumulate."""
+    zeros, _ = _vary_alike(jnp.zeros(shape, dtype), like)
+    return zeros, jax.ShapeDtypeStruct(shape, dtype,
+                                       vma=jax.typeof(like).vma)
+
+
+def _accumulate(o_ref, z_ref, t, term):
+    """``o += term()`` over the contraction grid axis ``t``, seeding the
+    resident output tile from the aliased zero tile on the first step.
+    ``term`` is a thunk so the whole computation is traced INSIDE each
+    ``pl.when`` arm (see the module docstring for why); only one arm
+    runs per grid step."""
+    @pl.when(t == 0)
+    def _first():
+        o_ref[...] = z_ref[...] + term()
+
+    @pl.when(t != 0)
+    def _rest():
+        o_ref[...] += term()
 
 
 def panel_gemm(a, b, policy=px.FLOAT32):
-    """``A @ B`` as a row-tiled Pallas kernel — the SUMMA panel GEMM.
+    """``A @ B`` as a (row, col, contraction)-tiled Pallas kernel — the
+    SUMMA panel GEMM.
 
     Same numerics contract as :func:`ops.precision.pdot`: operands round
     to the policy compute dtype, the contraction accumulates in the
     policy accumulation dtype (promoted for x64-mode f64 operands under
     the float32-floor policy), output is the accumulation dtype."""
-    from jax.experimental import pallas as pl
-
-    a = px.to_compute(a, policy)
-    b = px.to_compute(b, policy)
+    a, b = _vary_alike(px.to_compute(a, policy), px.to_compute(b, policy))
     acc_dt = jnp.promote_types(px.accum_dtype(policy),
                                jnp.promote_types(a.dtype, b.dtype))
     m, k = a.shape
     _, n = b.shape
-    bm = _row_block(m)
+    bm = _block(m, _BM, max(_sublanes(a.dtype), _sublanes(acc_dt)))
+    bn = _block(n, _BN, _LANES)
+    bk = _block(k, _BK, _LANES)
+    mp, np_, kp = _round_up(m, bm), _round_up(n, bn), _round_up(k, bk)
 
-    def kern(a_ref, b_ref, o_ref):
-        # pdot's MXU-precision guarantee must survive the Pallas route —
-        # without the explicit precision a f32 FLOAT32-policy call
-        # outside a `precise` scope would run the backend default
-        o_ref[:, :] = jnp.dot(a_ref[:, :], b_ref[:, :],
-                              preferred_element_type=acc_dt,
-                              precision=policy.dot_precision)
+    def kern(z_ref, a_ref, b_ref, o_ref):
+        # the output tile stays resident across the contraction axis
+        # (its index map ignores it).  pdot's MXU-precision guarantee
+        # must survive the Pallas route — without the explicit precision
+        # a f32 FLOAT32-policy call outside a `precise` scope would run
+        # the backend default
+        _accumulate(o_ref, z_ref, pl.program_id(2),
+                    lambda: jnp.dot(a_ref[...], b_ref[...],
+                                    preferred_element_type=acc_dt,
+                                    precision=policy.dot_precision))
 
-    return pl.pallas_call(
+    zeros, out_shape = _accumulator((mp, np_), acc_dt, a)
+    out = pl.pallas_call(
         kern,
-        grid=(m // bm,),
-        in_specs=[pl.BlockSpec((bm, k), lambda i: (i, 0)),
-                  pl.BlockSpec((k, n), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((bm, n), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, n), acc_dt),
+        grid=(mp // bm, np_ // bn, kp // bk),
+        in_specs=[pl.BlockSpec((bm, bn), lambda i, j, t: (i, j)),
+                  pl.BlockSpec((bm, bk), lambda i, j, t: (i, t)),
+                  pl.BlockSpec((bk, bn), lambda i, j, t: (t, j))],
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, t: (i, j)),
+        out_shape=out_shape,
+        input_output_aliases={0: 0},
         interpret=_interpret(),
-    )(a, b)
+        name="dslib_panel_gemm",
+    )(zeros, _pad2(a, mp, kp), _pad2(b, kp, np_))
+    return out[:m, :n]
 
 
 def distances_sq(a, b, precision=None):
-    """Pairwise squared euclidean distances as a row-tiled Pallas kernel
-    — the ring/tiled ε-pass inner loop (``ops/base.distances_sq``'s
+    """Pairwise squared euclidean distances as a tiled Pallas kernel —
+    the ring/tiled ε-pass inner loop (``ops/base.distances_sq``'s
     ‖a‖² − 2a·bᵀ + ‖b‖² formulation, clamped at zero against
-    cancellation).  Output dtype matches the plain-HLO path (the
-    operands' promoted float dtype); ``precision`` threads to the cross
-    GEMM exactly as the plain path threads it to ``jnp.matmul`` — the
-    Pallas route must not silently drop a caller's explicit MXU
-    precision (``None`` inherits the enclosing scope, as there)."""
-    from jax.experimental import pallas as pl
-
+    cancellation).  The cross GEMM and the combine run in the kernel;
+    the row norms are one cheap elementwise pass outside it.  Output
+    dtype matches the plain-HLO path (the operands' promoted float
+    dtype); ``precision`` threads to the cross GEMM exactly as the plain
+    path threads it to ``jnp.matmul`` (``None`` inherits the enclosing
+    scope at trace time, as there)."""
     out_dt = jnp.promote_types(a.dtype, b.dtype)
-    m, _ = a.shape
-    kf, d = b.shape
-    bm = _row_block(m)
+    a, b = _vary_alike(a.astype(out_dt), b.astype(out_dt))
+    m, d = a.shape
+    kf, _ = b.shape
+    bm = _block(m, _BM, _sublanes(out_dt))
+    bn = _block(kf, _BN, _LANES)
+    bk = _block(d, _BK, _LANES)
+    mp, np_, kp = _round_up(m, bm), _round_up(kf, bn), _round_up(d, bk)
+    a_sq = jnp.sum(a * a, axis=1, keepdims=True)          # (m, 1)
+    b_sq = jnp.sum(b * b, axis=1)[None, :]                # (1, kf)
+    last = kp // bk - 1
 
-    def kern(a_ref, b_ref, o_ref):
-        av = a_ref[:, :]
-        bv = b_ref[:, :]
-        cross = jnp.dot(av, bv.T, preferred_element_type=out_dt,
-                        precision=precision)
-        a_sq = jnp.sum(av * av, axis=1, keepdims=True)
-        b_sq = jnp.sum(bv * bv, axis=1)
-        o_ref[:, :] = jnp.maximum(a_sq - 2.0 * cross + b_sq[None, :],
-                                  jnp.zeros((), out_dt))
+    def kern(z_ref, a_ref, b_ref, asq_ref, bsq_ref, o_ref):
+        t = pl.program_id(2)
+        # a·bᵀ: contract the feature (lane) dim of both tiles
+        _accumulate(o_ref, z_ref, t, lambda: lax.dot_general(
+            a_ref[...], b_ref[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=out_dt, precision=precision))
 
-    return pl.pallas_call(
+        @pl.when(t == last)
+        def _combine():
+            o_ref[...] = jnp.maximum(
+                asq_ref[...] - 2.0 * o_ref[...] + bsq_ref[...],
+                jnp.zeros((), out_dt))
+
+    zeros, out_shape = _accumulator((mp, np_), out_dt, a)
+    out = pl.pallas_call(
         kern,
-        grid=(m // bm,),
-        in_specs=[pl.BlockSpec((bm, d), lambda i: (i, 0)),
-                  pl.BlockSpec((kf, d), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((bm, kf), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, kf), out_dt),
+        grid=(mp // bm, np_ // bn, kp // bk),
+        in_specs=[pl.BlockSpec((bm, bn), lambda i, j, t: (i, j)),
+                  pl.BlockSpec((bm, bk), lambda i, j, t: (i, t)),
+                  pl.BlockSpec((bn, bk), lambda i, j, t: (j, t)),
+                  pl.BlockSpec((bm, 1), lambda i, j, t: (i, 0)),
+                  pl.BlockSpec((1, bn), lambda i, j, t: (0, j))],
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, t: (i, j)),
+        out_shape=out_shape,
+        input_output_aliases={0: 0},
         interpret=_interpret(),
-    )(a, b)
+        name="dslib_distances_sq",
+    )(zeros, _pad2(a, mp, kp), _pad2(b, np_, kp),
+      _pad2(a_sq, mp, 1), _pad2(b_sq, 1, np_))
+    return out[:m, :kf]
 
 
 def node_histogram(node, bx, contrib, n_nodes, n_bins, policy=px.FLOAT32):
     """The tree level's (node, feature, bin) weighted histogram as a
-    row-tiled Pallas kernel — the forest fit's scatter-shaped hot loop
+    tiled Pallas kernel — the forest fit's scatter-shaped hot loop
     (``trees/decision_tree._node_histogram``) re-expressed as an MXU
     contraction: per feature, the (node, bin) scatter index one-hot
-    encodes into a (rows, n_nodes·n_bins) matrix whose transpose-GEMM
-    against the per-sample stats IS the histogram.  XLA schedules the
-    scatter as a serialized loop; the one-hot GEMM is dense MXU work
-    with a (feature, row-tile) grid, the output block revisited across
-    row tiles (zero-init at tile 0) so each feature's histogram
-    accumulates in-register.
+    encodes against a block of histogram columns, and the per-sample
+    stats contracted with that one-hot over the samples IS the
+    histogram.  Samples sit on the lane axis throughout (index rows
+    ``(1, bm)``, stats ``(S, bm)``), so no block is narrower than a
+    lane tile; the grid is (feature, column block, row tile) with the
+    output block resident across row tiles (zero-init at tile 0).
 
     ``node`` (m,) int32, ``bx`` (m, n) int32 bin ids, ``contrib``
     (m, S) per-sample weighted stats (w·stats — computed by the caller
@@ -166,62 +242,52 @@ def node_histogram(node, bx, contrib, n_nodes, n_bins, policy=px.FLOAT32):
     stats.  With integer-representable contributions (Poisson-weight ×
     count stats — the forest's actual regime) the sums are exact, so
     this route is BIT-equal to the XLA scatter, not merely allclose."""
-    from jax.experimental import pallas as pl
-
-    contrib = px.to_compute(contrib, policy)
+    node, bx, contrib = _vary_alike(node, bx,
+                                    px.to_compute(contrib, policy))
     acc_dt = jnp.promote_types(px.accum_dtype(policy), contrib.dtype)
     m, n = bx.shape
     s = contrib.shape[1]
     nb = int(n_nodes) * int(n_bins)
-    bm = _row_block(m)
+    sp = _round_up(s, max(_sublanes(contrib.dtype), _sublanes(acc_dt)))
+    bm = _block(m, _BK, _LANES)          # samples: the contraction axis
+    bc = _block(nb, _BN, _LANES)         # histogram columns
+    mp, nbp = _round_up(m, bm), _round_up(nb, bc)
 
-    def kern(n_ref, b_ref, c_ref, o_ref):
-        i = pl.program_id(1)
+    def kern(z_ref, n_ref, b_ref, c_ref, o_ref):
+        j = pl.program_id(1)
 
-        @pl.when(i == 0)
-        def _init():
-            o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+        def partial_hist():
+            idx = n_ref[...] * n_bins + b_ref[...]          # (1, bm)
+            col = lax.broadcasted_iota(jnp.int32, (bc, bm), 0) + j * bc
+            c = c_ref[...]
+            onehot_t = jnp.where(col == idx, jnp.ones((), c.dtype),
+                                 jnp.zeros((), c.dtype))    # (bc, bm)
+            # (sp, bm) · (bc, bm)ᵀ: contract the sample (lane) dim of both
+            return lax.dot_general(
+                c, onehot_t, (((1,), (1,)), ((), ())),
+                preferred_element_type=acc_dt,
+                precision=policy.dot_precision)
 
-        idx = n_ref[:] * n_bins + b_ref[:, 0]               # (bm,)
-        onehot = (idx[:, None] == jax.lax.broadcasted_iota(
-            jnp.int32, (bm, nb), 1)).astype(acc_dt)
-        o_ref[0, :, :] += jnp.dot(onehot.T, c_ref[:, :],
-                                  preferred_element_type=acc_dt,
-                                  precision=policy.dot_precision)
+        _accumulate(o_ref, z_ref, pl.program_id(2), partial_hist)
 
+    # pad samples carry zero stats, so whatever column they index adds 0
+    node_r = _pad2(node[None, :], 1, mp)                    # (1, mp)
+    bx_r = jnp.pad(bx.T, ((0, 0), (0, mp - m)))[:, None, :]  # (n, 1, mp)
+    c_t = _pad2(contrib.T, sp, mp)                          # (sp, mp)
+    zeros, out_shape = _accumulator((n, sp, nbp), acc_dt, contrib)
     out = pl.pallas_call(
         kern,
-        grid=(n, m // bm),
-        in_specs=[pl.BlockSpec((bm,), lambda f, i: (i,)),
-                  pl.BlockSpec((bm, 1), lambda f, i: (i, f)),
-                  pl.BlockSpec((bm, s), lambda f, i: (i, 0))],
-        out_specs=pl.BlockSpec((1, nb, s), lambda f, i: (f, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, nb, s), acc_dt),
+        grid=(n, nbp // bc, mp // bm),
+        in_specs=[pl.BlockSpec((None, sp, bc), lambda f, j, i: (f, 0, j)),
+                  pl.BlockSpec((1, bm), lambda f, j, i: (0, i)),
+                  pl.BlockSpec((None, 1, bm), lambda f, j, i: (f, 0, i)),
+                  pl.BlockSpec((sp, bm), lambda f, j, i: (0, i))],
+        out_specs=pl.BlockSpec((None, sp, bc), lambda f, j, i: (f, 0, j)),
+        out_shape=out_shape,
+        input_output_aliases={0: 0},
         interpret=_interpret(),
-    )(node, bx, contrib)
-    # (n, n_nodes·n_bins, S) → the scatter path's (n_nodes, n, n_bins, S)
-    return out.reshape(n, n_nodes, n_bins, s).transpose(1, 0, 2, 3)
-
-
-_HIST_AVAILABLE: bool | None = None
-
-
-def hist_available() -> bool:
-    """Cached probe for the histogram kernel specifically: its grid /
-    block shapes (tiny lane dims, 1-D blocks) stress different Mosaic
-    paths than :func:`panel_gemm`, so the forest router probes THIS
-    kernel before trusting it — a failure degrades the fit to the XLA
-    scatter, never to a crash mid-growth."""
-    global _HIST_AVAILABLE
-    if _HIST_AVAILABLE is None:
-        try:
-            import numpy as np
-            node = jnp.asarray([0, 0, 1, 1, 1, 0, 1, 0], jnp.int32)
-            bx = jnp.asarray(np.arange(8, dtype=np.int32)[:, None] % 2)
-            contrib = jnp.ones((8, 1), px.compute_dtype(px.FLOAT32))
-            out = np.asarray(node_histogram(node, bx, contrib, 2, 2))
-            _HIST_AVAILABLE = bool(out.shape == (2, 1, 2, 1)
-                                   and abs(float(out.sum()) - 8.0) < 1e-6)
-        except Exception:  # noqa: BLE001 — any failure means "not here"
-            _HIST_AVAILABLE = False
-    return _HIST_AVAILABLE
+        name="dslib_node_histogram",
+    )(zeros, node_r, bx_r, c_t)
+    # (n, S, n_nodes·n_bins) → the scatter path's (n_nodes, n, n_bins, S)
+    return out[:, :s, :nb].reshape(n, s, n_nodes, n_bins) \
+        .transpose(2, 0, 3, 1)

@@ -46,7 +46,10 @@ def neigh_count_min(xv, eps2, vals, colmask, sentinel, tile,
     ``ops/pallas_kernels`` (the ``DSLIB_OVERLAP=pallas`` inner-loop
     route; a jit static for the enclosing kernel — the single-device
     tier has no collective to overlap, so this is the only knob that
-    applies to it)."""
+    applies to it).  Single-device means it: this tier calls the kernel
+    outside any ``shard_map``, and on a TPU mesh with more than one
+    device XLA refuses to partition a Mosaic kernel (an error, by
+    design — the multi-row tier is the ring)."""
     mp, n = xv.shape
     nt = mp // tile
     x_tiles = xv.reshape(nt, tile, n)

@@ -538,9 +538,13 @@ def _als_fit_sparse(data, lrows, cols, counts, tdata, tlrows, tcols, tcounts,
                 cnt_ = jax.ops.segment_sum(wc, sc, num_segments=nseg)
                 return (acc[0] + a, acc[1] + b, acc[2] + cnt_), None
 
-            acc0 = (jnp.zeros((nseg, n_f * n_f), d_e.dtype),
-                    jnp.zeros((nseg, n_f), d_e.dtype),
-                    jnp.zeros((nseg,), d_e.dtype))
+            # both half-steps add shard-local terms (the psum comes after
+            # the scan), so the carry is rows-varying from the first chunk:
+            # seed it that way or the scan's carry type changes mid-loop
+            acc0 = tuple(
+                lax.pcast(jnp.zeros(s, d_e.dtype), (_mesh.ROWS,),
+                          to="varying")
+                for s in ((nseg, n_f * n_f), (nseg, n_f), (nseg,)))
             (a, b, cnts), _ = lax.scan(
                 body, acc0,
                 (seg_c.reshape(n_chunks, chunk),

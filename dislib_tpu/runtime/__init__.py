@@ -12,8 +12,10 @@ the survival story is built from four pieces that compose (SURVEY §6
   coordinator join, ingest IO, and host↔device transfers (``retry.py``);
 - **elastic** — restore snapshots onto a different device count/mesh
   shape by re-padding host-side logical state (``elastic.py``);
-- **xla_flags** — the single guarded site allowed to mutate ``XLA_FLAGS``
-  (version-gated XLA:CPU collective-timeout mitigation; ``xla_flags.py``);
+- **xla_flags** — the single site allowed to mutate ``XLA_FLAGS``
+  (XLA:CPU collective-timeout mitigation; ``xla_flags.py``);
+- **compile_cache** — the one resolver of where JAX's persistent
+  compilation cache lives (``compile_cache.py``);
 - **health** — the round-8 *internal*-fault layer: fused numerical-health
   guards on every chunked fit loop, a chunk watchdog, snapshot writes
   gated on healthy chunks, and rollback-to-last-good remediation
@@ -47,6 +49,7 @@ driving ``tests/test_resilience.py`` is ``dislib_tpu.utils.faults``.
 """
 
 from dislib_tpu.runtime import xla_flags  # noqa: F401
+from dislib_tpu.runtime import compile_cache  # noqa: F401
 from dislib_tpu.runtime import health  # noqa: F401
 from dislib_tpu.runtime.adoption import (Adoption, AdoptionRejected,
                                          adopt_latest, generation_token)
@@ -93,5 +96,5 @@ __all__ = [
     "ChunkedFitLoop", "ChunkOutcome", "LoopState", "Escalation",
     "EscalationLadder",
     "ContinuousTrainer", "PromotionFailed",
-    "health", "xla_flags",
+    "health", "xla_flags", "compile_cache",
 ]

@@ -93,10 +93,8 @@ class AsyncFetch:
         self._x = x
         self._value = None
         self._resolved = False
-        try:
+        if hasattr(x, "copy_to_host_async"):    # a host value has none
             x.copy_to_host_async()
-        except (AttributeError, RuntimeError):
-            pass                        # host values / exotic backends
 
     def result(self) -> np.ndarray:
         if not self._resolved:

@@ -1,20 +1,14 @@
 """The ONE place allowed to mutate ``XLA_FLAGS`` (lint-enforced by
 ``tests/test_xla_flags_policy.py``; a handful of test/example rigs may set
-the universally-supported device-count flag, nothing else).
+the device-count flag, nothing else).
 
-Why centralised: the package used to inject
-``--xla_cpu_collective_call_terminate_timeout_seconds`` /
-``--xla_cpu_collective_call_warn_stuck_timeout_seconds`` unconditionally at
-import.  XLA treats unknown flags as FATAL — jaxlib builds that predate the
-flags abort the whole process at first backend init (``Unknown flags in
-XLA_FLAGS``), which turned the mitigation into a guaranteed crash on
-jaxlib 0.4.36.  Every injection is therefore gated on jaxlib version here,
-and nowhere else is allowed to spell the flag names.
+XLA treats unknown flags as FATAL at first backend init, so every
+injection lives here and nowhere else is allowed to spell the flag names.
 
-The timeout flags themselves remain valuable where they exist: XLA:CPU
-aborts the process when a collective participant waits >40 s, and on a
-thread-starved CI rig (8 virtual devices on one core) a long compile can
-legitimately stall a participant that long.
+The timeout flags: XLA:CPU aborts the process when a collective
+participant waits >40 s, and on a thread-starved CI rig (8 virtual
+devices on one core) a long compile can legitimately stall a participant
+that long.
 """
 
 from __future__ import annotations
@@ -27,35 +21,6 @@ _TIMEOUT_FLAGS = (
     ("xla_cpu_collective_call_warn_stuck_timeout_seconds", 60),
 )
 
-# First jaxlib line where the collective-call timeout flags are assumed to
-# parse.  0.4.x verifiably rejects them (fatal abort observed on 0.4.36);
-# the threshold is deliberately conservative — missing the mitigation on a
-# version that would have accepted it costs a slower abort on a stall,
-# while injecting into a version that rejects it crashes every process at
-# import.  ``DSLIB_XLA_CPU_TIMEOUT_FLAGS=1`` force-enables on rigs known
-# to support them; ``=0`` force-disables.
-_MIN_JAXLIB_FOR_TIMEOUT_FLAGS = (0, 6, 0)
-
-
-def _jaxlib_version() -> tuple | None:
-    try:
-        import jaxlib
-        parts = jaxlib.__version__.split(".")[:3]
-        return tuple(int("".join(c for c in p if c.isdigit()) or 0)
-                     for p in parts)
-    except Exception:  # noqa: BLE001 — unknown jaxlib: treat as unsupported
-        return None
-
-
-def cpu_collective_timeout_flags_supported() -> bool:
-    """True when this jaxlib is believed to parse the XLA:CPU collective
-    timeout flags.  Env override: ``DSLIB_XLA_CPU_TIMEOUT_FLAGS=1``/``0``."""
-    forced = os.environ.get("DSLIB_XLA_CPU_TIMEOUT_FLAGS")
-    if forced in ("0", "1"):
-        return forced == "1"
-    v = _jaxlib_version()
-    return v is not None and v >= _MIN_JAXLIB_FOR_TIMEOUT_FLAGS
-
 
 def _append_flag(name: str, value) -> None:
     """Append ``--name=value`` to XLA_FLAGS unless the name is already
@@ -66,16 +31,11 @@ def _append_flag(name: str, value) -> None:
     os.environ["XLA_FLAGS"] = (cur + f" --{name}={value}").strip()
 
 
-def inject_cpu_collective_timeouts() -> bool:
+def inject_cpu_collective_timeouts() -> None:
     """Raise the XLA:CPU collective-rendezvous abort threshold (warn log
-    stays early).  Must run before the backend initialises.  No-op —
-    returning False — when this jaxlib does not support the flags; returns
-    True when the flags are (or already were) in place."""
-    if not cpu_collective_timeout_flags_supported():
-        return False
+    stays early).  Must run before the backend initialises."""
     for name, default in _TIMEOUT_FLAGS:
         _append_flag(name, default)
-    return True
 
 
 def force_host_platform_device_count(n: int) -> None:

@@ -2,9 +2,8 @@
 the "millions of users" serving mode).
 
 Training hardened (PRs 1–3), `predict` was still a per-call afterthought:
-every request paid tracing/compile risk for its exact shape, per-op
-dispatch RTT (~70 ms/dispatch on the reference rig, BENCH_local_r05), and
-there was no way to serve a model while its successor trains.  This
+every request paid tracing/compile risk for its exact shape and a
+dispatch round trip per op, and there was no way to serve a model while its successor trains.  This
 package makes one served batch cost **one cached XLA dispatch
 end-to-end**, from four pieces that compose:
 

@@ -186,8 +186,23 @@ def _capture_entries(pipeline, buckets):
             "n_outs": cap["n_outs"],
             "out_cols": cap["out_cols"],
             "pshape": cap["pshape"],
+            "device_ids": _device_ids(cap["leaves"][cap["input_slot"]]),
         }
     return entries, per_bucket
+
+
+def _device_ids(leaf) -> list:
+    """Ids of the devices a captured program runs on, in assignment
+    order, read off its (committed) input leaf: the mesh's devices for a
+    mesh-sharded program, the one device of a single-device one.  The
+    loader must hand exactly these to ``deserialize_and_load``, whose
+    default is EVERY device of the backend — wrong for a program
+    compiled for fewer (the single-device sparse fold-in on a multi-chip
+    host, a fit shrunk onto a mesh prefix)."""
+    sharding = leaf.sharding
+    mesh = getattr(sharding, "mesh", None)
+    devices = mesh.devices.flat if mesh is not None else sharding.device_set
+    return [int(d.id) for d in devices]
 
 
 def export_bundle(pipeline, path: str, buckets=None, checkpoint=None,
@@ -503,13 +518,16 @@ def _build_execs(raw, meta) -> dict:
     from jax.experimental.serialize_executable import deserialize_and_load
 
     execs = {}
+    by_id = {d.id: d for d in jax.devices()}
     for b in meta["buckets"]:
         pb = meta["per_bucket"][str(b)]
         payload = raw[f"exec_{b}"].tobytes()
         in_tree = jtu.tree_structure(
             (tuple(range(pb["n_leaves"])), {}))
         out_tree = jtu.tree_structure(tuple(range(pb["n_outs"])))
-        loaded = deserialize_and_load(payload, in_tree, out_tree)
+        loaded = deserialize_and_load(
+            payload, in_tree, out_tree,
+            execution_devices=[by_id[i] for i in pb["device_ids"]])
         shardings = getattr(loaded, "input_shardings", None)
         shardings = shardings[0] if shardings else None
         args = []

@@ -91,12 +91,28 @@ def _node_histogram(node, bx, w, stats, n_nodes, n_bins, hist="xla"):
     scatter-add; "pallas" routes the one-hot-GEMM Pallas kernel
     (``ops/pallas_kernels.node_histogram``) — bit-equal here because the
     forest's contributions (Poisson weights × count/target stats) are
-    integer-representable, so the sums are exact under either order."""
+    integer-representable, so the sums are exact under either order.
+
+    The Pallas arm runs per row shard inside a ``shard_map`` and sums the
+    partial histograms with one ``psum`` over 'rows': a Mosaic kernel is
+    opaque to the SPMD partitioner (on a multi-device mesh XLA refuses to
+    partition it), and shard-local histogram + all-reduce is the
+    distributed form of the scatter anyway."""
     if hist == "pallas":
+        from jax.sharding import PartitionSpec as P
+
         from dislib_tpu.ops import pallas_kernels as _pk
-        return _pk.node_histogram(node, bx, w[:, None] * stats,
-                                  n_nodes, n_bins).astype(
-            px.compute_dtype(px.FLOAT32))
+        from dislib_tpu.parallel import mesh as _mesh
+
+        def local(nd, b, c):
+            return lax.psum(_pk.node_histogram(nd, b, c, n_nodes, n_bins),
+                            _mesh.ROWS)
+
+        rows = P(_mesh.ROWS)
+        return jax.shard_map(
+            local, mesh=_mesh.get_mesh(), in_specs=(rows, rows, rows),
+            out_specs=P(), check_vma=True,
+        )(node, bx, w[:, None] * stats).astype(px.compute_dtype(px.FLOAT32))
     m, n = bx.shape
     acc_dt = px.compute_dtype(px.FLOAT32)
     feat = lax.broadcasted_iota(jnp.int32, (m, n), 1)
@@ -315,13 +331,9 @@ class _BaseTreeEnsemble(BaseEstimator):
         try_features = self._try_features_count(n)
         # histogram schedule: resolved ONCE here (the fit boundary — the
         # spmm/summa routing precedent, so a DSLIB_OVERLAP flip retraces
-        # and the run is `hist:<sched>` counter-observable).  "pallas"
-        # needs the hist-specific probe on top of the router's: a Mosaic
-        # rejection of THIS kernel's shapes degrades to the XLA scatter.
+        # and the run is `hist:<sched>` counter-observable)
         from dislib_tpu.ops import overlap as _ov
-        from dislib_tpu.ops import pallas_kernels as _pk
-        hist_sched = "pallas" if (_ov.resolve(None) == "pallas"
-                                  and _pk.hist_available()) else "xla"
+        hist_sched = "pallas" if _ov.resolve(None) == "pallas" else "xla"
         _prof.count_schedule("hist", hist_sched)
         box = {"feats": [], "tbins": [], "x": x}
 

@@ -248,9 +248,14 @@ class FitCheckpoint:
                 f.flush()
                 os.fsync(f.fileno())
             for i in range(self.keep - 1, 0, -1):
-                src = self._gen_path(i - 1)
-                if os.path.exists(src):
-                    os.replace(src, self._gen_path(i))
+                try:
+                    os.replace(self._gen_path(i - 1), self._gen_path(i))
+                except FileNotFoundError:
+                    # no such generation yet — or a concurrent writer on
+                    # this path (every rank of a multi-process fit saves
+                    # to it) rotated it first; a check-then-rename here
+                    # killed one rank and hung its peers' next collective
+                    pass
             os.replace(tmp, self.path)
             _fsync_dir(d)
         except BaseException:

@@ -30,10 +30,14 @@ import pytest  # noqa: E402
 
 @pytest.fixture(autouse=True)
 def _reset_mesh():
-    """Each test starts from the default (n_devices, 1) mesh unless it sets its own."""
+    """Each test starts from the default (n_devices, 1) mesh unless it sets
+    its own — and leaves it that way: a module-scoped fixture of the NEXT
+    file runs before that file's first per-test reset, so a test that
+    shrinks the mesh (the elastic tiers) must not hand its mesh on."""
     import dislib_tpu as ds
     ds.init()
     yield
+    ds.init()
 
 
 # Every jitted executable holds LLVM JIT code pages, and one long pytest

@@ -3,10 +3,10 @@ pattern): estimator iteration loops must not read device values back to
 host except through the blessed boundaries — `runtime.fetch` (retried,
 async-capable, a fusion force point) or an explicit `force()`.
 
-The per-dispatch host RTT on this rig is ~70 ms (BENCH_local_r05): ONE
-stray `jax.device_get` / `float(device_scalar)` / `np.asarray(device_val)`
-inside a fit loop reintroduces a per-iteration sync and silently costs
-5-500x on chip.  This lint makes that a CPU test failure instead.
+ONE stray `jax.device_get` / `float(device_scalar)` /
+`np.asarray(device_val)` inside a fit loop reintroduces a per-iteration
+host sync, which idles the device every iteration.  This lint makes that
+a CPU test failure instead.
 
 Policy, enforced by AST scan of the estimator packages:
 

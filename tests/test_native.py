@@ -161,3 +161,29 @@ class TestIoIntegration:
             del os.environ["DSLIB_NO_NATIVE"]
         np.testing.assert_allclose(x1.collect(), x2.collect(), rtol=1e-6)
         np.testing.assert_allclose(y1.collect(), y2.collect(), rtol=1e-6)
+
+
+class TestBuildFailureIsRemembered:
+    def test_reason_is_kept_and_numpy_parsers_serve(self, monkeypatch,
+                                                    tmp_path):
+        """A failed build leaves the NumPy parsers serving — and says
+        why, instead of vanishing into a silent fallback."""
+        import subprocess
+
+        import dislib_tpu as ds
+
+        def broken():
+            raise subprocess.CalledProcessError(
+                1, ["g++"], stderr=b"fastio.cpp:1: error: no such thing")
+        monkeypatch.setattr(native, "_build_and_load", broken)
+        monkeypatch.setattr(native, "_lib", None)
+        monkeypatch.setattr(native, "_tried", False)
+        monkeypatch.setattr(native, "_build_error", None)
+        assert native.get_lib() is None
+        assert "g++ exited 1" in native.build_error()
+        assert "no such thing" in native.build_error()
+        p = tmp_path / "x.csv"
+        p.write_text("1,2\n3,4\n")
+        np.testing.assert_allclose(
+            ds.load_txt_file(str(p), block_size=(2, 2)).collect(),
+            [[1, 2], [3, 4]])

@@ -12,7 +12,6 @@ dot; in the seq body every one does).
 """
 
 import re
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -53,7 +52,7 @@ class TestRouter:
         monkeypatch.setenv("DSLIB_OVERLAP", "seq")
         assert _ov.resolve() == "seq"
         monkeypatch.setenv("DSLIB_OVERLAP", "pallas")
-        assert _ov.resolve() in ("pallas", "db")   # db iff pallas missing
+        assert _ov.resolve() == "pallas"
 
     def test_unknown_raises(self):
         with pytest.raises(ValueError, match="unknown overlap schedule"):
@@ -65,30 +64,14 @@ class TestRouter:
         assert _ov.overlapped("db") and _ov.overlapped("pallas")
         assert not _ov.overlapped("seq")
 
-    def test_pallas_degrades_to_db_when_unavailable(self, monkeypatch):
+    def test_pallas_is_never_rerouted(self):
+        """A requested schedule is the schedule that runs: there is no
+        availability probe that could turn a Mosaic refusal on a TPU into
+        a quiet switch to another schedule."""
+        assert _ov.resolve("pallas") == "pallas"
         from dislib_tpu.ops import pallas_kernels as _pk
-        monkeypatch.setattr(_pk, "_AVAILABLE", False)
-        monkeypatch.setattr(_ov, "_WARN_REGISTRY", {})
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            assert _ov.resolve("pallas") == "db"
-        assert any("falling back" in str(x.message) for x in w), \
-            "the pallas→db degrade must warn (sequential stays explicit)"
-
-    def test_pallas_degrade_warns_once_per_process(self, monkeypatch):
-        """The degradation warning dedupes through the module registry:
-        many dispatch sites resolve the schedule (spmm, forest, rechunk,
-        the ring tiers), and even under an ``always`` warning filter the
-        process must see the degrade exactly ONCE, not once per site."""
-        from dislib_tpu.ops import pallas_kernels as _pk
-        monkeypatch.setattr(_pk, "_AVAILABLE", False)
-        monkeypatch.setattr(_ov, "_WARN_REGISTRY", {})
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            for _ in range(4):              # four "dispatch sites"
-                assert _ov.resolve("pallas") == "db"
-        hits = [x for x in w if "falling back" in str(x.message)]
-        assert len(hits) == 1, f"expected one degrade warning, got {len(hits)}"
+        assert not hasattr(_pk, "available")
+        assert not hasattr(_pk, "hist_available")
 
     def test_public_observability_entry(self, monkeypatch):
         monkeypatch.delenv("DSLIB_OVERLAP", raising=False)
@@ -632,11 +615,6 @@ class TestRingSchedules:
 # ---------------------------------------------------------------------------
 
 class TestPallasRoute:
-    def test_kernels_available_on_this_rig(self):
-        from dislib_tpu.ops import pallas_kernels as _pk
-        assert _pk.available(), \
-            "pallas interpret mode should run on the CPU rig"
-
     def test_panel_gemm_matches_pdot(self):
         from dislib_tpu.ops import pallas_kernels as _pk
         a = jnp.asarray(_mk((48, 32)))

@@ -210,6 +210,41 @@ class TestSnapshotIntegrity:
         ck.delete()
         assert os.listdir(tmp_path) == [] and ck.load() is None
 
+    def test_writers_sharing_a_path_do_not_race_the_rotation(self,
+                                                             tmp_path):
+        """Every rank of a multi-process fit saves to the SAME path; a
+        check-then-rename rotation let one writer move a generation out
+        from under another (FileNotFoundError → one dead rank → its
+        peers hung at the next collective).  Bounded stress: more writers
+        than cores, every save must succeed and a snapshot must load."""
+        import sys
+        import threading
+        path = str(tmp_path / "s.npz")
+        errors = []
+
+        def writer(w):
+            ck = FitCheckpoint(path, every=1, keep=2)
+            try:
+                for i in range(40):
+                    ck.save({"gen": np.asarray([w, i])})
+            except BaseException as e:  # noqa: BLE001 — reported below
+                errors.append(e)
+
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            threads = [threading.Thread(target=writer, args=(w,))
+                       for w in range(2 * (os.cpu_count() or 4))]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=120)
+        finally:
+            sys.setswitchinterval(old)
+        assert not any(t.is_alive() for t in threads)
+        assert not errors, errors
+        assert FitCheckpoint(path).load() is not None
+
     @pytest.mark.parametrize("mode", ["flip", "truncate", "foreign"])
     def test_corrupt_newest_falls_back_to_previous(self, tmp_path, mode):
         path = str(tmp_path / "s.npz")

@@ -213,10 +213,7 @@ class TestHistogramKernel:
                                        (200, 2, 8, 32, 1)])
     def test_pallas_bit_equal_to_xla_scatter(self, rng, shape):
         import jax.numpy as jnp
-        from dislib_tpu.ops import pallas_kernels as _pk
         from dislib_tpu.trees.decision_tree import _node_histogram
-        if not _pk.hist_available():
-            pytest.skip("pallas histogram kernel unavailable")
         m, n, n_nodes, n_bins, s = shape
         node, bx, w, stats = self._inputs(rng, m, n, n_nodes, n_bins, s)
         outs = {}
@@ -236,10 +233,7 @@ class TestHistogramKernel:
     def test_bit_equal_f64_x64_mode(self, rng):
         import jax
         import jax.numpy as jnp
-        from dislib_tpu.ops import pallas_kernels as _pk
         from dislib_tpu.trees.decision_tree import _node_histogram
-        if not _pk.hist_available():
-            pytest.skip("pallas histogram kernel unavailable")
         with jax.enable_x64(True):
             node, bx, w, stats = self._inputs(rng, 96, 3, 4, 8, 2,
                                               dtype=np.float64)
@@ -257,10 +251,7 @@ class TestHistogramKernel:
         """DSLIB_OVERLAP resolves the histogram schedule ONCE at the fit
         boundary (`hist:<sched>` counter), and the FITTED forests agree
         bit-for-bit across schedules — same splits, same probabilities."""
-        from dislib_tpu.ops import pallas_kernels as _pk
         from dislib_tpu.utils import profiling as prof
-        if not _pk.hist_available():
-            pytest.skip("pallas histogram kernel unavailable")
         x, y = _class_data(rng, n=120, d=4, k=2)
         proba = {}
         for env, sched in (("db", "xla"), ("pallas", "pallas")):
@@ -275,31 +266,11 @@ class TestHistogramKernel:
                     rf.predict_proba(ds.array(x)).collect())
         assert (proba["xla"] == proba["pallas"]).all()
 
-    def test_degrades_to_xla_when_hist_probe_fails(self, rng, monkeypatch):
-        """A Mosaic rejection of THIS kernel's shapes degrades the fit to
-        the XLA scatter — never a crash mid-growth."""
-        from dislib_tpu.ops import pallas_kernels as _pk
-        from dislib_tpu.utils import profiling as prof
-        monkeypatch.setenv("DSLIB_OVERLAP", "pallas")
-        monkeypatch.setattr(_pk, "_HIST_AVAILABLE", False)
-        x, y = _class_data(rng, n=90, d=3, k=2)
-        prof.reset_counters()
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore")
-            rf = RandomForestClassifier(n_estimators=3, random_state=0)
-            rf.fit(ds.array(x), ds.array(y[:, None]))
-        sc = prof.schedule_counters()
-        assert sc.get("hist:xla", 0) >= 1 and "hist:pallas" not in sc
-        assert rf.score(ds.array(x), ds.array(y[:, None])) >= 0.85
-
     def test_warm_refit_traces_nothing_new(self, rng, monkeypatch):
         """The routed kernel is a jit STATIC resolved at the fit
         boundary: a second same-shape fit under the pallas route compiles
         zero new programs (the zero-new-hot-path-traces acceptance)."""
-        from dislib_tpu.ops import pallas_kernels as _pk
         from dislib_tpu.utils import profiling as prof
-        if not _pk.hist_available():
-            pytest.skip("pallas histogram kernel unavailable")
         monkeypatch.setenv("DSLIB_OVERLAP", "pallas")
         x, y = _class_data(rng, n=120, d=4, k=2)
         with warnings.catch_warnings():

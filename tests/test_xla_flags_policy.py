@@ -1,14 +1,11 @@
-"""XLA_FLAGS hygiene lint + the version gate for the collective-timeout
-flags (round-6 satellite: the class of bug where an unsupported flag is
-injected at import — XLA fatally aborts on unknown flags — must not
-recur).
+"""XLA_FLAGS hygiene lint + the collective-timeout injection's contract
+(XLA fatally aborts on unknown flags, so flag names live in one module).
 
 Policy, enforced by scanning the repo's Python sources:
 
 1. the XLA:CPU collective-timeout flag NAMES may be spelled only in
-   ``dislib_tpu/runtime/xla_flags.py`` (the one guarded, version-gated
-   injection site) — nowhere else, so nothing can reintroduce an
-   unguarded injection;
+   ``dislib_tpu/runtime/xla_flags.py`` (the one injection site) —
+   nowhere else;
 2. ``os.environ["XLA_FLAGS"]`` mutation is allowed only in that module
    plus a short allowlist of test/example bootstrap sites, and those
    sites may set only the universally-supported device-count flag.
@@ -16,8 +13,6 @@ Policy, enforced by scanning the repo's Python sources:
 
 import os
 import re
-
-import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -68,9 +63,8 @@ def test_timeout_flag_names_confined_to_guarded_site():
             if _TIMEOUT_FLAG.search(f.read()):
                 offenders.append(rel)
     assert not offenders, (
-        "the XLA:CPU collective-timeout flags may only be injected by the "
-        f"version-gated {GUARDED_SITE} (jaxlib builds that predate them "
-        f"abort on unknown flags); found the names in: {offenders}")
+        "the XLA:CPU collective-timeout flags may only be injected by "
+        f"{GUARDED_SITE}; found the names in: {offenders}")
 
 
 def test_xla_flags_mutation_only_at_allowed_sites():
@@ -97,43 +91,19 @@ def test_xla_flags_mutation_only_at_allowed_sites():
             f"flag ({flags}) — use dislib_tpu.runtime.xla_flags")
 
 
-class TestVersionGate:
-    def test_gate_matches_this_jaxlib(self):
-        """On the pinned CI jaxlib (0.4.x) the flags are unsupported and
-        must NOT be in this process's XLA_FLAGS; on a jaxlib past the
-        threshold the gate opens."""
+class TestInjection:
+    def test_inject_is_idempotent(self, monkeypatch):
         from dislib_tpu.runtime import xla_flags as xf
-        v = xf._jaxlib_version()
-        assert v is not None
-        if os.environ.get("DSLIB_XLA_CPU_TIMEOUT_FLAGS") in ("0", "1"):
-            pytest.skip("gate explicitly forced via env")
-        expect = v >= xf._MIN_JAXLIB_FOR_TIMEOUT_FLAGS
-        assert xf.cpu_collective_timeout_flags_supported() == expect
-        if not expect:
-            assert "xla_cpu_collective_call" not in \
-                os.environ.get("XLA_FLAGS", ""), \
-                "unsupported timeout flags leaked into XLA_FLAGS"
-
-    def test_force_enable_and_disable(self, monkeypatch):
-        from dislib_tpu.runtime import xla_flags as xf
-        monkeypatch.setenv("DSLIB_XLA_CPU_TIMEOUT_FLAGS", "1")
         monkeypatch.setenv("XLA_FLAGS", "")
-        assert xf.cpu_collective_timeout_flags_supported()
-        assert xf.inject_cpu_collective_timeouts()
+        xf.inject_cpu_collective_timeouts()
         flags = os.environ["XLA_FLAGS"]
         assert "terminate_timeout_seconds=600" in flags
         assert "warn_stuck_timeout_seconds=60" in flags
-        # idempotent: a second injection appends nothing
-        assert xf.inject_cpu_collective_timeouts()
+        xf.inject_cpu_collective_timeouts()
         assert os.environ["XLA_FLAGS"] == flags
-        monkeypatch.setenv("DSLIB_XLA_CPU_TIMEOUT_FLAGS", "0")
-        monkeypatch.setenv("XLA_FLAGS", "")
-        assert not xf.inject_cpu_collective_timeouts()
-        assert os.environ["XLA_FLAGS"] == ""
 
     def test_user_value_wins(self, monkeypatch):
         from dislib_tpu.runtime import xla_flags as xf
-        monkeypatch.setenv("DSLIB_XLA_CPU_TIMEOUT_FLAGS", "1")
         monkeypatch.setenv(
             "XLA_FLAGS",
             "--xla_cpu_collective_call_terminate_timeout_seconds=99")

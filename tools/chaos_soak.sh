@@ -11,14 +11,15 @@
 #         tools/chaos_soak.sh --multihost [SEED] [OUT_JSONL]
 #
 # Default mode runs the `slow`-marked tests/test_chaos_soak.py (excluded
-# from tier-1) and echoes the machine-readable summary line; append it to
-# the current BENCH_local_*.jsonl when recording a capture.
+# from tier-1) and echoes the machine-readable summary line.  The modes
+# that take OUT_JSONL also APPEND their summary line to that file when it
+# is given; there is no default file.
 #
 # --matrix (round-12) runs the seeded chaos MATRIX instead — every
 # chunked estimator × every fault injector incl. the tier-targeted
 # FaultAtTier (tests/test_chaos_matrix.py) — and APPENDS its
 # machine-readable summary (per-cell verdicts + resilience counters) to
-# OUT_JSONL (default BENCH_local_matrix.jsonl) as one JSON line.
+# OUT_JSONL as one JSON line.
 #
 # --oscillate (round-16) runs the oscillating-CAPACITY tier: a seeded
 # shrink → heal → grow device-availability walk across every chunked
@@ -30,20 +31,19 @@
 # generations with a fault at every seam (torn export, corrupt bundle,
 # canary gate trip, preemption, capacity shrink/grow, explicit rollback)
 # while client threads decode (tenant, generation) from every response —
-# and APPENDS the summary to OUT_JSONL (default BENCH_local_r15.jsonl).
+# and APPENDS the summary to OUT_JSONL.
 # --multihost (round-20) runs the MULTI-HOST SURVIVAL soak: repeated
 # kill → resume → rejoin → grow-back episodes (lease-based membership
 # over a FileCoordinator, death published as a capacity level, the
 # head-home grow on rejoin) under live retrieval client traffic — every
 # dead-window failure must be TYPED (ShardDrained), the healed model
 # must equal the unfaulted oracle, and the rank_deaths/rank_rejoins
-# counters are asserted per episode.  APPENDS the summary to OUT_JSONL
-# (default BENCH_local_r19.jsonl).
+# counters are asserted per episode.  APPENDS the summary to OUT_JSONL.
 set -o pipefail
 cd "$(dirname "$0")/.." || exit 1
 if [ "$1" = "--multihost" ]; then
     SEED="${2:-0}"
-    OUT="${3:-BENCH_local_r19.jsonl}"
+    OUT="${3:-}"
     LOG="$(mktemp)"
     env JAX_PLATFORMS=cpu DSLIB_SOAK_SEED="$SEED" \
         timeout -k 10 900 \
@@ -52,7 +52,7 @@ if [ "$1" = "--multihost" ]; then
     rc=${PIPESTATUS[0]}
     echo "-- multihost soak summary --"
     grep -a "^CHAOS_MH_SUMMARY" "$LOG" | sed 's/^CHAOS_MH_SUMMARY //'
-    if [ "$rc" -eq 0 ]; then
+    if [ "$rc" -eq 0 ] && [ -n "$OUT" ]; then
         grep -a "^CHAOS_MH_SUMMARY" "$LOG" \
             | sed 's/^CHAOS_MH_SUMMARY //' >> "$OUT"
         echo "appended to $OUT"
@@ -62,7 +62,7 @@ if [ "$1" = "--multihost" ]; then
 fi
 if [ "$1" = "--trainer" ]; then
     SEED="${2:-0}"
-    OUT="${3:-BENCH_local_r15.jsonl}"
+    OUT="${3:-}"
     LOG="$(mktemp)"
     env JAX_PLATFORMS=cpu DSLIB_SOAK_SEED="$SEED" \
         python -m pytest tests/test_chaos_soak.py::test_chaos_trainer_soak \
@@ -70,7 +70,7 @@ if [ "$1" = "--trainer" ]; then
     rc=${PIPESTATUS[0]}
     echo "-- trainer soak summary --"
     grep -a "^CHAOS_TRAINER_SUMMARY" "$LOG" | sed 's/^CHAOS_TRAINER_SUMMARY //'
-    if [ "$rc" -eq 0 ]; then
+    if [ "$rc" -eq 0 ] && [ -n "$OUT" ]; then
         grep -a "^CHAOS_TRAINER_SUMMARY" "$LOG" \
             | sed 's/^CHAOS_TRAINER_SUMMARY //' >> "$OUT"
         echo "appended to $OUT"
@@ -93,7 +93,7 @@ if [ "$1" = "--oscillate" ]; then
 fi
 if [ "$1" = "--matrix" ]; then
     SEED="${2:-0}"
-    OUT="${3:-BENCH_local_matrix.jsonl}"
+    OUT="${3:-}"
     LOG="$(mktemp)"
     env JAX_PLATFORMS=cpu DSLIB_MATRIX_SEED="$SEED" \
         python -m pytest tests/test_chaos_matrix.py::test_chaos_matrix_full \
@@ -101,7 +101,7 @@ if [ "$1" = "--matrix" ]; then
     rc=${PIPESTATUS[0]}
     echo "-- matrix summary --"
     grep -a "^CHAOS_MATRIX_SUMMARY" "$LOG" | sed 's/^CHAOS_MATRIX_SUMMARY //'
-    if [ "$rc" -eq 0 ]; then
+    if [ "$rc" -eq 0 ] && [ -n "$OUT" ]; then
         grep -a "^CHAOS_MATRIX_SUMMARY" "$LOG" \
             | sed 's/^CHAOS_MATRIX_SUMMARY //' >> "$OUT"
         echo "appended to $OUT"

@@ -133,29 +133,19 @@ def main():
     assert acct["hosts"] == nprocs
     assert acct["messages_per_step_max"] <= acct["hosts"] - 1
     assert acct["dcn_bytes_moved"] == acct["deviceput_bytes"]
-    from dislib_tpu.runtime.xla_flags import _jaxlib_version
-    v = _jaxlib_version()
-    collectives_ok = (v is not None and v >= (0, 6, 0)) or \
-        os.environ.get("DSLIB_FORCE_MP_TESTS") == "1"
-    if collectives_ok:
-        out, sched = rc.reshard(data, (m, n), dst, schedule="dcn")
-        assert sched == "dcn"
-        # oracle: the relayout is a pure re-partition of the logical array
-        mp2 = -(-m // 2) * 2
-        np2 = -(-n // 2) * 2
-        oracle = np.zeros((mp2, np2), np.float32)
-        oracle[:m, :n] = x
-        for s in out.addressable_shards:
-            np.testing.assert_array_equal(np.asarray(s.data),
-                                          oracle[s.index],
-                                          err_msg="dcn shard mismatch")
-        log(rank, f"rechunk parity OK ({acct['dcn_messages']} DCN "
-                  f"messages, {acct['dcn_bytes_moved']} bytes)")
-    else:
-        log(rank, "rechunk parity SKIPPED (this jaxlib's CPU backend "
-                  "lacks multiprocess collectives) — accounting + "
-                  "support gates checked; mock-host tier-1 carries "
-                  "bit-equality")
+    out, sched = rc.reshard(data, (m, n), dst, schedule="dcn")
+    assert sched == "dcn"
+    # oracle: the relayout is a pure re-partition of the logical array
+    mp2 = -(-m // 2) * 2
+    np2 = -(-n // 2) * 2
+    oracle = np.zeros((mp2, np2), np.float32)
+    oracle[:m, :n] = x
+    for s in out.addressable_shards:
+        np.testing.assert_array_equal(np.asarray(s.data),
+                                      oracle[s.index],
+                                      err_msg="dcn shard mismatch")
+    log(rank, f"rechunk parity OK ({acct['dcn_messages']} DCN "
+              f"messages, {acct['dcn_bytes_moved']} bytes)")
     votes = coord.exchange("dryrun-rechunk", rank, True, n=nprocs)
     assert all(votes.values())
 

@@ -5,14 +5,9 @@
 # poisoned-shard typed abort), and a coherent cross-process
 # shrink→grow capacity episode.  See tools/mh_dryrun.py for the phases.
 #
-# The coordination service (jax.distributed KV) is platform-independent,
-# so the bundle-barrier and capacity phases run for real everywhere.
-# Only the rechunk COLLECTIVE phase needs multiprocess CPU support
-# (jaxlib >= 0.6); on older rigs the worker skips that one phase loudly
-# — its bit-equality is still proven on every tier-1 run through the
-# single-process DSLIB_MOCK_HOSTS overlay
-# (tests/test_multihost_dataplane.py).  DSLIB_FORCE_MP_TESTS=1 forces
-# the collective phase regardless.
+# The rechunk collective phase is also proven on every tier-1 run through
+# the single-process DSLIB_MOCK_HOSTS overlay
+# (tests/test_multihost_dataplane.py).
 #
 # --chaos (round 20) runs the process-killing survival drill instead:
 # ``tools/mh_dryrun.py --chaos`` SIGKILLs one of two real coordinated
