@@ -47,6 +47,13 @@ from dislib_tpu.runtime import fitloop as _fitloop
 from dislib_tpu.runtime import health as _health
 from dislib_tpu.utils.dlog import verbose_logger
 from dislib_tpu.utils.profiling import profiled_jit as _pjit
+from dislib_tpu.utils.profiling import new_call as _new_call, span as _span
+
+# device scopes of one Lloyd's iteration, shared by the dense and the
+# sparse kernel (PERF.md section 3 holds the vocabulary)
+_NORMS = "dslib.kmeans.norms"
+_ASSIGN = "dslib.kmeans.assign"
+_UPDATE = "dslib.kmeans.update"
 
 
 class KMeans(BaseEstimator):
@@ -95,37 +102,40 @@ class KMeans(BaseEstimator):
     # -- fitting -------------------------------------------------------------
 
     def _init_centers(self, x):
-        k, n = self.n_clusters, x.shape[1]
-        if isinstance(self.init, (np.ndarray, list)):
-            c = np.asarray(self.init, dtype=np.float32)
-            if c.shape != (k, n):
-                raise ValueError(f"init centers must be {(k, n)}, got {c.shape}")
-            return jnp.asarray(c)
-        if self.init != "random":
-            raise ValueError(f"unsupported init {self.init!r}")
-        rng = np.random.RandomState(self.random_state)
-        # sample k distinct rows — the reference inits from data rows too
-        idx = rng.choice(x.shape[0], size=min(k, x.shape[0]), replace=False)
-        if isinstance(x, SparseArray):
-            # BCOO row gather: filter the host triplets for the k chosen
-            # rows and scatter into a (k, n) dense block — O(nnz) filter +
-            # O(k·n) result, never an O(k·m) selection operand (the
-            # sharded-rows fit path fetches these same triplets anyway)
-            sidx = np.sort(idx)
-            ind = np.asarray(jax.device_get(x._bcoo.indices))
-            val = np.asarray(jax.device_get(x._bcoo.data), np.float32)
-            pos = np.searchsorted(sidx, ind[:, 0])
-            pos = np.minimum(pos, len(sidx) - 1)
-            hit = sidx[pos] == ind[:, 0]
-            rows_np = np.zeros((len(sidx), n), np.float32)
-            np.add.at(rows_np, (pos[hit], ind[hit, 1]), val[hit])
-            rows = jnp.asarray(rows_np)
-        else:
-            rows = x[np.sort(idx), :]._data[: len(idx), : n]
-        if len(idx) < k:  # fewer samples than clusters: top up with jitter
-            extra = rows[rng.randint(0, len(idx), k - len(idx))] + 1e-3
-            rows = jnp.concatenate([rows, extra], axis=0)
-        return rows
+        with _span("dslib.kmeans.init_centers"):
+            k, n = self.n_clusters, x.shape[1]
+            if isinstance(self.init, (np.ndarray, list)):
+                c = np.asarray(self.init, dtype=np.float32)
+                if c.shape != (k, n):
+                    raise ValueError(
+                        f"init centers must be {(k, n)}, got {c.shape}")
+                return jnp.asarray(c)
+            if self.init != "random":
+                raise ValueError(f"unsupported init {self.init!r}")
+            rng = np.random.RandomState(self.random_state)
+            # sample k distinct rows — the reference inits from data rows too
+            idx = rng.choice(x.shape[0], size=min(k, x.shape[0]),
+                             replace=False)
+            if isinstance(x, SparseArray):
+                # BCOO row gather: filter the host triplets for the k chosen
+                # rows and scatter into a (k, n) dense block — O(nnz) filter +
+                # O(k·n) result, never an O(k·m) selection operand (the
+                # sharded-rows fit path fetches these same triplets anyway)
+                sidx = np.sort(idx)
+                ind = np.asarray(jax.device_get(x._bcoo.indices))
+                val = np.asarray(jax.device_get(x._bcoo.data), np.float32)
+                pos = np.searchsorted(sidx, ind[:, 0])
+                pos = np.minimum(pos, len(sidx) - 1)
+                hit = sidx[pos] == ind[:, 0]
+                rows_np = np.zeros((len(sidx), n), np.float32)
+                np.add.at(rows_np, (pos[hit], ind[hit, 1]), val[hit])
+                rows = jnp.asarray(rows_np)
+            else:
+                rows = x[np.sort(idx), :]._data[: len(idx), : n]
+            if len(idx) < k:  # fewer samples than clusters: top up with jitter
+                extra = rows[rng.randint(0, len(idx), k - len(idx))] + 1e-3
+                rows = jnp.concatenate([rows, extra], axis=0)
+            return rows
 
     def fit(self, x: Array, y=None, checkpoint=None, health=None):
         """Fit on `x`.  With ``checkpoint=FitCheckpoint(path, every=k)`` the
@@ -139,83 +149,85 @@ class KMeans(BaseEstimator):
         polling — is owned by :class:`~dislib_tpu.runtime.ChunkedFitLoop`;
         centers are host-side logical state, so snapshots restore onto a
         different mesh/device count unchanged (elastic resume)."""
-        sparse_in = isinstance(x, SparseArray)
-        box = {"x": x, "inertia": None}
-        log = verbose_logger("kmeans", self.verbose)
-        # data_rebind handles BOTH backings since round 14: dense arrays
-        # re-canonicalize, sparse arrays reshard their panel buffers on
-        # device — the elastic mesh-shrink tier no longer degrades for
-        # sparse fits
-        loop = _fitloop.ChunkedFitLoop(
-            "kmeans", checkpoint=checkpoint, health=health,
-            max_iter=self.max_iter, carry_names=("centers",),
-            carry_shapes=((self.n_clusters, x.shape[1]),),
-            snapshot_expect={"centers": (self.n_clusters, x.shape[1])},
-            elastic=_fitloop.data_rebind(box))
+        with _span("dslib.kmeans.fit", call=_new_call()):
+            sparse_in = isinstance(x, SparseArray)
+            box = {"x": x, "inertia": None}
+            log = verbose_logger("kmeans", self.verbose)
+            # data_rebind handles BOTH backings since round 14: dense arrays
+            # re-canonicalize, sparse arrays reshard their panel buffers on
+            # device — the elastic mesh-shrink tier no longer degrades for
+            # sparse fits
+            loop = _fitloop.ChunkedFitLoop(
+                "kmeans", checkpoint=checkpoint, health=health,
+                max_iter=self.max_iter, carry_names=("centers",),
+                carry_shapes=((self.n_clusters, x.shape[1]),),
+                snapshot_expect={"centers": (self.n_clusters, x.shape[1])},
+                elastic=_fitloop.data_rebind(box))
 
-        def init(rem):
-            box["inertia"] = None
-            return _fitloop.LoopState(
-                (jnp.asarray(rem.perturb(self._init_centers(box["x"]))),))
+            def init(rem):
+                box["inertia"] = None
+                return _fitloop.LoopState(
+                    (jnp.asarray(rem.perturb(self._init_centers(box["x"]))),))
 
-        def restore(snap, rem):
-            # snapshot compatibility (centers shape) is declared via
-            # snapshot_expect and judged by the rollback funnel
-            centers = np.asarray(snap["centers"])
-            # a faulted chunk's inertia must not leak into the fitted
-            # attrs if the restored state exits the loop (converged
-            # snapshot): None falls back to -score(x)
-            box["inertia"] = None
-            return _fitloop.LoopState((jnp.asarray(rem.perturb(centers)),),
-                                      it=int(snap["n_iter"]),
-                                      done=bool(snap.get("converged", False)))
+            def restore(snap, rem):
+                # snapshot compatibility (centers shape) is declared via
+                # snapshot_expect and judged by the rollback funnel
+                centers = np.asarray(snap["centers"])
+                # a faulted chunk's inertia must not leak into the fitted
+                # attrs if the restored state exits the loop (converged
+                # snapshot): None falls back to -score(x)
+                box["inertia"] = None
+                return _fitloop.LoopState(
+                    (jnp.asarray(rem.perturb(centers)),),
+                    it=int(snap["n_iter"]),
+                    done=bool(snap.get("converged", False)))
 
-        def step(st, chunk):
-            (centers,) = st.carries
-            if sparse_in:
-                data, lrows, cols, rowsq = box["x"].sharded_rows()
-                centers, n_done, inertia, shift, hist, hvec = \
-                    _kmeans_fit_sparse_sharded(
-                        data, lrows, cols, rowsq, centers, x.shape[0], chunk,
-                        float(self.tol), _mesh.get_mesh())
-            else:
-                xd = box["x"]
-                centers, n_done, inertia, shift, hist, hvec = _kmeans_fit(
-                    xd._data, xd.shape, centers, chunk, float(self.tol),
-                    fast=self._fast())
+            def step(st, chunk):
+                (centers,) = st.carries
+                if sparse_in:
+                    data, lrows, cols, rowsq = box["x"].sharded_rows()
+                    centers, n_done, inertia, shift, hist, hvec = \
+                        _kmeans_fit_sparse_sharded(
+                            data, lrows, cols, rowsq, centers, x.shape[0],
+                            chunk, float(self.tol), _mesh.get_mesh())
+                else:
+                    xd = box["x"]
+                    centers, n_done, inertia, shift, hist, hvec = _kmeans_fit(
+                        xd._data, xd.shape, centers, chunk, float(self.tol),
+                        fast=self._fast())
 
-            def commit():
-                # deferred: these scalar syncs run only AFTER the verdict,
-                # so the watchdogged hvec read is the chunk's first force
-                # point (and a faulted chunk never touches the box)
-                box["inertia"] = inertia
-                it = st.it + int(n_done)
-                done = float(shift) < self.tol
-                log.info("iter %d: inertia=%.6g shift=%.3g", it,
-                         float(inertia), float(shift))
-                return _fitloop.LoopState((centers,), it, done)
+                def commit():
+                    # deferred: these scalar syncs run only AFTER the verdict,
+                    # so the watchdogged hvec read is the chunk's first force
+                    # point (and a faulted chunk never touches the box)
+                    box["inertia"] = inertia
+                    it = st.it + int(n_done)
+                    done = float(shift) < self.tol
+                    log.info("iter %d: inertia=%.6g shift=%.3g", it,
+                             float(inertia), float(shift))
+                    return _fitloop.LoopState((centers,), it, done)
 
-            return _fitloop.ChunkOutcome(
-                commit, hvec=hvec,
-                history=lambda: _fetch(hist)[: int(n_done)])
+                return _fitloop.ChunkOutcome(
+                    commit, hvec=hvec,
+                    history=lambda: _fetch(hist)[: int(n_done)])
 
-        def snapshot(st):
-            # async offload: the device->host copy starts now and the file
-            # write runs on the snapshot worker, both overlapping the next
-            # chunk's compute (centers are never donated)
-            return {"centers": _fetch(st.carries[0], blocking=False),
-                    "n_iter": st.it, "converged": st.done}
+            def snapshot(st):
+                # async offload: the device->host copy starts now and the file
+                # write runs on the snapshot worker, both overlapping the next
+                # chunk's compute (centers are never donated)
+                return {"centers": _fetch(st.carries[0], blocking=False),
+                        "n_iter": st.it, "converged": st.done}
 
-        st = loop.run(init=init, step=step, restore=restore,
-                      snapshot=snapshot)
-        self.centers_ = np.asarray(jax.device_get(st.carries[0]))
-        self.n_iter_ = st.it
-        self.history_ = np.asarray(loop.history, dtype=np.float64)
-        self.fit_info_ = loop.info
-        # inertia is None only when resuming an already-finished fit
-        self.inertia_ = float(box["inertia"]) \
-            if box["inertia"] is not None else -self.score(box["x"])
-        return self
+            st = loop.run(init=init, step=step, restore=restore,
+                          snapshot=snapshot)
+            self.centers_ = _fetch(st.carries[0])
+            self.n_iter_ = st.it
+            self.history_ = np.asarray(loop.history, dtype=np.float64)
+            self.fit_info_ = loop.info
+            # inertia is None only when resuming an already-finished fit
+            self.inertia_ = float(box["inertia"]) \
+                if box["inertia"] is not None else -self.score(box["x"])
+            return self
 
     # async trial protocol (SURVEY §4.5): fit/score entirely on device, no
     # host read until GridSearchCV has dispatched every trial
@@ -296,25 +308,28 @@ def _kmeans_fit(xp, shape, centers0, max_iter, tol, fast=False):
     # GEMM reads 2 bytes/element instead of 4 (same values the MXU's own
     # input rounding would produce — only the HBM traffic changes).  The
     # center-update GEMM still reads the f32 copy, keeping centers exact.
-    x_sq = jnp.sum(xv * xv, axis=1, keepdims=True)
+    with jax.named_scope(_NORMS):
+        x_sq = jnp.sum(xv * xv, axis=1, keepdims=True)
     xd = xv.astype(jnp.bfloat16) if fast else xv
 
     def step(carry):
         centers, _, it, _, hist = carry
-        cross = jnp.matmul(xd, centers.astype(xd.dtype).T,
-                           precision="default" if fast else None,
-                           preferred_element_type=xv.dtype)
-        c_sq = jnp.sum(centers * centers, axis=1)
-        d = jnp.maximum(x_sq - 2.0 * cross + c_sq[None, :], 0.0)
-        labels = jnp.argmin(d, axis=1)
-        onehot = jax.nn.one_hot(labels, k, dtype=xv.dtype) * w[:, None]
-        sums = onehot.T @ xv                 # (k, n) — row-axis psum under SPMD
-        counts = jnp.sum(onehot, axis=0)     # (k,)
-        new_centers = jnp.where(counts[:, None] > 0,
-                                sums / jnp.maximum(counts, 1.0)[:, None],
-                                centers)
-        shift = jnp.sum((new_centers - centers) ** 2)
-        inertia = jnp.sum(jnp.min(d, axis=1) * w)
+        with jax.named_scope(_ASSIGN):
+            cross = jnp.matmul(xd, centers.astype(xd.dtype).T,
+                               precision="default" if fast else None,
+                               preferred_element_type=xv.dtype)
+            c_sq = jnp.sum(centers * centers, axis=1)
+            d = jnp.maximum(x_sq - 2.0 * cross + c_sq[None, :], 0.0)
+            labels = jnp.argmin(d, axis=1)
+        with jax.named_scope(_UPDATE):
+            onehot = jax.nn.one_hot(labels, k, dtype=xv.dtype) * w[:, None]
+            sums = onehot.T @ xv             # (k, n) — row-axis psum under SPMD
+            counts = jnp.sum(onehot, axis=0)     # (k,)
+            new_centers = jnp.where(counts[:, None] > 0,
+                                    sums / jnp.maximum(counts, 1.0)[:, None],
+                                    centers)
+            shift = jnp.sum((new_centers - centers) ** 2)
+            inertia = jnp.sum(jnp.min(d, axis=1) * w)
         return new_centers, shift, it + 1, inertia, hist.at[it].set(inertia)
 
     def cond(carry):
@@ -385,28 +400,31 @@ def _kmeans_fit_sparse_sharded(data, lrows, cols, rowsq, centers0, m,
 
         def step(carry):
             centers, _, it, _, hist = carry
-            c_sq = jnp.sum(centers * centers, axis=1)
-            # cross = x_local @ centersᵀ, one gather + segment_sum
-            contrib = centers.T[cc] * d_e[:, None]           # (nnz, k)
-            cross = jax.ops.segment_sum(contrib, lr, num_segments=m_local)
-            dist = jnp.maximum(rsq[:, None] - 2.0 * cross + c_sq[None, :],
-                               0.0)
-            labels = jnp.argmin(dist, axis=1)
-            onehot = jax.nn.one_hot(labels, k, dtype=centers.dtype) \
-                * valid[:, None].astype(centers.dtype)
-            counts = lax.psum(jnp.sum(onehot, axis=0), _mesh.ROWS)
-            # sums = xᵀ onehot: shard-local partial + psum
-            contrib2 = onehot[lr] * d_e[:, None]             # (nnz, k)
-            partial = jax.ops.segment_sum(contrib2, cc,
-                                          num_segments=centers.shape[1])
-            sums = lax.psum(partial, _mesh.ROWS).T           # (k, n)
-            inertia = lax.psum(
-                jnp.sum(jnp.min(dist, axis=1)
-                        * valid.astype(centers.dtype)), _mesh.ROWS)
-            new_centers = jnp.where(counts[:, None] > 0,
-                                    sums / jnp.maximum(counts, 1.0)[:, None],
-                                    centers)
-            shift = jnp.sum((new_centers - centers) ** 2)
+            with jax.named_scope(_ASSIGN):
+                c_sq = jnp.sum(centers * centers, axis=1)
+                # cross = x_local @ centersᵀ, one gather + segment_sum
+                contrib = centers.T[cc] * d_e[:, None]       # (nnz, k)
+                cross = jax.ops.segment_sum(contrib, lr,
+                                            num_segments=m_local)
+                dist = jnp.maximum(
+                    rsq[:, None] - 2.0 * cross + c_sq[None, :], 0.0)
+                labels = jnp.argmin(dist, axis=1)
+            with jax.named_scope(_UPDATE):
+                onehot = jax.nn.one_hot(labels, k, dtype=centers.dtype) \
+                    * valid[:, None].astype(centers.dtype)
+                counts = lax.psum(jnp.sum(onehot, axis=0), _mesh.ROWS)
+                # sums = xᵀ onehot: shard-local partial + psum
+                contrib2 = onehot[lr] * d_e[:, None]         # (nnz, k)
+                partial = jax.ops.segment_sum(
+                    contrib2, cc, num_segments=centers.shape[1])
+                sums = lax.psum(partial, _mesh.ROWS).T       # (k, n)
+                inertia = lax.psum(
+                    jnp.sum(jnp.min(dist, axis=1)
+                            * valid.astype(centers.dtype)), _mesh.ROWS)
+                new_centers = jnp.where(
+                    counts[:, None] > 0,
+                    sums / jnp.maximum(counts, 1.0)[:, None], centers)
+                shift = jnp.sum((new_centers - centers) ** 2)
             return new_centers, shift, it + 1, inertia, hist.at[it].set(inertia)
 
         def cond(carry):

@@ -59,7 +59,9 @@ from jax import lax
 
 from dislib_tpu.ops.base import distances_sq as _raw_distances_sq, precise
 from dislib_tpu.parallel import mesh as _mesh
+from dislib_tpu.utils.profiling import host_read as _host_read
 from dislib_tpu.utils.profiling import profiled_jit as _pjit
+from dislib_tpu.utils.profiling import span as _span
 
 __all__ = [
     "Array",
@@ -488,8 +490,9 @@ class Array:
             if expr.value is not None:   # prefix already materialised by
                 self._concrete = expr.value  # another consumer's force
             else:
-                program, leaves, shared = _linearize(expr)
-                root, *shared_vals = _exec_program(program, *leaves)
+                with _span("dslib.array.force"):
+                    program, leaves, shared = _linearize(expr)
+                    root, *shared_vals = _exec_program(program, *leaves)
                 for node, val in zip(shared, shared_vals):
                     node.value = val
                     node.args = ()      # edges are dead once cached —
@@ -578,14 +581,14 @@ class Array:
         devices, so the gather is a `process_allgather` over DCN (every
         host ends with the full logical array, the reference's
         gather-to-master contract)."""
-        from dislib_tpu.utils.profiling import count_transfer
-        count_transfer()
-        if not self._data.is_fully_addressable:
-            from jax.experimental import multihost_utils
-            out = np.asarray(multihost_utils.process_allgather(
-                self._data, tiled=True))
-        else:
-            out = np.asarray(jax.device_get(self._data))
+        data = self._data               # the force, outside the read
+        with _host_read():
+            if not data.is_fully_addressable:
+                from jax.experimental import multihost_utils
+                out = np.asarray(multihost_utils.process_allgather(
+                    data, tiled=True))
+            else:
+                out = np.asarray(jax.device_get(data))
         out = out[: self._shape[0], : self._shape[1]]
         if self._sparse:
             import scipy.sparse as sp
@@ -593,7 +596,9 @@ class Array:
         return out
 
     def block_until_ready(self) -> "Array":
-        self._data.block_until_ready()
+        data = self._data               # the force, with a span of its own
+        with _span("dslib.array.wait"):
+            data.block_until_ready()
         return self
 
     def __float__(self) -> float:
@@ -604,10 +609,9 @@ class Array:
                 f"only a (1, 1) ds-array converts to float, got {self._shape}")
         # read the backing directly: collect() of a sparse-flagged array
         # wraps the scalar in a csr_matrix, which float() rejects
-        from dislib_tpu.utils.profiling import count_transfer
-        count_transfer()
-        return float(np.asarray(jax.device_get(self._data[0:1, 0:1]))
-                     .reshape(()))
+        corner = self._data[0:1, 0:1]
+        with _host_read():
+            return float(np.asarray(jax.device_get(corner)).reshape(()))
 
     # -- layout --------------------------------------------------------------
 
@@ -1313,11 +1317,10 @@ def apply_along_axis(func, axis, x: Array, *args, **kwargs) -> Array:
             f"JAX-traceable ({type(e).__name__}: {e}); falling back to host "
             "NumPy (device->host->device round trip, far slower)",
             UserWarning, stacklevel=2)
-        from dislib_tpu.utils.profiling import count_transfer
         logical = x._data[: x._shape[0], : x._shape[1]]
-        count_transfer()
-        out = np.apply_along_axis(
-            func, axis, np.asarray(jax.device_get(logical)), *args, **kwargs)
+        with _host_read():
+            host = np.asarray(jax.device_get(logical))
+        out = np.apply_along_axis(func, axis, host, *args, **kwargs)
         out = jnp.asarray(out)
         if out.ndim == 1:
             out = out.reshape(1, -1) if axis == 0 else out.reshape(-1, 1)

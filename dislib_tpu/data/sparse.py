@@ -49,7 +49,7 @@ from jax.experimental import sparse as jsparse
 from dislib_tpu.data.array import Array
 from dislib_tpu.ops.base import precise
 from dislib_tpu.parallel import mesh as _mesh
-from dislib_tpu.utils.profiling import count_transfer as _count_transfer
+from dislib_tpu.utils.profiling import host_read as _host_read
 from dislib_tpu.utils.profiling import profiled_jit as _pjit
 
 __all__ = ["SparseArray", "ShardedSparse", "SparsePanelView", "nse_quantum"]
@@ -222,10 +222,10 @@ class ShardedSparse:
     def host_triplets(self):
         """(rows, cols, vals) global host triplets — the collect path
         (counts ONE host transfer via the blessed counter)."""
-        _count_transfer()
-        d = np.asarray(jax.device_get(self.data))
-        lr = np.asarray(jax.device_get(self.lrows))
-        cc = np.asarray(jax.device_get(self.cols))
+        with _host_read():
+            d = np.asarray(jax.device_get(self.data))
+            lr = np.asarray(jax.device_get(self.lrows))
+            cc = np.asarray(jax.device_get(self.cols))
         rows_l, cols_l, vals_l = [], [], []
         for s, k in enumerate(self.counts):
             rows_l.append(lr[s, :k].astype(np.int64) + s * self.m_local)
@@ -244,8 +244,8 @@ class ShardedSparse:
         The stream is shard-major over live slots, which by the
         row-sorted invariant IS the global row-sorted entry order."""
         if self.cols_host is None:
-            _count_transfer()
-            cc = np.asarray(jax.device_get(self.cols))
+            with _host_read():
+                cc = np.asarray(jax.device_get(self.cols))
             self.cols_host = np.concatenate(
                 [cc[s, :k] for s, k in enumerate(self.counts)]
             ).astype(np.int32)

@@ -136,9 +136,17 @@ def matmul(a: Array, b: Array, transpose_a: bool = False,
     guarded) and takes the dense path; ``"auto"`` picks spmm at or below
     ``DSLIB_SPMM_MAX_DENSITY`` (default 0.1) or whenever densifying
     would blow ``DSLIB_SPARSE_DENSIFY_BUDGET``, densify otherwise."""
+    with _prof.span("dslib.matmul", call=_prof.new_call()) as sp:
+        return _route_matmul(sp, a, b, transpose_a, transpose_b, algorithm,
+                             precision)
+
+
+def _route_matmul(sp, a, b, transpose_a, transpose_b, algorithm, precision):
+    """:func:`matmul` inside its span ``sp``, which learns the route
+    taken (``route=xla|summa|spmm``)."""
     from dislib_tpu.data.sparse import SparseArray
     if isinstance(a, SparseArray) or isinstance(b, SparseArray):
-        return _matmul_sparse(a, b, transpose_a, transpose_b, algorithm,
+        return _matmul_sparse(sp, a, b, transpose_a, transpose_b, algorithm,
                               precision)
     policy = px.resolve(precision)
     a_shape = (a.shape[1], a.shape[0]) if transpose_a else a.shape
@@ -151,6 +159,7 @@ def matmul(a: Array, b: Array, transpose_a: bool = False,
     dense = type(a) is Array and type(b) is Array
     algo = _pick_algorithm(algorithm, a, b, a_shape, b_shape, dense,
                            transpose_a, transpose_b)
+    sp.set(route=algo)
     if algo == "summa":
         if not dense:
             raise ValueError("algorithm='summa' needs dense ds-array "
@@ -205,7 +214,8 @@ def _pick_sparse_algorithm(a, algorithm):
     return "spmm" if 4 * pm * pn > densify_budget_bytes() else "densify"
 
 
-def _matmul_sparse(a, b, transpose_a, transpose_b, algorithm, precision):
+def _matmul_sparse(sp, a, b, transpose_a, transpose_b, algorithm,
+                   precision):
     """The sparse fast-path entry: SparseArray @ dense ds-array via the
     spmm/densify router.  Transposed and sparse-rhs/sparse-sparse forms
     have no sharded schedule — they densify EXPLICITLY (never silently:
@@ -226,8 +236,10 @@ def _matmul_sparse(a, b, transpose_a, transpose_b, algorithm, precision):
         raise ValueError(f"matmul shape mismatch: {a.shape} @ {b.shape}")
     algo = _pick_sparse_algorithm(a, algorithm)
     if algo == "spmm":
+        sp.set(route="spmm")
         return _spmm_entry(a, b, precision=precision)
-    return matmul(a.to_dense(), b, precision=precision)
+    return _route_matmul(sp, a.to_dense(), b, False, False, "auto",
+                         precision)
 
 
 def _matmul_summa(a, b, transpose_a, transpose_b, policy, out_shape, reg):

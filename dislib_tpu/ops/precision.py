@@ -200,12 +200,7 @@ def pdot(a, b, policy: Policy = FLOAT32):
     Output dtype is the accumulation dtype (float32; float64 on x64-mode
     float64 operands under the float32-floor policy).  ``jnp.matmul``
     semantics, so batched (3-D) operands contract per batch."""
-    a = to_compute(a, policy)
-    b = to_compute(b, policy)
-    acc = jnp.promote_types(jnp.dtype(policy.accum),
-                            jnp.promote_types(a.dtype, b.dtype))
-    return jnp.matmul(a, b, precision=policy.dot_precision,
-                      preferred_element_type=acc)
+    return _contract(jnp.matmul, a, b, policy)
 
 
 def peinsum(subscripts, a, b, policy: Policy = FLOAT32):
@@ -214,12 +209,19 @@ def peinsum(subscripts, a, b, policy: Policy = FLOAT32):
     updates).  Operands round to the policy compute dtype, the
     contraction accumulates float32 (``preferred_element_type``), output
     is the accumulation dtype — same contract as :func:`pdot`."""
-    a = to_compute(a, policy)
-    b = to_compute(b, policy)
-    acc = jnp.promote_types(jnp.dtype(policy.accum),
-                            jnp.promote_types(a.dtype, b.dtype))
-    return jnp.einsum(subscripts, a, b, precision=policy.dot_precision,
-                      preferred_element_type=acc)
+    return _contract(functools.partial(jnp.einsum, subscripts), a, b, policy)
+
+
+def _contract(contraction, a, b, policy):
+    """The body :func:`pdot` and :func:`peinsum` share, under the one
+    device scope that names the library's GEMMs in a trace."""
+    with jax.named_scope("dslib.pdot"):
+        a = to_compute(a, policy)
+        b = to_compute(b, policy)
+        acc = jnp.promote_types(jnp.dtype(policy.accum),
+                                jnp.promote_types(a.dtype, b.dtype))
+        return contraction(a, b, precision=policy.dot_precision,
+                           preferred_element_type=acc)
 
 
 def precise(fn):

@@ -133,19 +133,21 @@ def summa_matmul(ap, bp, mesh, policy, overlap="db", comm_only=False):
             # (masked psum: non-owners contribute exact zeros); offsets
             # are computed identically on every rank, so the slice is
             # in-bounds everywhere and the mask picks the owner's panel
-            owner_c = off // ka
-            a_pan = lax.dynamic_slice(ac, (0, off - owner_c * ka),
-                                      (m_loc, kb))
-            a_pan = jnp.where(my_c == owner_c, a_pan,
-                              jnp.zeros((), a_pan.dtype))
-            a_pan = lax.psum(a_pan, _mesh.COLS)
+            with jax.named_scope("dslib.summa.fetch_a"):
+                owner_c = off // ka
+                a_pan = lax.dynamic_slice(ac, (0, off - owner_c * ka),
+                                          (m_loc, kb))
+                a_pan = jnp.where(my_c == owner_c, a_pan,
+                                  jnp.zeros((), a_pan.dtype))
+                a_pan = lax.psum(a_pan, _mesh.COLS)
             # broadcast the B panel from its owner rows-rank along 'rows'
-            owner_r = off // kb_loc
-            b_pan = lax.dynamic_slice(bc, (off - owner_r * kb_loc, 0),
-                                      (kb, n_loc))
-            b_pan = jnp.where(my_r == owner_r, b_pan,
-                              jnp.zeros((), b_pan.dtype))
-            b_pan = lax.psum(b_pan, _mesh.ROWS)
+            with jax.named_scope("dslib.summa.fetch_b"):
+                owner_r = off // kb_loc
+                b_pan = lax.dynamic_slice(bc, (off - owner_r * kb_loc, 0),
+                                          (kb, n_loc))
+                b_pan = jnp.where(my_r == owner_r, b_pan,
+                                  jnp.zeros((), b_pan.dtype))
+                b_pan = lax.psum(b_pan, _mesh.ROWS)
             return a_pan, b_pan
 
         if comm_only:
@@ -157,10 +159,11 @@ def summa_matmul(ap, bp, mesh, policy, overlap="db", comm_only=False):
         else:
             def consume(t, acc, pan):
                 a_pan, b_pan = pan
-                if overlap == "pallas":
-                    from dislib_tpu.ops import pallas_kernels as _pk
-                    return acc + _pk.panel_gemm(a_pan, b_pan, policy)
-                return acc + px.pdot(a_pan, b_pan, policy)
+                with jax.named_scope("dslib.summa.gemm"):
+                    if overlap == "pallas":
+                        from dislib_tpu.ops import pallas_kernels as _pk
+                        return acc + _pk.panel_gemm(a_pan, b_pan, policy)
+                    return acc + px.pdot(a_pan, b_pan, policy)
 
             acc_shape = (m_loc, n_loc)
 

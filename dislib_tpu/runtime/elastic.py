@@ -101,11 +101,11 @@ class AsyncFetch:
             import jax
 
             from dislib_tpu.runtime.retry import Retry
-            from dislib_tpu.utils.profiling import count_transfer
-            count_transfer()
+            from dislib_tpu.utils.profiling import host_read
             try:
-                self._value = Retry.from_env().call(
-                    lambda: np.asarray(jax.device_get(self._x)))
+                with host_read():
+                    self._value = Retry.from_env().call(
+                        lambda: np.asarray(jax.device_get(self._x)))
             except RuntimeError as e:
                 if "deleted" in str(e) or "donated" in str(e):
                     raise RuntimeError(
@@ -135,6 +135,6 @@ def fetch(x, blocking: bool = True):
     import jax
 
     from dislib_tpu.runtime.retry import Retry
-    from dislib_tpu.utils.profiling import count_transfer
-    count_transfer()
-    return Retry.from_env().call(lambda: np.asarray(jax.device_get(x)))
+    from dislib_tpu.utils.profiling import host_read
+    with host_read():
+        return Retry.from_env().call(lambda: np.asarray(jax.device_get(x)))

@@ -80,7 +80,10 @@ from dislib_tpu.runtime.health import NO_REMEDIATION
 from dislib_tpu.runtime.preemption import (capacity_target,
                                            preemption_requested,
                                            raise_if_preempted)
-from dislib_tpu.utils.profiling import count_resilience
+from dislib_tpu.utils.profiling import count_resilience, span
+
+# the span of a whole run: the one name ``run`` and ``run_one`` share
+_RUN = "dslib.fitloop.run"
 
 __all__ = ["ChunkedFitLoop", "LoopState", "ChunkOutcome", "Escalation",
            "EscalationLadder", "NO_REMEDIATION", "TIERS", "data_rebind",
@@ -342,61 +345,71 @@ class ChunkedFitLoop:
             else min(self.checkpoint.every, left)
 
     def _one_chunk(self, st, step, chunk):
-        """admit → step → judge (watchdogged) → materialize the deferred
-        commit.  Returns ``(state, history)``, or None after a rollback
-        was decided (``self._esc`` holds the escalation).  The preemption
-        flag is polled ONCE here and reused by ``_commit`` — two
-        independent polls could let a flag arriving between them snapshot
-        a chunk whose health vector was never judged (check_on='save')."""
-        carries = self.guard.admit(*st.carries)
-        out = step(LoopState(carries, st.it, st.done, st.extra), chunk)
-        self._preempt = preemption_requested()
-        self._cap_plan = self._capacity_plan()
-        if self.check_on == "chunk":
-            do_check = True
-        else:                           # 'save': judge at save boundaries
-            # a pending capacity resize forces the boundary: the resize
-            # snapshots this chunk's state, so it must be judged first
-            boundary = out.state.done \
-                or (self._cadence + 1) % self.save_every == 0 \
-                or self._preempt or self._cap_plan is not None
-            do_check = self.checkpoint is not None and boundary
-        if do_check:
-            if out.host_values is not None:
-                verdict = self.guard.check_host(out.host_values, it=st.it)
-            elif out.hvec is not None:
-                verdict = self.guard.check(
-                    out.hvec, carry_names=self.carry_names,
-                    carry_shapes=self.carry_shapes, it=st.it,
-                    increasing=self.increasing)
-            else:
-                verdict = None
-            if verdict is not None and not verdict.ok:
-                esc = self.ladder.escalate(verdict, it=st.it)  # may raise
-                self.info["rollbacks"] += 1
-                self.info["escalations"][esc.tier] += 1
-                if esc.tier == "elastic":
-                    self._shrink_mesh()
-                self._esc = esc
-                return None
-        state = out.state() if callable(out.state) else out.state
-        hist = out.history() if callable(out.history) else out.history
-        return state, hist
+        """admit → step (the chunk's dispatch) → judge (watchdogged).
+        Returns the chunk's :class:`ChunkOutcome`, its deferred commit
+        still unrun, or None after a rollback was decided (``self._esc``
+        holds the escalation).  The preemption flag is polled ONCE here
+        and reused by ``_commit`` — two independent polls could let a
+        flag arriving between them snapshot a chunk whose health vector
+        was never judged (check_on='save')."""
+        with span("dslib.fitloop.chunk", it=st.it):
+            carries = self.guard.admit(*st.carries)
+            out = step(LoopState(carries, st.it, st.done, st.extra), chunk)
+            self._preempt = preemption_requested()
+            self._cap_plan = self._capacity_plan()
+            if self.check_on == "chunk":
+                do_check = True
+            else:                       # 'save': judge at save boundaries
+                # a pending capacity resize forces the boundary: the resize
+                # snapshots this chunk's state, so it must be judged first
+                boundary = out.state.done \
+                    or (self._cadence + 1) % self.save_every == 0 \
+                    or self._preempt or self._cap_plan is not None
+                do_check = self.checkpoint is not None and boundary
+            if do_check:
+                if out.host_values is not None:
+                    verdict = self.guard.check_host(out.host_values,
+                                                    it=st.it)
+                elif out.hvec is not None:
+                    verdict = self.guard.check(
+                        out.hvec, carry_names=self.carry_names,
+                        carry_shapes=self.carry_shapes, it=st.it,
+                        increasing=self.increasing)
+                else:
+                    verdict = None
+                if verdict is not None and not verdict.ok:
+                    esc = self.ladder.escalate(verdict, it=st.it)  # may raise
+                    self.info["rollbacks"] += 1
+                    self.info["escalations"][esc.tier] += 1
+                    if esc.tier == "elastic":
+                        self._shrink_mesh()
+                    self._esc = esc
+                    return None
+            return out
 
-    def _commit(self, st, hist, snapshot):
-        self.info["chunks"] += 1
-        self._cadence += 1
-        if hist is not None and len(hist):
-            self.history.extend(hist)
-        if self.checkpoint is None:
-            return
-        boundary = st.done or self._cadence % self.save_every == 0
-        if (boundary or self._preempt or self._cap_plan is not None) \
-                and (not st.done or self.save_final):
-            self.guard.save_async(self.checkpoint, snapshot(st))
-        if self._preempt and not st.done \
-                and (self.max_iter is None or st.it < self.max_iter):
-            raise_if_preempted(self.checkpoint)
+    def _commit(self, out, snapshot) -> LoopState:
+        """Materialize a judged chunk's deferred state and history (the
+        estimator's scalar reads — the device wait too, where the health
+        check is off and ``dslib.fitloop.wait`` never opened), book the
+        chunk, and write the gated snapshot.  Returns the successor
+        state."""
+        with span("dslib.fitloop.commit"):
+            st = out.state() if callable(out.state) else out.state
+            hist = out.history() if callable(out.history) else out.history
+            self.info["chunks"] += 1
+            self._cadence += 1
+            if hist is not None and len(hist):
+                self.history.extend(hist)
+            if self.checkpoint is None:
+                return st
+            boundary = st.done or self._cadence % self.save_every == 0
+            if (boundary or self._preempt or self._cap_plan is not None) \
+                    and (not st.done or self.save_final):
+                self.guard.save_async(self.checkpoint, snapshot(st))
+            if self._preempt and not st.done \
+                    and (self.max_iter is None or st.it < self.max_iter):
+                raise_if_preempted(self.checkpoint)
+            return st
 
     def _capacity_plan(self):
         """Compare the published capacity level against the current mesh
@@ -506,23 +519,24 @@ class ChunkedFitLoop:
         """Drive a whole fit: chunks until converged/budget-spent, the
         full protocol per chunk.  Returns the final state (also kept as
         ``self.state``); flushes the checkpoint before returning."""
-        st = self._load_state(init, restore)
-        while not st.done:
-            chunk = self._plan(st)
-            if chunk is not None and chunk <= 0:
-                break
-            got = self._one_chunk(st, step, chunk)
-            if got is None:             # rolled back: reload last-good
-                st = self._load_state(init, restore, self._esc.remediation)
-                continue
-            st, hist = got
-            self._commit(st, hist, snapshot)
-            if self._cap_plan is not None and not st.done:
-                st = self._apply_capacity(st, init, restore)
-        if self.checkpoint is not None:
-            self.checkpoint.flush()     # last snapshot lands before return
-        self._state = st
-        return st
+        with span(_RUN):
+            st = self._load_state(init, restore)
+            while not st.done:
+                chunk = self._plan(st)
+                if chunk is not None and chunk <= 0:
+                    break
+                out = self._one_chunk(st, step, chunk)
+                if out is None:         # rolled back: reload last-good
+                    st = self._load_state(init, restore,
+                                          self._esc.remediation)
+                    continue
+                st = self._commit(out, snapshot)
+                if self._cap_plan is not None and not st.done:
+                    st = self._apply_capacity(st, init, restore)
+            if self.checkpoint is not None:
+                self.checkpoint.flush()  # last snapshot lands before return
+            self._state = st
+            return st
 
     def run_one(self, *, init, step, restore=None, snapshot=None) -> LoopState:
         """Streaming entry (``partial_fit``): ONE committed chunk per
@@ -532,19 +546,20 @@ class ChunkedFitLoop:
         so the fault budget, save cadence, and escalation state are
         stream-wide; the first call restores from the checkpoint (a
         preempted stream resumes where it snapshot)."""
-        st = self._state if self._state is not None \
-            else self._load_state(init, restore)
-        while True:
-            got = self._one_chunk(st, step, None)
-            if got is None:
-                st = self._load_state(init, restore, self._esc.remediation)
-                continue
-            st, hist = got
-            self._commit(st, hist, snapshot)
-            if self._cap_plan is not None and not st.done:
-                st = self._apply_capacity(st, init, restore)
-            self._state = st
-            return st
+        with span(_RUN):
+            st = self._state if self._state is not None \
+                else self._load_state(init, restore)
+            while True:
+                out = self._one_chunk(st, step, None)
+                if out is None:
+                    st = self._load_state(init, restore,
+                                          self._esc.remediation)
+                    continue
+                st = self._commit(out, snapshot)
+                if self._cap_plan is not None and not st.done:
+                    st = self._apply_capacity(st, init, restore)
+                self._state = st
+                return st
 
     @property
     def state(self):

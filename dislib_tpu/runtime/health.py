@@ -53,6 +53,8 @@ import threading
 
 import numpy as np
 
+from dislib_tpu.utils import profiling
+
 __all__ = ["NumericalDivergence", "WatchdogTimeout", "HealthPolicy",
            "ChunkGuard", "Verdict", "Remediation", "NO_REMEDIATION",
            "guard", "health_vec", "check_snapshot", "HEALTH_BASE_LEN"]
@@ -321,8 +323,7 @@ class ChunkGuard:
         t.start()
         t.join(deadline)
         if t.is_alive():
-            from dislib_tpu.utils.profiling import count_resilience
-            count_resilience("watchdog_trips")
+            profiling.count_resilience("watchdog_trips")
             raise WatchdogTimeout(
                 f"{self.name}: chunk {self.chunk_index} force point "
                 f"exceeded its {deadline}s deadline — hung collective or "
@@ -355,8 +356,12 @@ class ChunkGuard:
         else:
             handle = AsyncFetch(hvec)   # copy enqueued before resolution
         try:
-            h = np.asarray(Retry.from_env().call(
-                lambda: self._watched_resolve(handle)), np.float64).ravel()
+            # the chunk's force point: the host blocks here until the
+            # device has run the whole chunk
+            with profiling.span("dslib.fitloop.wait"):
+                h = np.asarray(Retry.from_env().call(
+                    lambda: self._watched_resolve(handle)),
+                    np.float64).ravel()
         finally:
             self._checks_done += 1
         v = self._classify(h, carry_names, carry_shapes, it, increasing)

@@ -10,8 +10,7 @@ through the array module's initialisation.
 from dislib_tpu.utils.checkpoint import FitCheckpoint
 from dislib_tpu.utils.profiling import (
     annotate, counters, dispatch_count, memory_stats, op_graph,
-    profiled_jit, reset_counters, start_trace, stop_trace, trace,
-    trace_count,
+    profiled_jit, reset_counters, span, span_totals, trace, trace_count,
 )
 
 _LAZY_ATTRS = {
@@ -35,7 +34,7 @@ def __getattr__(name):
 
 __all__ = ["shuffle", "train_test_split", "save_model", "load_model",
            "FitCheckpoint",
-           "start_trace", "stop_trace", "trace", "annotate", "op_graph",
+           "trace", "annotate", "span", "span_totals", "op_graph",
            "memory_stats",
            "profiled_jit", "dispatch_count", "trace_count", "counters",
            "reset_counters"]

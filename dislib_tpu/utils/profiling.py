@@ -2,19 +2,29 @@
 
 Reference mechanism: COMPSs `runcompss --tracing` LD_PRELOADs Extrae into
 master+workers and merges Paraver timelines; `--graph` dumps the task DAG.
-dislib code is unmodified — tracing hooks the runtime.
 
-TPU-native equivalent, same layering (estimator code stays unmodified, the
-profiler hooks the runtime):
+TPU-native equivalent: the library marks its own layer boundaries, and
+`jax.profiler` is the one store of what happened when.
 
-- `start_trace(logdir)` / `stop_trace()` / `trace(logdir)` — wrap
-  `jax.profiler`; produces XPlane/Perfetto timelines (per-op HLO, ICI
-  collectives) — the Paraver analog.
-- `annotate(name)` — `jax.named_scope` + `jax.profiler.TraceAnnotation`;
-  user-event markers on both the XLA op names and the host timeline — the
-  Extrae user-events analog.  Estimators wrap their phases with it.
+- `trace(logdir)` — the operator's entry: a `jax.profiler` capture around
+  a block; XPlane/Perfetto timelines (per-op HLO, ICI collectives) — the
+  Paraver analog.
+- `span(name, **stats)` — the HOST span primitive.  The library opens one
+  at every layer boundary of its hot paths (the fit: estimator, fit
+  runtime, the wait for the device, host reads; the product: router,
+  force, wait).  A span is a `jax.profiler.TraceAnnotation`, so while a
+  capture runs it lies on the profiler's clock beside the device ops
+  (name, start, end; its parent is the enclosing span on the thread), and
+  it always adds to a per-name tally `{count, total_s, max_s}`
+  (`span_totals()`), which is what an operator reads with no profiler.
+  There is no switch and no second list of spans.
+- device code is named with `jax.named_scope("dslib.…")` directly: a
+  scope is metadata of the compiled ops (`op_name`), it changes no
+  program.
+- `annotate(name)` — both at once, for user code: `jax.named_scope` +
+  `span` — the Extrae user-events analog.
 - `op_graph(fn, *args)` — compiled-HLO text of a jitted function — the
-  `--graph` task-DAG analog.
+  `--graph` task-DAG analog; the scopes show in its `op_name`s.
 - dispatch/retrace counters (round-7 fusion PR): every library kernel is
   wrapped by :func:`profiled_jit`, which counts one *dispatch* per call
   and one *trace* per (re)compilation.  `dispatch_count()` is how the
@@ -22,40 +32,97 @@ profiler hooks the runtime):
   measured number (and a test assertion), and `trace_count()` is the
   retrace guard — a cache-key regression shows up as extra traces, not
   as a silent 20-second recompile on chip.
+
+Naming rule.  `dslib.<module>.<phase>`, lower case, a fixed string at its
+site: shapes, indices and iteration numbers go into `**stats`, never into
+the name, so a name means the same thing in every trace and every PR.
+PERF.md (section 3) holds the whole vocabulary and the metric that reads
+each name.
+
+One call, one identifier.  The span of a public entry (`dslib.kmeans.fit`,
+`dslib.matmul`) carries ``call=new_call()``.  Spans on the caller's thread
+are its children by nesting, which is every span of today's hot paths.  A
+span opened on ANOTHER thread on behalf of a call is given that call's id
+as a plain ``call=`` stat by the code that starts the thread; no thread of
+the library opens one of its own yet (the chunk watchdog's thread runs
+wholly inside the caller's `dslib.fitloop.wait`).
 """
 
 from __future__ import annotations
 
 import contextlib
 import functools
+import itertools
 import threading
+import time
 
 import jax
 
 
-def start_trace(logdir: str) -> None:
-    """Begin a profiler capture; view with TensorBoard/Perfetto."""
-    jax.profiler.start_trace(logdir)
-
-
-def stop_trace() -> None:
-    jax.profiler.stop_trace()
-
-
 @contextlib.contextmanager
 def trace(logdir: str):
-    """Context-managed capture: ``with dslib.utils.trace('/tmp/tb'): fit()``."""
-    start_trace(logdir)
+    """Context-managed capture: ``with dslib.utils.trace('/tmp/tb'): fit()``;
+    view with TensorBoard/Perfetto.  The library's spans and scopes are in
+    it under their ``dslib.`` names."""
+    jax.profiler.start_trace(logdir)
     try:
         yield
     finally:
-        stop_trace()
+        jax.profiler.stop_trace()
+
+
+_CALL_IDS = itertools.count(1)
+
+
+def new_call() -> int:
+    """A fresh ``call=`` id for the span of a public entry."""
+    return next(_CALL_IDS)
+
+
+class span:
+    """A host span: ``with span("dslib.<module>.<phase>", **stats): ...``.
+
+    Enters a ``jax.profiler.TraceAnnotation(name, **stats)`` (a flag test
+    while no capture runs) and adds the elapsed time to the per-name tally
+    that :func:`span_totals` returns.  ``set(**stats)`` adds stats learned
+    inside the span (the route a router picked).  See the module docstring
+    for the naming rule and the threading rule."""
+
+    __slots__ = ("name", "_ann", "_t0")
+
+    def __init__(self, name: str, **stats):
+        self.name = name
+        self._ann = jax.profiler.TraceAnnotation(name, **stats)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def set(self, **stats) -> None:
+        self._ann.set_metadata(**stats)
+
+    def __exit__(self, exc_type, exc, tb):
+        dt = time.perf_counter() - self._t0
+        self._ann.__exit__(exc_type, exc, tb)
+        with _COUNTERS_LOCK:
+            tally = _COUNTERS.spans.get(self.name)
+            if tally is None:
+                _COUNTERS.spans[self.name] = [1, dt, dt]
+            else:
+                tally[0] += 1
+                tally[1] += dt
+                if dt > tally[2]:
+                    tally[2] = dt
+        return False
 
 
 @contextlib.contextmanager
 def annotate(name: str):
-    """Mark a phase on both the device op names and the host trace timeline."""
-    with jax.named_scope(name), jax.profiler.TraceAnnotation(name):
+    """Mark a phase on both the device op names and the host timeline:
+    ``jax.named_scope(name)`` for what is traced inside, :func:`span` for
+    the host side."""
+    with jax.named_scope(name), span(name):
         yield
 
 
@@ -77,7 +144,7 @@ class _Counters:
     (asserted against the dispatch counters in ``tests/test_fitloop``)."""
 
     __slots__ = ("dispatches", "traces", "transfers", "dispatch_by",
-                 "trace_by", "resilience", "schedules")
+                 "trace_by", "resilience", "schedules", "spans")
 
     def __init__(self):
         self.dispatches = 0
@@ -87,6 +154,7 @@ class _Counters:
         self.trace_by: dict[str, int] = {}
         self.resilience: dict[str, int] = {}
         self.schedules: dict[str, int] = {}
+        self.spans: dict[str, list] = {}    # name -> [count, total_s, max_s]
 
 
 _COUNTERS = _Counters()
@@ -154,6 +222,14 @@ def count_transfer(n: int = 1) -> None:
         _COUNTERS.transfers += n
 
 
+def host_read() -> span:
+    """THE host-read boundary: ``with host_read(): value = <the read>``
+    counts one transfer and bounds the read with the ``dslib.host_read``
+    span, in one helper so that the count and the span cannot part."""
+    count_transfer()
+    return span("dslib.host_read")
+
+
 def transfer_count() -> int:
     """Total host↔device transfers through the library's blessed sync
     boundaries since the last `reset_counters()`."""
@@ -205,6 +281,19 @@ def schedule_counters() -> dict:
         return dict(_COUNTERS.schedules)
 
 
+def span_totals() -> dict:
+    """``{name: {"count", "total_s", "max_s"}}`` of every :func:`span`
+    closed since the last ``reset_counters()`` — the spans' numbers with
+    no profiler running."""
+    with _COUNTERS_LOCK:
+        return _span_rows()
+
+
+def _span_rows() -> dict:       # the caller holds _COUNTERS_LOCK
+    return {name: {"count": c, "total_s": t, "max_s": m}
+            for name, (c, t, m) in _COUNTERS.spans.items()}
+
+
 def dispatch_count() -> int:
     """Total library-kernel dispatches since the last `reset_counters()`."""
     return _COUNTERS.dispatches
@@ -216,8 +305,9 @@ def trace_count() -> int:
 
 
 def counters() -> dict:
-    """Snapshot of the tallies: ``{dispatches, traces, dispatch_by,
-    trace_by}`` with per-kernel-name breakdowns (plain dict copies)."""
+    """Snapshot of the tallies: ``{dispatches, traces, transfers,
+    dispatch_by, trace_by, resilience, schedules, spans}`` with
+    per-kernel-name breakdowns (plain dict copies)."""
     with _COUNTERS_LOCK:
         return {"dispatches": _COUNTERS.dispatches,
                 "traces": _COUNTERS.traces,
@@ -225,11 +315,12 @@ def counters() -> dict:
                 "dispatch_by": dict(_COUNTERS.dispatch_by),
                 "trace_by": dict(_COUNTERS.trace_by),
                 "resilience": dict(_COUNTERS.resilience),
-                "schedules": dict(_COUNTERS.schedules)}
+                "schedules": dict(_COUNTERS.schedules),
+                "spans": _span_rows()}
 
 
 def reset_counters() -> None:
-    """Zero the dispatch/trace tallies (tests and bench regions)."""
+    """Zero every tally, the spans' too (tests and bench regions)."""
     with _COUNTERS_LOCK:
         _COUNTERS.dispatches = 0
         _COUNTERS.traces = 0
@@ -238,6 +329,7 @@ def reset_counters() -> None:
         _COUNTERS.trace_by.clear()
         _COUNTERS.resilience.clear()
         _COUNTERS.schedules.clear()
+        _COUNTERS.spans.clear()
 
 
 def memory_stats():

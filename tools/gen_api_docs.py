@@ -117,7 +117,8 @@ SECTIONS = [
       "TornBundleWrite", "CanaryGateTrip",
       "KillRankAt", "LeaseExpiry", "TornCoordWrite"]),
     ("Profiling", "dislib_tpu.utils.profiling",
-     ["trace", "annotate", "op_graph", "profiled_jit", "dispatch_count",
+     ["trace", "span", "new_call", "span_totals", "annotate", "op_graph",
+      "profiled_jit", "dispatch_count",
       "trace_count", "transfer_count", "counters", "reset_counters",
       "count_resilience", "resilience_counters",
       "count_schedule", "schedule_counters"]),
