@@ -243,6 +243,11 @@ def phase_train(run):
                          "rollbacks": info["rollbacks"]},
             "inertia": float(km.inertia_),
             "centers_max_abs_err_vs_numpy": center_err,
+            # which Lloyd step the fit's traces took: the fused kernel on
+            # a TPU, the two XLA passes under the interpreter's backend
+            "kmeans_step": sorted(
+                key.split(":", 1)[1] for key in prof.schedule_counters()
+                if key.startswith("kmeans_step:")),
             "collectives": collectives}
 
 

@@ -10,18 +10,28 @@ TPU-native redesign (the survey's §4.2 TPU mapping, verbatim):
   + one worker→master sync every iteration; here an iteration is one fused
   XLA program over the row-sharded data.
 - `_partial_sum`'s per-block (distances → argmin → per-cluster Σx/count)
-  becomes: a (m, k) distance matrix via one GEMM (‖x‖² − 2x·cᵀ + ‖c‖²,
-  MXU-bound), argmin, and the per-cluster sums as `onehotᵀ @ x` — another
-  GEMM.  The arity-tree `_merge` is the row-axis partial-sum reduction XLA
-  emits as a `psum` over ICI.  The `arity` knob is gone: reduction topology
-  belongs to the compiler (SURVEY §6).
+  is one Lloyd step, in one of two bodies chosen at trace time from what
+  the trace can observe (`profiling.schedule_counters()` says which, as
+  `kmeans_step:fused` / `kmeans_step:two_pass`).  Fused
+  (`ops/base.py::lloyd_step`): ONE blocked pass over the rows, each block
+  read from HBM once and serving both products — the distance cross term
+  (‖x‖² − 2x·cᵀ + ‖c‖², argmin) and the per-cluster sums `onehotᵀ @ x` —
+  in the Pallas kernel `ops/pallas_kernels.py::kmeans_step`, per row
+  shard under a `shard_map` with one `psum`.  For dense float32 rows on a
+  TPU, `fast_distance` off, k and d within the kernel's vregs and VMEM, d
+  no multiple of 128 (the TPU then holds X features-major, the layout the
+  kernel reads), at least one block of rows a device.  Two-pass, everything
+  else: the (m, k) distance matrix via one GEMM, argmin, and the sums as
+  another GEMM, XLA's own fusions, two reads of X; the arity-tree `_merge`
+  is the row-axis partial-sum reduction XLA emits as a `psum` over ICI.
+  The `arity` knob is gone: reduction topology belongs to the compiler
+  (SURVEY §6).
 - Padded (zero) rows carry weight 0 so they never perturb sums or counts.
-- A Pallas fused E-step kernel was built and benchmarked in round 2 (single
-  pass over x per iteration vs the XLA path's two GEMM reads): 105-111
-  iter/s across tile sizes 512-4096 vs 124 iter/s for this XLA path on the
-  1M×100 k=10 north star (TPU v5e).  XLA's own fusion already wins, so the
-  kernel was deleted (SURVEY §8: "Pallas only where XLA fusion MEASURABLY
-  falls short").
+- History: a fused E-step kernel lost to the two GEMMs in round 2 at 1M×100
+  (105-111 against 124 iter/s) and was deleted.  At 12M×100 both GEMMs are
+  bound by their read of X, 13.95 ms an iteration against 6.97 for the one
+  pass (PERF.md, PR 28); the kernel that came back keeps the rows on the
+  lane axis, which is how the TPU stores such an array.
 """
 
 from __future__ import annotations
@@ -40,6 +50,9 @@ from dislib_tpu.data.array import Array, _repad, ensure_canonical, \
     fused_kernel
 from dislib_tpu.data.sparse import SparseArray, _spmm
 from dislib_tpu.ops import distances_sq as _distances_sq
+from dislib_tpu.ops.base import lloyd_step as _lloyd_step, \
+    lloyd_step_fuses as _lloyd_step_fuses, \
+    load_lloyd_step as _load_lloyd_step
 from dislib_tpu.parallel import mesh as _mesh
 from dislib_tpu.ops.base import precise
 from dislib_tpu.runtime import fetch as _fetch
@@ -47,13 +60,16 @@ from dislib_tpu.runtime import fitloop as _fitloop
 from dislib_tpu.runtime import health as _health
 from dislib_tpu.utils.dlog import verbose_logger
 from dislib_tpu.utils.profiling import profiled_jit as _pjit
+from dislib_tpu.utils.profiling import count_schedule as _count_schedule
 from dislib_tpu.utils.profiling import new_call as _new_call, span as _span
 
 # device scopes of one Lloyd's iteration, shared by the dense and the
-# sparse kernel (PERF.md section 3 holds the vocabulary)
+# sparse kernel (PERF.md section 3 holds the vocabulary); the fused step
+# is assign and the sums of update in one kernel, under a name of its own
 _NORMS = "dslib.kmeans.norms"
 _ASSIGN = "dslib.kmeans.assign"
 _UPDATE = "dslib.kmeans.update"
+_STEP = "dslib.kmeans.step"
 
 
 class KMeans(BaseEstimator):
@@ -295,7 +311,7 @@ class KMeans(BaseEstimator):
 # ---------------------------------------------------------------------------
 
 @partial(_pjit, static_argnames=("shape", "max_iter", "fast"),
-         name="kmeans_fit")
+         name="kmeans_fit", before=_load_lloyd_step)
 @precise
 def _kmeans_fit(xp, shape, centers0, max_iter, tol, fast=False):
     m, n = shape
@@ -309,27 +325,47 @@ def _kmeans_fit(xp, shape, centers0, max_iter, tol, fast=False):
     # input rounding would produce — only the HBM traffic changes).  The
     # center-update GEMM still reads the f32 copy, keeping centers exact.
     with jax.named_scope(_NORMS):
-        x_sq = jnp.sum(xv * xv, axis=1, keepdims=True)
+        x_sq = jnp.sum(xv * xv, axis=1)
     xd = xv.astype(jnp.bfloat16) if fast else xv
+    # one pass over X where the kernel applies, two XLA passes everywhere
+    # else; decided here, once a trace, by what the trace can observe
+    fused = not fast and _lloyd_step_fuses(xv, k)
+    _count_schedule("kmeans_step", "fused" if fused else "two_pass")
+    if fused:
+        # the kernel reads the norms and the weights as rows beside X's
+        # rows on its lanes: a relayout, made once here.  Behind a barrier,
+        # or XLA sinks w (an iota and a compare) into the loop and writes
+        # it anew every iteration
+        x_sq_row, w_row = lax.optimization_barrier(
+            (x_sq[None, :], w[None, :]))
 
-    def step(carry):
-        centers, _, it, _, hist = carry
+    def two_pass(centers):
         with jax.named_scope(_ASSIGN):
             cross = jnp.matmul(xd, centers.astype(xd.dtype).T,
                                precision="default" if fast else None,
                                preferred_element_type=xv.dtype)
             c_sq = jnp.sum(centers * centers, axis=1)
-            d = jnp.maximum(x_sq - 2.0 * cross + c_sq[None, :], 0.0)
+            d = jnp.maximum(x_sq[:, None] - 2.0 * cross + c_sq[None, :], 0.0)
             labels = jnp.argmin(d, axis=1)
         with jax.named_scope(_UPDATE):
             onehot = jax.nn.one_hot(labels, k, dtype=xv.dtype) * w[:, None]
             sums = onehot.T @ xv             # (k, n) — row-axis psum under SPMD
             counts = jnp.sum(onehot, axis=0)     # (k,)
+            inertia = jnp.sum(jnp.min(d, axis=1) * w)
+        return sums, counts, inertia
+
+    def one_pass(centers):
+        with jax.named_scope(_STEP):
+            return _lloyd_step(xv, x_sq_row, w_row, centers)
+
+    def step(carry):
+        centers, _, it, _, hist = carry
+        sums, counts, inertia = (one_pass if fused else two_pass)(centers)
+        with jax.named_scope(_UPDATE):
             new_centers = jnp.where(counts[:, None] > 0,
                                     sums / jnp.maximum(counts, 1.0)[:, None],
                                     centers)
             shift = jnp.sum((new_centers - centers) ** 2)
-            inertia = jnp.sum(jnp.min(d, axis=1) * w)
         return new_centers, shift, it + 1, inertia, hist.at[it].set(inertia)
 
     def cond(carry):

@@ -35,6 +35,14 @@ probe and no fallback schedule.  Everywhere else they run in Pallas
 interpret mode, semantically identical, which keeps the router testable
 on the CPU rig.
 
+``kmeans_step`` is the one kernel here that no schedule option selects:
+it is the dense KMeans fit's pass over the rows wherever its shape test
+holds (``ops/base.py::lloyd_step``), because it halves the traffic of a
+memory-bound step.  It alone does not pad or crop its large operand (any
+op in front of a custom call that is not a bitcast copies all of X: it
+reads X in the layout the chip holds and handles the ragged block
+inside) and does not carry ``vma`` (``lloyd_step`` says why).
+
 Kernels keep the library's precision-lint contract: no hardcoded compute
 dtypes — every cast routes through ``ops/precision`` or derives from a
 value's own dtype.
@@ -46,6 +54,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from dislib_tpu.ops import precision as px
 
@@ -291,3 +300,139 @@ def node_histogram(node, bx, contrib, n_nodes, n_bins, policy=px.FLOAT32):
     # (n, S, n_nodes·n_bins) → the scatter path's (n_nodes, n, n_bins, S)
     return out[:, :s, :nb].reshape(n, s, n_nodes, n_bins) \
         .transpose(2, 0, 3, 1)
+
+
+# -- the fused Lloyd step ----------------------------------------------------
+
+_KM_VMEM_LIMIT = 100 * 2 ** 20       # of the v5e's 128 MiB
+
+
+def kmeans_step(x, x_sq, w, centers, block, chunk):
+    """One Lloyd step's reductions in ONE pass over the rows of ``x``
+    (rows, d): per cluster the weighted sum of its rows ``(k, d)`` and
+    their weight ``(k,)``, and the weighted sum of every row's squared
+    distance to its nearest centre, as ``(sums, counts, inertia)``.
+    ``x_sq`` (1, rows) are the rows' squared norms and ``w`` (1, rows)
+    the row weights, 1 or 0 (0 on padding: the weighted one-hot has to
+    be exact in bfloat16), each a row beside X's rows on the
+    lanes (loop-invariant: the caller makes them once, a relayout of
+    4 bytes a row that inside an iteration loop would run every time),
+    ``centers`` (k, d); ``block`` rows a grid step, a whole number of
+    ``chunk`` rows, itself a multiple of the lane width
+    (``ops/base.py::lloyd_step`` derives both from the shapes).
+
+    The kernel reads ``x`` features-major, ``(d, rows)``: that is how
+    the TPU holds a tall array whose ``d`` is no multiple of the lane
+    width (its compact layout), so the transpose is a bitcast and no
+    copy of X is made.  The grid walks blocks of rows, X's one read
+    from HBM, double-buffered; a rolled loop walks the block's chunks in
+    VMEM.  Everything of a chunk has its rows along the lanes: the
+    distances ``(k, chunk)``, whose argmin runs over sublanes, the cross
+    term ``c . xt_chunk`` and the sums ``onehot . xt_chunk^T``.
+
+    Both products are the float32 policy's 'highest' contraction, its six
+    bfloat16 passes spelled out over ``precision.highest_parts`` so that
+    ONE split of the chunk serves both: the cross term in three matmuls
+    of the centres' stacked parts, one per part of the chunk, summed
+    smallest first; the one-hot is 0 or 1, whose lower parts are zero, so
+    its three passes over the chunk's parts are all six.  Distances are
+    the expansion the two-pass step uses, clamped at zero; ties go to
+    the first minimum as ``jnp.argmin``.  A block's partials leave as
+    they are and are summed outside, so no float32 sum runs serially over
+    more than a block.  A ragged last block reads rows past the end:
+    their ``w`` and ``x_sq`` are padded with zeros here and their ``x``
+    is zeroed in VMEM, on that block alone."""
+    rows, d = x.shape
+    k = centers.shape[0]
+    dt = x.dtype
+    kp = _round_up(k, _sublanes(px.BFLOAT16.compute))
+    nb, nc = -(-rows // block), block // chunk
+    tail = rows % block
+    lane_tiles = chunk // _LANES
+    rows_on_lanes = (((1,), (1,)), ((), ()))     # contract the lanes of both
+
+    def one_pass(a, b, dims=(((1,), (0,)), ((), ()))):
+        return lax.dot_general(a, b, dims, precision=px.ONE_PASS,
+                               preferred_element_type=dt)
+
+    def lane_sums(v):
+        """(r, chunk) -> (r, 128): whole-vreg adds, no cross-lane op."""
+        return jnp.sum(v.reshape(v.shape[0], lane_tiles, _LANES), axis=1)
+
+    def kern(xt_ref, xsq_ref, w_ref, c_ref, sums_ref, cnt_ref, in_ref):
+        if tail:
+            @pl.when(pl.program_id(0) == nb - 1)
+            def _ragged():
+                # rows past the end hold whatever the DMA left: zero
+                # them, 0 * NaN would reach every sum through the MXU
+                a = tail // _LANES * _LANES
+                if a != tail:
+                    keep = lax.broadcasted_iota(jnp.int32, (d, _LANES), 1) \
+                        < tail - a
+                    xt_ref[:, a:a + _LANES] = jnp.where(
+                        keep, xt_ref[:, a:a + _LANES], jnp.zeros((), dt))
+                    a += _LANES
+                if a < block:
+                    xt_ref[:, a:] = jnp.zeros((d, block - a), dt)
+
+        c = c_ref[...]
+        below = lax.broadcasted_iota(jnp.int32, (kp, 1), 0) < k
+        # a padded centre is infinitely far: it never wins the argmin
+        c_sq = jnp.where(below, jnp.sum(c * c, axis=1, keepdims=True),
+                         jnp.full((), jnp.inf, dt))
+        which = lax.broadcasted_iota(jnp.int32, (kp, chunk), 0)
+        # the centres' parts stacked hi, mid, lo: a part of the chunk
+        # meets every part it owes a product in one matmul
+        c3 = jnp.concatenate(px.highest_parts(c), axis=0)
+
+        def body(j, carry):
+            sums, cnt, inertia = carry
+            at = pl.ds(pl.multiple_of(j * chunk, chunk), chunk)
+            xc = xt_ref[:, at]                                   # (d, chunk)
+            hi, mid, lo = px.highest_parts(xc)
+            p_hi, p_mid, p_lo = one_pass(c3, hi), \
+                one_pass(c3[:2 * kp], mid), one_pass(c3[:kp], lo)
+            # c_lo.x_hi + c_mid.x_mid + c_hi.x_lo, then the two middle
+            # products, then c_hi.x_hi: smallest first
+            cross = ((p_hi[2 * kp:] + p_mid[kp:] + p_lo)
+                     + (p_hi[kp:2 * kp] + p_mid[:kp])) + p_hi[:kp]
+            dist = jnp.maximum(xsq_ref[:, at] - 2.0 * cross + c_sq, 0.0)
+            nearest = jnp.min(dist, axis=0, keepdims=True)
+            label = jnp.min(jnp.where(dist == nearest, which, kp),
+                            axis=0, keepdims=True)
+            wj = w_ref[:, at]
+            onehot = jnp.where(which == label, wj, jnp.zeros((), dt))
+            oh = px.to_compute(onehot, px.BFLOAT16)
+            sums += (one_pass(oh, lo, rows_on_lanes)
+                     + one_pass(oh, mid, rows_on_lanes)) \
+                + one_pass(oh, hi, rows_on_lanes)
+            return (sums, cnt + lane_sums(onehot),
+                    inertia + lane_sums(nearest * wj))
+
+        sums_ref[0], cnt_ref[0], in_ref[0] = lax.fori_loop(
+            0, nc, body, (jnp.zeros((kp, d), dt), jnp.zeros((kp, _LANES), dt),
+                          jnp.zeros((1, _LANES), dt)))
+
+    def lanes(v):
+        # to whole blocks; nothing where the blocks divide the rows
+        return _pad2(v, 1, nb * block)
+
+    side = pl.BlockSpec((1, block), lambda i: (0, i))
+    part = lambda *shape: pl.BlockSpec((1,) + shape, lambda i: (i, 0, 0))
+    sums, cnt, inertia = pl.pallas_call(
+        kern,
+        grid=(nb,),
+        in_specs=[pl.BlockSpec((d, block), lambda i: (0, i)), side, side,
+                  pl.BlockSpec((kp, d), lambda i: (0, 0))],
+        out_specs=[part(kp, d), part(kp, _LANES), part(1, _LANES)],
+        out_shape=[jax.ShapeDtypeStruct((nb, kp, d), dt),
+                   jax.ShapeDtypeStruct((nb, kp, _LANES), dt),
+                   jax.ShapeDtypeStruct((nb, 1, _LANES), dt)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_KM_VMEM_LIMIT),
+        interpret=_interpret(),
+        name="dslib_kmeans_step",
+    )(x.T, lanes(x_sq), lanes(w), _pad2(centers, kp, d))
+    return (jnp.sum(sums, axis=0)[:k], jnp.sum(cnt, axis=(0, 2))[:k],
+            jnp.sum(inertia))

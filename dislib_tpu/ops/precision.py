@@ -224,6 +224,46 @@ def _contract(contraction, a, b, policy):
                            preferred_element_type=acc)
 
 
+def highest_parts(x):
+    """The three bfloat16 addends ``(hi, mid, lo)`` of a float32 ``x``,
+    ``hi + mid + lo == x`` to the last bit, eight significant bits each:
+    what a 'highest' contraction feeds the MXU, for a kernel that spells
+    the six passes out itself because ONE split of a tile then serves two
+    products (``pallas_kernels.kmeans_step``).
+
+    ``hi`` is ``x`` rounded to nearest, on the bits: what it leaves then
+    has either sign, and the products a six-pass contraction drops
+    (mid.lo, lo.mid, lo.lo) cancel over a sum instead of adding up one
+    way (PR 27 read 4e-6 of an inertia with ``hi`` cut).  ``mid`` is what
+    is left, cut to its first eight bits: it and ``lo`` take the sign
+    ``hi``'s rounding left, so nothing more is to be had from rounding
+    again, and the cut is one op an element less in a kernel the vector
+    unit bounds (6.97 against 7.12 ms an iteration, PERF.md, PR 28).  No
+    cast to bfloat16 and back does the rounding: XLA, which interprets
+    the kernel on a CPU, folds that round trip away and the lower parts
+    come out zero."""
+    bf16 = jnp.dtype(jnp.bfloat16)
+    top = jnp.uint32(0xFFFF0000)
+
+    def head(v, nearest):
+        bits = jax.lax.bitcast_convert_type(v, jnp.uint32)
+        if nearest:
+            bits = bits + jnp.uint32(0x8000)
+        return jax.lax.bitcast_convert_type(bits & top, v.dtype)
+
+    x = f32(x)
+    hi = head(x, nearest=True)
+    rest = x - hi
+    mid = head(rest, nearest=False)
+    return hi.astype(bf16), mid.astype(bf16), (rest - mid).astype(bf16)
+
+
+# one MXU pass of bfloat16 operands with float32 accumulation, stated on
+# the dot: inside a `precise` scope an unstated precision asks for six
+# passes of operands that hold no more bits than one
+ONE_PASS = jax.lax.Precision.DEFAULT
+
+
 def precise(fn):
     """Trace-time float32-faithful matmul scope for library kernels.
 

@@ -3,14 +3,27 @@
 The directory is part of the cache key, so it must not move between
 processes or runs: ``JAX_COMPILATION_CACHE_DIR`` when the environment sets
 it (JAX reads that variable itself, and no code here sets another), else
-``<checkout>/.jax_cache`` next to the package.  ``chip_smoke.py`` and
-``bench.py`` both call :func:`enable`; side files that must be shared
-between a run's processes (bench setup caches) follow :func:`resolve_dir`.
+``<checkout>/.jax_cache`` next to the package.  ``chip_smoke.py``,
+``bench.py`` and the benchmark's harness call :func:`enable`; side files
+that must be shared between a run's processes (bench setup caches) follow
+:func:`resolve_dir`.
+
+The checkout's own directory must not be part of a key either.  JAX
+hashes a lowered module with its debug locations stripped, so an XLA-only
+program's key holds no path; but a Pallas TPU kernel travels inside a
+custom call as serialized Mosaic bytecode that was written WITH its
+locations, out of reach of that strip pass, and each location names the
+source file by its absolute path.  :func:`enable` therefore has JAX name
+every file under the checkout relative to it
+(``jax_hlo_source_file_canonicalization_regex``, which Pallas' lowering
+applies to its locations too): the same tree, unpacked anywhere, then
+finds what it compiled.
 """
 
 from __future__ import annotations
 
 import os
+import re
 
 __all__ = ["resolve_dir", "enable"]
 
@@ -25,7 +38,8 @@ def resolve_dir() -> str:
 
 
 def enable() -> str:
-    """Turn the persistent cache on at :func:`resolve_dir` and return the
+    """Turn the persistent cache on at :func:`resolve_dir`, name source
+    files relative to the checkout (module docstring) and return the
     directory.  Call before the first compile (it initialises the
     backend).  With the environment variable set the directory is
     already JAX's own; no code sets another.
@@ -37,6 +51,12 @@ def enable() -> str:
     runs are tests and dry runs whose compiles take seconds."""
     import jax
     path = resolve_dir()
+    # before the CPU's early return: it only names files, and a program
+    # lowered here for the TPU then hashes as it does on the chip.  A
+    # value the user has set stays
+    if not jax.config.jax_hlo_source_file_canonicalization_regex:
+        jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                          "^" + re.escape(_CHECKOUT + os.sep))
     if jax.default_backend() == "cpu":
         jax.config.update("jax_enable_compilation_cache", False)
         return path

@@ -52,6 +52,8 @@ def test_rehearsal_runs_every_phase_stamped(tmp_path):
     assert not cache.exists() or not any(cache.iterdir())
     assert by["train"]["fit_info"] == {"chunks": 2, "rollbacks": 0}
     assert by["train"]["collectives"]["all-reduce"] >= 1
+    # the interpreter's backend keeps the two XLA passes; a TPU reads "fused"
+    assert by["train"]["kmeans_step"] == ["two_pass"]
     assert by["serve"]["traces_after_start"] == 0
     assert by["serve"]["dispatches_per_batch_max"] == 1
     assert by["bundle"]["traces_after_load"] == 0
