@@ -3,12 +3,14 @@
 
 One process, which holds the chip for the whole run; no child touches JAX.
 It drives the library boundary a user drives — ingest, scaler, a
-checkpointed KMeans fit, the predict server, a deployment bundle, and the
-three Pallas kernels inside their callers — at the full width of the
-north-star model of ``BASELINE.json`` (KMeans 1 000 000 x 100, k=10), and
-checks every result by the repo's own means (a NumPy Lloyd oracle, the
-direct predict path, bit-equality across a bundle round trip, the XLA
-schedule of each kernel inside ``ops/precision.ERROR_BOUNDS``).
+checkpointed KMeans fit, a Gaussian mixture fit, the predict server, a
+deployment bundle, and the three Pallas kernels inside their callers — at
+the full width of the north-star model of ``BASELINE.json`` (KMeans
+1 000 000 x 100, k=10) and of its mixture (1 000 000 x 50, k=16), and
+checks every result by the repo's own means (a NumPy Lloyd oracle, a NumPy
+EM oracle, the direct predict path, bit-equality across a bundle round
+trip, the XLA schedule of each kernel inside
+``ops/precision.ERROR_BOUNDS``).
 
     python chip_smoke.py              # on a TPU: exit 0 and the result line
     python chip_smoke.py --rehearsal  # CPU dry run: tiny size, Pallas
@@ -48,15 +50,16 @@ import numpy as np
 FULL = dict(m=1_000_000, n=100, k=10, buckets=(1, 8, 64, 512),
             request_rows=(1, 3, 8, 17, 64, 200, 512), n_requests=36,
             summa_panel=8192, ring=(200_000, 10), forest=(100_000, 20),
-            forest_trees=4, forest_nodes=8)
+            forest_trees=4, forest_nodes=8, mixture=(1_000_000, 50, 16))
 # the same phases at a size the CPU backend and the Pallas interpreter
 # finish in seconds
 REHEARSAL = dict(m=4_000, n=20, k=4, buckets=(1, 8),
                  request_rows=(1, 3, 8), n_requests=9,
                  summa_panel=128, ring=(96, 5), forest=(200, 3),
-                 forest_trees=2, forest_nodes=4)
+                 forest_trees=2, forest_nodes=4, mixture=(4_000, 10, 3))
 REHEARSAL_DEVICES = 4           # mirrors the four-chip host
 GATE_TOL = 2e-3                 # the bench gate: device vs NumPy Lloyd
+EM_GATE_TOL = 1e-4              # device vs NumPy EM (float64), two iterations
 BALANCE_MAX = 1.5               # per-device peak bytes, max over min
 
 
@@ -72,6 +75,37 @@ def lloyd_step(x, centers):
     sums = onehot.T @ x
     return np.where(counts[:, None] > 0,
                     sums / np.maximum(counts, 1)[:, None], centers)
+
+
+def e_step(x, weights, means, covs):
+    """The E-step of a NumPy EM with full covariances, in float64:
+    ``(log pi_j N(x | mu_j, Sigma_j), log sum_j of it)`` per row."""
+    d = x.shape[1]
+    logp = np.empty((x.shape[0], len(weights)))
+    for j, (w, mu, cov) in enumerate(zip(weights, means, covs)):
+        prec = np.linalg.inv(np.linalg.cholesky(cov)).T     # upper, P P^T
+        y = (x - mu) @ prec
+        logp[:, j] = np.log(w) + np.log(np.diag(prec)).sum() \
+            - 0.5 * d * np.log(2 * np.pi) - 0.5 * np.einsum("ij,ij->i", y, y)
+    top = logp.max(1, keepdims=True)
+    return logp, top[:, 0] + np.log(np.exp(logp - top).sum(1))
+
+
+def em_step(x, weights, means, covs, reg_covar=1e-6):
+    """One NumPy EM iteration with full covariances, in float64 — the
+    reference the device mixture fit is held to: ``(weights, means, covs,
+    lower bound at the parameters that came in)``."""
+    n, d = x.shape
+    logp, lse = e_step(x, weights, means, covs)
+    resp = np.exp(logp - lse[:, None])
+    nk = resp.sum(0) + 1e-10
+    new_means = resp.T @ x / nk[:, None]
+    new_covs = np.empty((len(weights), d, d))
+    for j, (mu, n_j) in enumerate(zip(new_means, nk)):
+        diff = x - mu
+        new_covs[j] = (diff * resp[:, j:j + 1]).T @ diff / n_j \
+            + reg_covar * np.eye(d)
+    return nk / n, new_means, new_covs, lse.mean()
 
 
 class Run:
@@ -249,6 +283,94 @@ def phase_train(run):
                 key.split(":", 1)[1] for key in prof.schedule_counters()
                 if key.startswith("kmeans_step:")),
             "collectives": collectives}
+
+
+def phase_mixture(run):
+    """``GaussianMixture`` with full covariances at the width of
+    ``BASELINE.json``'s configuration (1M x 50, k = 16): two EM iterations
+    from an explicit start against NumPy's in float64, ``score`` and
+    ``predict`` through the same blocked E-step, the step the program's
+    traces took, and on several devices its one all-reduce a step."""
+    import jax
+    import jax.numpy as jnp
+    import dislib_tpu as ds
+    from dislib_tpu.cluster.gm import _gm_fit
+    from dislib_tpu.ops.base import em_block
+    from dislib_tpu.utils import profiling as prof
+
+    n_dev = run.stamp["n_devices"]
+    m, d, k = run.cfg["mixture"]
+    rng = np.random.RandomState(1)
+    mu = 2.0 * rng.rand(k, d)
+    factors = np.eye(d) + rng.randn(k, d, d) / (2.0 * np.sqrt(d))
+    comp = rng.randint(0, k, m)
+    x_host = np.empty((m, d), np.float32)
+    for j in range(k):
+        at = comp == j
+        x_host[at] = mu[j] + rng.randn(int(at.sum()), d) @ factors[j]
+    x = ds.array(x_host)
+    local = x._data.shape[0] // n_dev
+    block = em_block(local, d, k)
+    start = dict(weights_init=np.full(k, 1.0 / k, np.float32),
+                 means_init=(mu + 0.5 * rng.randn(k, d)).astype(np.float32),
+                 precisions_init=np.tile(np.eye(d, dtype=np.float32),
+                                         (k, 1, 1)))
+
+    prof.reset_counters()
+
+    def fit():
+        return ds.GaussianMixture(n_components=k, max_iter=2, tol=0.0,
+                                  **start).fit(x)
+    gm, first_s = _wall(fit)
+    _, warm_s = _wall(fit)
+    steps = sorted(key.split(":", 1)[1] for key in prof.schedule_counters()
+                   if key.startswith("gm_step:"))
+
+    want = (start["weights_init"].astype(np.float64),
+            start["means_init"].astype(np.float64),
+            np.tile(np.eye(d), (k, 1, 1)))
+    x64, bounds = x_host.astype(np.float64), []
+    for _ in range(2):
+        *want, bound = em_step(x64, *want)
+        bounds.append(bound)
+    np.testing.assert_allclose(gm.history_, bounds, rtol=EM_GATE_TOL)
+    np.testing.assert_allclose(gm.weights_, want[0], rtol=EM_GATE_TOL,
+                               atol=EM_GATE_TOL)
+    np.testing.assert_allclose(gm.means_, want[1], rtol=EM_GATE_TOL,
+                               atol=EM_GATE_TOL)
+    np.testing.assert_allclose(gm.covariances_, want[2], rtol=EM_GATE_TOL,
+                               atol=EM_GATE_TOL)
+    logp, lse = e_step(x64, *want)
+    bound, labels = lse.mean(), logp.argmax(1)
+    assert abs(gm.score(x) - bound) <= EM_GATE_TOL * abs(bound)
+    got = gm.predict(x).collect().ravel()
+    agree = float(np.mean(got == labels))
+    assert agree >= 0.9999, f"predict agrees with NumPy on {agree} of rows"
+
+    collectives = None
+    if n_dev > 1:
+        hlo = _gm_fit.lower(
+            x._data, x.shape, k, "full", 1e-6, 0.0, 2,
+            tuple(jnp.asarray(a) for a in (gm.weights_, gm.means_,
+                                           gm.covariances_))
+        ).compile().as_text()
+        assert "all-gather" not in hlo and "all-to-all" not in hlo, \
+            "the compiled mixture fit gathers"
+        collectives = {"all-reduce": hlo.count(" all-reduce(")
+                       + hlo.count(" all-reduce-start(")}
+        assert collectives["all-reduce"] == 1, collectives
+    return {"first_call_s": first_s, "warm_call_s": warm_s,
+            "shape": [m, d], "k": k, "block_rows": block,
+            "ragged_last_block": bool(local % block),
+            "lower_bound": float(gm.lower_bound_),
+            "means_max_abs_err_vs_numpy":
+                float(np.abs(gm.means_ - want[1]).max()),
+            "covariances_max_abs_err_vs_numpy":
+                float(np.abs(gm.covariances_ - want[2]).max()),
+            "predict_agreement": agree,
+            "gm_step": steps, "collectives": collectives,
+            "peak_balance_after_fit": _balanced(run.peak_bytes(),
+                                                "after the mixture fit")}
 
 
 def phase_serve(run):
@@ -508,6 +630,7 @@ def main(argv=None) -> int:
     run.state["cache_dir"] = cache_dir
     run.phase("device", phase_device)
     run.phase("train", phase_train)
+    run.phase("mixture", phase_mixture)
     run.phase("serve", phase_serve)
     run.phase("bundle", phase_bundle)
     run.phase("kernels", phase_kernels)

@@ -4,12 +4,14 @@ component, per-iteration host sync on log-likelihood; SURVEY.md §3.3,
 BASELINE config 5).
 
 TPU-native redesign, same shape as KMeans (§4.2 mapping): the whole EM loop
-is one jitted `lax.while_loop` on device.  The E-step's per-block
-log-prob/responsibility tasks become batched GEMMs over the row-sharded data
-(the Mahalanobis term is one (m, d) @ (d, d) matmul per component, vmapped);
-the M-step's arity-tree partial sums (weights / means / covariances) are the
-row-axis reductions XLA lowers to `psum` over ICI.  Convergence on the
-log-likelihood delta happens on device; the host syncs once per fit.
+is one jitted `lax.while_loop` on device.  An iteration is the k Cholesky
+factorisations, ONE blocked pass over the row-sharded data
+(`ops/base.py::em_step`: per block of rows the E-step's whitened
+differences, as one GEMM for the k components, and the M-step's sums about
+the old means; no (m, k, d) and no (m, k) array exists) ended by one packed
+`psum` over ICI, and the closing arithmetic on (k, d, d) numbers.
+Convergence on the log-likelihood delta happens on device; the host syncs
+once per fit.  `score` and `predict` run the same blocked E-step.
 
 All four covariance types of the reference are supported: full, tied, diag,
 spherical.  Padded (zero) rows carry weight 0 everywhere.
@@ -27,14 +29,19 @@ from jax import lax
 from dislib_tpu.base import BaseEstimator
 from dislib_tpu.data.array import Array, ensure_canonical, fused_kernel
 from dislib_tpu.parallel import mesh as _mesh
+from dislib_tpu.ops import base as _ops
 from dislib_tpu.ops.base import precise
 from dislib_tpu.utils.profiling import profiled_jit as _pjit
+from dislib_tpu.utils.profiling import count_schedule as _count_schedule
+from dislib_tpu.utils.profiling import new_call as _new_call, span as _span
 from dislib_tpu.runtime import fetch as _fetch
 from dislib_tpu.runtime import fitloop as _fitloop
 from dislib_tpu.runtime import health as _health
 from dislib_tpu.utils.dlog import verbose_logger
 
-_LOG2PI = float(np.log(2.0 * np.pi))
+# the span and scope names of this module (PERF.md, section 3)
+_FIT, _INIT = "dslib.gm.fit", "dslib.gm.init"
+_CHOL, _CLOSE = "dslib.gm.chol", "dslib.gm.close"
 
 
 class GaussianMixture(BaseEstimator):
@@ -79,30 +86,31 @@ class GaussianMixture(BaseEstimator):
 
     # ------------------------------------------------------------------
 
-    def _init_resp(self, x: Array):
-        """Initial responsibilities (m_pad, k) — hard KMeans labels or random."""
-        m, n = x.shape
-        k = self.n_components
+    def _start(self, x: Array, overrides):
+        """What the program makes its first parameters from, or None when
+        ``overrides`` (the explicit ``*_init``) leave nothing to make:
+        hard KMeans labels and their centres, or the key of a seeded
+        random draw.  Device dispatch only, and nothing of size (m, k):
+        the responsibilities themselves are made block by block inside the
+        program's first pass over the rows (``ops/base.py::em_start``)."""
+        if all(o is not None for o in overrides):
+            return None
         if self.init_params == "kmeans":
             # run the KMeans device kernels directly so the init stays on
             # device end-to-end — no host read between here and the EM loop
             # (keeps `_fit_async` dispatch-only for GridSearchCV, SURVEY §4.5)
             from dislib_tpu.cluster.kmeans import (KMeans, _kmeans_fit,
                                                    _kmeans_predict)
-            km = KMeans(n_clusters=k, max_iter=10, tol=1e-4,
+            km = KMeans(n_clusters=self.n_components, max_iter=10, tol=1e-4,
                         random_state=self.random_state)
             centers = _kmeans_fit(x._data, x.shape, km._init_centers(x),
                                   10, 1e-4, fast=km._fast())[0]
-            labels = _kmeans_predict(x._data, x.shape, centers)[:, 0]
-            resp = jax.nn.one_hot(labels, k, dtype=jnp.float32)
-        elif self.init_params == "random":
+            return {"centers": centers,
+                    "labels": _kmeans_predict(x._data, x.shape, centers)}
+        if self.init_params == "random":
             seed = 0 if self.random_state is None else int(self.random_state)
-            resp = jax.random.uniform(jax.random.PRNGKey(seed),
-                                      (x._data.shape[0], k), dtype=jnp.float32)
-            resp = resp / jnp.sum(resp, axis=1, keepdims=True)
-        else:
-            raise ValueError(f"unsupported init_params {self.init_params!r}")
-        return resp
+            return {"key": jax.random.PRNGKey(seed)}
+        raise ValueError(f"unsupported init_params {self.init_params!r}")
 
     def fit(self, x: Array, y=None, checkpoint=None, health=None):
         """Fit by EM.  With ``checkpoint=FitCheckpoint(path, every=k)`` the
@@ -117,89 +125,99 @@ class GaussianMixture(BaseEstimator):
         ``halve`` action additionally doubles ``reg_covar`` per restart
         (the EM damping knob — a collapsing component's singular
         covariance is the classic EM failure)."""
-        if self.covariance_type not in ("full", "tied", "diag", "spherical"):
+        self._check_params()
+        with _span(_FIT, call=_new_call()):
+            m, n = x.shape
+            box = {"x": x, "reg_covar": float(self.reg_covar), "start": None,
+                   "lb": None}
+            log = verbose_logger("gm", self.verbose)
+            loop = _fitloop.ChunkedFitLoop(
+                "gm", checkpoint=checkpoint, health=health,
+                max_iter=self.max_iter,
+                increasing=True,            # EM lower bound must not fall
+                carry_names=("weights", "means", "covariances"),
+                carry_shapes=((self.n_components,), (self.n_components, n)),
+                snapshot_expect={"weights": (self.n_components,),
+                                 "means": (self.n_components, n)},
+                elastic=_fitloop.data_rebind(box))
+
+            def init(rem):
+                # EM damping: the 'halve' escalation tier raises the
+                # covariance ridge per tier attempt, the standard fix for a
+                # component collapsing onto a point (singular covariance→NaN)
+                box["reg_covar"] = float(self.reg_covar) * rem.damping
+                with _span(_INIT):
+                    overrides = self._explicit_inits(n)
+                    box["start"] = self._start(box["x"], overrides)
+                box["lb"] = None
+                return _fitloop.LoopState(overrides)
+
+            def restore(snap, rem):
+                # resume: all three parameters come from the snapshot, so
+                # there is no start to make and nothing of the rows' size
+                box["reg_covar"] = float(self.reg_covar) * rem.damping
+                box["start"] = None
+                # weights/means compatibility is declared via snapshot_expect
+                # and judged by the rollback funnel
+                ov = tuple(jnp.asarray(rem.perturb(snap[k])) for k in
+                           ("weights", "means", "covariances"))
+                box["lb"] = float(snap["lower_bound"])
+                return _fitloop.LoopState(
+                    ov, it=int(snap["n_iter"]),
+                    done=bool(snap.get("converged", False)))
+
+            def step(st, chunk):
+                xd = box["x"]
+                # the start serves the first chunk alone: from then on the
+                # carries hold all three parameters
+                start, box["start"] = box["start"], None
+                weights, means, covs, lb_dev, n_done, conv, hist, hvec = \
+                    _gm_fit(xd._data, xd.shape, self.n_components,
+                            self.covariance_type, box["reg_covar"],
+                            float(self.tol), chunk, st.carries,
+                            prev_lb0=box["lb"], start=start)
+
+                def commit():
+                    # deferred scalar syncs: the watchdogged hvec read stays
+                    # the chunk's first force point
+                    box["lb"] = float(lb_dev)
+                    it = st.it + int(n_done)
+                    log.info("iter %d: lower_bound=%.6g", it, box["lb"])
+                    return _fitloop.LoopState((weights, means, covs), it,
+                                              bool(conv))
+
+                return _fitloop.ChunkOutcome(
+                    commit, hvec=hvec,
+                    history=lambda: _fetch(hist)[: int(n_done)])
+
+            def snapshot(st):
+                # the EM parameters are DONATED to the next chunk's kernel
+                # (HBM reused in place), so their device->host copies are
+                # blocking; the checksum+file write still overlaps the next
+                # chunk on the snapshot worker
+                weights, means, covs = st.carries
+                return {"weights": _fetch(weights), "means": _fetch(means),
+                        "covariances": _fetch(covs), "lower_bound": box["lb"],
+                        "n_iter": st.it, "converged": st.done}
+
+            st = loop.run(init=init, step=step, restore=restore,
+                          snapshot=snapshot)
+            weights, means, covs = st.carries
+            self.weights_ = _fetch(weights)
+            self.means_ = _fetch(means)
+            self.covariances_ = _fetch(covs)
+            self.lower_bound_ = box["lb"] if box["lb"] is not None else -np.inf
+            self.n_iter_ = st.it
+            self.converged_ = st.done
+            self.history_ = np.asarray(loop.history, dtype=np.float64)
+            self.fit_info_ = loop.info
+            return self
+
+    def _check_params(self):
+        if self.covariance_type not in _ops.COVARIANCE_TYPES:
             raise ValueError(f"bad covariance_type {self.covariance_type!r}")
         if self.max_iter < 1:
             raise ValueError("max_iter must be >= 1")
-        m, n = x.shape
-        box = {"x": x, "reg_covar": float(self.reg_covar), "resp0": None,
-               "lb": None}
-        log = verbose_logger("gm", self.verbose)
-        loop = _fitloop.ChunkedFitLoop(
-            "gm", checkpoint=checkpoint, health=health,
-            max_iter=self.max_iter,
-            increasing=True,            # EM lower bound must not fall
-            carry_names=("weights", "means", "covariances"),
-            carry_shapes=((self.n_components,), (self.n_components, n)),
-            snapshot_expect={"weights": (self.n_components,),
-                             "means": (self.n_components, n)},
-            elastic=_fitloop.data_rebind(box))
-
-        def init(rem):
-            # EM damping: the 'halve' escalation tier raises the
-            # covariance ridge per tier attempt, the standard fix for a
-            # component collapsing onto a point (singular covariance→NaN)
-            box["reg_covar"] = float(self.reg_covar) * rem.damping
-            box["resp0"] = self._init_resp(box["x"])
-            box["lb"] = None
-            return _fitloop.LoopState(self._explicit_inits(n))
-
-        def restore(snap, rem):
-            # resume: all three parameters come from the snapshot, so skip
-            # the (KMeans-based) responsibility init entirely
-            box["reg_covar"] = float(self.reg_covar) * rem.damping
-            box["resp0"] = jnp.zeros((box["x"]._data.shape[0],
-                                      self.n_components), jnp.float32)
-            # weights/means compatibility is declared via snapshot_expect
-            # and judged by the rollback funnel
-            ov = tuple(jnp.asarray(rem.perturb(snap[k])) for k in
-                       ("weights", "means", "covariances"))
-            box["lb"] = float(snap["lower_bound"])
-            return _fitloop.LoopState(ov, it=int(snap["n_iter"]),
-                                      done=bool(snap.get("converged", False)))
-
-        def step(st, chunk):
-            xd = box["x"]
-            weights, means, covs, lb_dev, n_done, conv, hist, hvec = _gm_fit(
-                xd._data, xd.shape, box["resp0"], self.covariance_type,
-                box["reg_covar"], float(self.tol), chunk, st.carries,
-                prev_lb0=box["lb"])
-
-            def commit():
-                # deferred scalar syncs: the watchdogged hvec read stays
-                # the chunk's first force point
-                box["lb"] = float(lb_dev)
-                it = st.it + int(n_done)
-                log.info("iter %d: lower_bound=%.6g", it, box["lb"])
-                return _fitloop.LoopState((weights, means, covs), it,
-                                          bool(conv))
-
-            return _fitloop.ChunkOutcome(
-                commit, hvec=hvec,
-                history=lambda: _fetch(hist)[: int(n_done)])
-
-        def snapshot(st):
-            # the EM parameters are DONATED to the next chunk's kernel
-            # (HBM reused in place), so their device->host copies are
-            # blocking; the checksum+file write still overlaps the next
-            # chunk on the snapshot worker
-            weights, means, covs = st.carries
-            return {"weights": _fetch(weights), "means": _fetch(means),
-                    "covariances": _fetch(covs), "lower_bound": box["lb"],
-                    "n_iter": st.it, "converged": st.done}
-
-        st = loop.run(init=init, step=step, restore=restore,
-                      snapshot=snapshot)
-        weights, means, covs = st.carries
-        self.weights_ = np.asarray(jax.device_get(weights))
-        self.means_ = np.asarray(jax.device_get(means))
-        self.covariances_ = np.asarray(jax.device_get(covs))
-        self.lower_bound_ = box["lb"] if box["lb"] is not None else -np.inf
-        self.n_iter_ = st.it
-        self.converged_ = st.done
-        self.history_ = np.asarray(loop.history, dtype=np.float64)
-        self.fit_info_ = loop.info
-        return self
 
     def score(self, x: Array, y=None) -> float:
         """Mean per-sample log-likelihood under the fitted mixture (sklearn
@@ -214,28 +232,25 @@ class GaussianMixture(BaseEstimator):
     # KMeans init — is device dispatch only; GridSearchCV reads nothing back
     # until every trial is in flight
     def _fit_async(self, x, y=None):
-        if self.covariance_type not in ("full", "tied", "diag", "spherical"):
-            raise ValueError(f"bad covariance_type {self.covariance_type!r}")
-        if self.max_iter < 1:
-            raise ValueError("max_iter must be >= 1")
-        resp0 = self._init_resp(x)
+        self._check_params()
         overrides = self._explicit_inits(x.shape[1])
-        return _gm_fit(x._data, x.shape, resp0, self.covariance_type,
-                       float(self.reg_covar), float(self.tol), self.max_iter,
-                       overrides)
+        return _gm_fit(x._data, x.shape, self.n_components,
+                       self.covariance_type, float(self.reg_covar),
+                       float(self.tol), self.max_iter, overrides,
+                       start=self._start(x, overrides))
 
     def _fit_finalize(self, state):
         if state is None:
             return
         weights, means, covs, lb, n_iter, conv, hist, _ = state
-        self.weights_ = np.asarray(jax.device_get(weights))
-        self.means_ = np.asarray(jax.device_get(means))
-        self.covariances_ = np.asarray(jax.device_get(covs))
+        self.weights_ = _fetch(weights)
+        self.means_ = _fetch(means)
+        self.covariances_ = _fetch(covs)
         self.lower_bound_ = float(lb)
         self.n_iter_ = int(n_iter)
         self.converged_ = bool(conv)
         self.history_ = np.asarray(
-            jax.device_get(hist), dtype=np.float64)[: self.n_iter_]
+            _fetch(hist), dtype=np.float64)[: self.n_iter_]
 
     def _score_async(self, state, x, y=None):
         if state is None:
@@ -303,126 +318,80 @@ def _chol_precisions(covs, cov_type, d):
     return 1.0 / jnp.sqrt(covs)
 
 
-def _log_prob(xv, means, prec, cov_type, d):
-    """Weighted log N(x | mu_k, Sigma_k): (m, k)."""
+def _close(nk, s, second, about, m, cov_type, reg_covar):
+    """Weights, means and covariances from the sums of one blocked pass
+    (``ops/base.py::em_step``), which are taken about the points ``about``
+    (the old means): ``mu_j = a_j + s_j / n_j`` and ``Sigma_j = S_j / n_j
+    - (s_j / n_j)(s_j / n_j)^T + reg_covar I``, for tied covariances
+    summed over j and averaged, for diag and spherical on the diagonal."""
+    d = about.shape[1]
+    nk = nk + 1e-10
+    moved = s / nk[:, None]
+    eye = jnp.eye(d, dtype=about.dtype)
     if cov_type == "full":
-        # maha_ik = ‖x_i P_k − μ_k P_k‖², expanded so no (k, m, d) DIFF
-        # intermediate materialises in HBM: the batched GEMM z = x @ P_k is
-        # the only (k, m, d) tensor, and the square-sum + dot against
-        # t_k = μ_k P_k fuse into its single read-back.  Same cancellation
-        # profile as ops.distances_sq (clamped at zero).
-        def per_comp(mu, pc):
-            z = xv @ pc                                       # (m, d) GEMM
-            t = mu @ pc                                       # (d,)
-            maha = jnp.maximum(
-                jnp.sum(z * z, axis=1) - 2.0 * (z @ t) + t @ t, 0.0)
-            return maha, jnp.sum(jnp.log(jnp.diag(pc)))
-        maha, logdet = jax.vmap(per_comp)(means, prec)
-        return -0.5 * (d * _LOG2PI + maha.T) + logdet[None, :]
-    if cov_type == "tied":
-        y = xv @ prec                                         # (m, d)
-        mu_p = means @ prec                                   # (k, d)
-        maha = (jnp.sum(y * y, axis=1)[:, None] - 2.0 * y @ mu_p.T
-                + jnp.sum(mu_p * mu_p, axis=1)[None, :])
-        logdet = jnp.sum(jnp.log(jnp.diag(prec)))
-        return -0.5 * (d * _LOG2PI + maha) + logdet
-    if cov_type == "diag":
-        p2 = prec * prec                                      # (k, d)
-        maha = ((xv * xv) @ p2.T - 2.0 * xv @ (means * p2).T
-                + jnp.sum(means * means * p2, axis=1)[None, :])
-        logdet = jnp.sum(jnp.log(prec), axis=1)
-        return -0.5 * (d * _LOG2PI + maha) + logdet[None, :]
-    # spherical
-    p2 = prec * prec                                          # (k,)
-    sq = (jnp.sum(xv * xv, axis=1)[:, None] - 2.0 * xv @ means.T
-          + jnp.sum(means * means, axis=1)[None, :])
-    maha = sq * p2[None, :]
-    logdet = d * jnp.log(prec)
-    return -0.5 * (d * _LOG2PI + maha) + logdet[None, :]
-
-
-def _estimate_covs(xv, resp, nk, means, cov_type, reg_covar, w):
-    """M-step covariance update; resp already includes the row mask."""
-    d = xv.shape[1]
-    if cov_type == "full":
-        # √r-weighted single intermediate: wd = √r_k (x − μ_k) makes the
-        # covariance wdᵀwd — symmetric PSD by construction, and only ONE
-        # (k, m, d) tensor reaches HBM (the diff and the weighting fuse
-        # into its materialisation) instead of the two that diff-then-
-        # weight would write.  r_k ≥ 0 always (responsibilities × mask).
-        def per_comp(r_k, mu, n_k):
-            wd = (xv - mu[None, :]) * jnp.sqrt(r_k)[:, None]
-            return wd.T @ wd / n_k + reg_covar * jnp.eye(d, dtype=xv.dtype)
-        return jax.vmap(per_comp)(resp.T, means, nk)
-    if cov_type == "tied":
-        # Σ_total = XᵀWX - Σ_k n_k μ_k μ_kᵀ, averaged
-        xw = xv * w[:, None]
-        avg_x2 = xw.T @ xv
-        avg_mu2 = (means * nk[:, None]).T @ means
-        cov = (avg_x2 - avg_mu2) / jnp.sum(nk)
-        return cov + reg_covar * jnp.eye(d, dtype=xv.dtype)
-    if cov_type == "diag":
-        avg_x2 = resp.T @ (xv * xv) / nk[:, None]
-        cov = avg_x2 - means * means
-        return cov + reg_covar
-    # spherical: mean of diag variances
-    avg_x2 = resp.T @ (xv * xv) / nk[:, None]
-    var = jnp.mean(avg_x2 - means * means, axis=1)
-    return var + reg_covar
+        covs = second / nk[:, None, None] \
+            - moved[:, :, None] * moved[:, None, :]
+        covs = 0.5 * (covs + jnp.swapaxes(covs, 1, 2)) + reg_covar * eye
+    elif cov_type == "tied":
+        covs = (second - jnp.einsum("jp,jq->pq", s, moved)) / jnp.sum(nk)
+        covs = 0.5 * (covs + covs.T) + reg_covar * eye
+    else:
+        covs = second / nk[:, None] - moved * moved
+        if cov_type == "spherical":
+            covs = jnp.mean(covs, axis=1)
+        covs = covs + reg_covar
+    return nk / m, about + moved, covs
 
 
 # `overrides` (the chunked/resumed EM parameter carries) is DONATED: XLA
 # aliases weights/means/covs to their updated outputs and reuses the HBM
-# in place across chunks; the (m, k) responsibilities never leave the
-# device program at all (e_step -> m_step fuse inside the while_loop).
+# in place across chunks.  Responsibilities exist for one block of rows
+# at a time, inside `em_step`'s pass, and never as an (m, k) array: not
+# as an argument either, the start (`start`) is KMeans labels or a key.
 # Callers never reuse a passed overrides tuple afterwards.
-@partial(_pjit, static_argnames=("shape", "cov_type", "max_iter"),
+@partial(_pjit, static_argnames=("shape", "k", "cov_type", "max_iter"),
          donate_argnames=("overrides",), name="gm_fit")
 @precise
-def _gm_fit(xp, shape, resp0, cov_type, reg_covar, tol, max_iter,
-            overrides=(None, None, None), prev_lb0=None):
+def _gm_fit(xp, shape, k, cov_type, reg_covar, tol, max_iter,
+            overrides=(None, None, None), prev_lb0=None, start=None):
     m, n = shape
-    xv = xp[:, :n]
-    xv = lax.with_sharding_constraint(xv, _mesh.row_sharding())
-    w = (lax.broadcasted_iota(jnp.int32, (xv.shape[0],), 0) < m).astype(xv.dtype)
-
-    def m_step(resp):
-        resp = resp * w[:, None]
-        nk = jnp.sum(resp, axis=0) + 1e-10                    # psum over rows
-        means = resp.T @ xv / nk[:, None]                     # GEMM + psum
-        covs = _estimate_covs(xv, resp, nk, means, cov_type, reg_covar, w)
-        weights = nk / m
-        return weights, means, covs
-
-    weights0, means0, covs0 = m_step(resp0)
-    w_o, mu_o, c_o = overrides
-    weights0 = weights0 if w_o is None else w_o
-    means0 = means0 if mu_o is None else mu_o
-    covs0 = covs0 if c_o is None else c_o
-
-    def e_step(weights, means, covs):
-        prec = _chol_precisions(covs, cov_type, n)
-        logp = _log_prob(xv, means, prec, cov_type, n) + jnp.log(weights)[None, :]
-        lse = jax.scipy.special.logsumexp(logp, axis=1)
-        resp = jnp.exp(logp - lse[:, None])
-        ll = jnp.sum(lse * w) / m                             # mean log-likelihood
-        return resp, ll
+    _count_schedule("gm_step", "blocked")
+    weights0, means0, covs0 = overrides
+    if start is not None:
+        # the first parameters, where the caller gave not all three: an
+        # M-step from hard labels about their centres, or from a seeded
+        # random draw about the rows' mean (padding rows are zero)
+        about = start["centers"] if "centers" in start else jnp.tile(
+            jnp.sum(xp[:, :n], axis=0) / m, (k, 1))
+        made = _close(*_ops.em_start(xp, m, about, cov_type,
+                                     labels=start.get("labels"),
+                                     key=start.get("key")),
+                      about, m, cov_type, reg_covar)
+        weights0, means0, covs0 = (
+            mine if given is None else given
+            for given, mine in zip(overrides, made))
 
     def step(carry):
         weights, means, covs, prev_lb, _, it, hist = carry
-        resp, lb = e_step(weights, means, covs)
-        weights, means, covs = m_step(resp)
-        conv = jnp.abs(lb - prev_lb) < tol
+        with jax.named_scope(_CHOL):
+            prec = _chol_precisions(covs, cov_type, n)
+        nk, s, second, loglik = _ops.em_step(xp, m, jnp.log(weights), means,
+                                             prec, cov_type)
+        with jax.named_scope(_CLOSE):
+            weights, means, covs = _close(nk, s, second, means, m, cov_type,
+                                          reg_covar)
+            lb = loglik / m                       # mean log-likelihood
+            conv = jnp.abs(lb - prev_lb) < tol
         return weights, means, covs, lb, conv, it + 1, hist.at[it].set(lb)
 
     def cond(carry):
         _, _, _, lb, conv, it, _ = carry
         return (~conv) & (it < max_iter)
 
-    lb0 = jnp.asarray(-jnp.inf, xv.dtype) if prev_lb0 is None else \
-        jnp.asarray(prev_lb0, xv.dtype)
+    lb0 = jnp.asarray(-jnp.inf, xp.dtype) if prev_lb0 is None else \
+        jnp.asarray(prev_lb0, xp.dtype)
     init = (weights0, means0, covs0, lb0, jnp.asarray(False), jnp.int32(0),
-            jnp.zeros((max_iter,), xv.dtype))
+            jnp.zeros((max_iter,), xp.dtype))
     weights, means, covs, lb, conv, n_iter, hist = \
         lax.while_loop(cond, step, init)
     # fused health vector — same program, zero extra dispatches (the EM
@@ -436,22 +405,16 @@ def _gm_fit(xp, shape, resp0, cov_type, reg_covar, tol, max_iter,
 @precise
 def _gm_loglik(xp, shape, weights, means, covs, cov_type):
     m, n = shape
-    xv = xp[:, :n]
-    prec = _chol_precisions(covs, cov_type, n)
-    logp = _log_prob(xv, means, prec, cov_type, n) + jnp.log(weights)[None, :]
-    lse = jax.scipy.special.logsumexp(logp, axis=1)
-    w = (lax.broadcasted_iota(jnp.int32, (xv.shape[0],), 0) < m).astype(xv.dtype)
-    return jnp.sum(lse * w) / m
+    with jax.named_scope(_CHOL):
+        prec = _chol_precisions(covs, cov_type, n)
+    return _ops.em_loglik(xp, m, jnp.log(weights), means, prec, cov_type) / m
 
 
 def _gm_predict_kernel(cfg, xp, weights, means, covs):
-    """`predict` as a fusion-node body (cfg = (shape, cov_type))."""
-    shape, cov_type = cfg
-    m, n = shape
-    xv = xp[:, :n]
-    prec = _chol_precisions(covs, cov_type, n)
-    logp = _log_prob(xv, means, prec, cov_type, n) + jnp.log(weights)[None, :]
-    # component ids stay int32 (float32 is exact only below 2^24)
-    labels = jnp.argmax(logp, axis=1).astype(jnp.int32)
-    valid = lax.broadcasted_iota(jnp.int32, (xv.shape[0],), 0) < m
-    return jnp.where(valid, labels, 0)[:, None]
+    """`predict` as a fusion-node body (cfg = (shape, cov_type)): the
+    blocked E-step's most probable component per row; component ids stay
+    int32 (float32 is exact only below 2^24)."""
+    (m, n), cov_type = cfg
+    with jax.named_scope(_CHOL):
+        prec = _chol_precisions(covs, cov_type, n)
+    return _ops.em_labels(xp, m, jnp.log(weights), means, prec, cov_type)

@@ -8,6 +8,8 @@ numerical fixes land in one place.
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -166,3 +168,323 @@ def lloyd_step(x, x_sq, w, centers):
                   P(None, _mesh.ROWS), P()),
         out_specs=P(), check_vma=False)(x, x_sq, w, centers)
     return packed[:k * d].reshape(k, d), packed[k * d:-1], packed[-1]
+
+
+# -- the EM step of a Gaussian mixture in one blocked pass over the rows ------
+# A block's temporaries are its rows' whitened differences and their
+# weighted copies, (block, k, d) float32 each; everything else of a block
+# is (block, k) or (block, d).  The block is derived from the shapes and
+# never from an option: the most rows whose (block, k, d) tile stays
+# under `_EM_TILE_BYTES`, in whole `_EM_ROW_QUANTUM`s (the chip holds a
+# tall X with its rows on the lanes, so a block starts on a lane tile).
+_EM_TILE_BYTES = 32 * 2 ** 20
+_EM_ROW_QUANTUM = 512
+_LOG2PI = 1.8378770664093453         # log(2 pi)
+COVARIANCE_TYPES = ("full", "tied", "diag", "spherical")
+
+
+def em_block(rows: int, d: int, k: int) -> int:
+    """Rows of one block of the blocked EM pass over ``rows`` rows on a
+    device: all of them where they fit the tile budget; else the largest
+    whole number of quanta under the budget that divides ``rows``, if one
+    of at least half the budget does; else the budget's, and the last
+    block is ragged (it then starts early and its rows that an earlier
+    block has seen weigh nothing)."""
+    most = max(_EM_TILE_BYTES // (4 * k * (d + -d % 8)) // _EM_ROW_QUANTUM,
+               1) * _EM_ROW_QUANTUM
+    if rows <= most:
+        return rows
+    whole = [b for b in range(most, most // 2 - 1, -_EM_ROW_QUANTUM)
+             if rows % b == 0]
+    return whole[0] if whole else most
+
+
+def _em_cut(i, block, local, *arrays):
+    """``(start, blocks)``: where the ``i``-th block of ``block`` rows
+    starts among a device's ``local`` rows (a ragged last block starts
+    early, so that it ends with the rows) and that block of every array."""
+    start = jnp.minimum(i * block, local - block)
+    return start, [lax.dynamic_slice_in_dim(a, start, block) for a in arrays]
+
+
+def _em_blocks(xp, m, d, k, body, zero, per_row=()):
+    """The sums over all row blocks of ``body(xb, w, start,
+    *per_row_blocks)``, a block's partial sums (a pytree shaped like
+    ``zero``), over the padded, row-sharded ``xp``: every device of the
+    mesh's ``rows`` axis loops over its own rows' blocks, and ONE ``psum``
+    of the packed totals crosses the devices (none on a mesh of one).
+    ``xb`` (block, d) are a block's rows with the padding columns
+    cropped, ``w`` (block,) their weights (1; 0 on the padding rows past
+    ``m`` and on the rows of a ragged last block that an earlier block
+    has seen), ``start`` the block's first row in the whole array.
+    ``per_row`` are further (rows, ...) arrays cut the same way.
+
+    A device's running totals are compensated (Kahan): what an addition
+    rounds away is carried and given back, so that thousands of blocks
+    add up as exactly as two, whatever the block's size.  Plain float32
+    totals over the 3125 blocks of 24M rows read 2e-6 of a lower bound
+    and 1e-5 of a covariance against the reference (PERF.md, PR 29)."""
+    from jax.flatten_util import ravel_pytree
+    mesh = _mesh.get_mesh()
+    local = xp.shape[0] // mesh.shape[_mesh.ROWS]
+    block = em_block(local, d, k)
+
+    def device(xs, *others):
+        first = lax.axis_index(_mesh.ROWS) * local
+
+        def one(i, carry):
+            total, lost = carry
+            start, (xb, *cut) = _em_cut(i, block, local, xs, *others)
+            rows = start + lax.iota(jnp.int32, block)
+            w = ((rows >= i * block) & (first + rows < m)).astype(xs.dtype)
+            # leaf by leaf, each in the shape its block's GEMM writes it
+            part = jax.tree.map(jnp.subtract,
+                                body(xb[:, :d], w, first + start, *cut), lost)
+            grown = jax.tree.map(jnp.add, total, part)
+            return grown, jax.tree.map(lambda g, t, p: (g - t) - p,
+                                       grown, total, part)
+
+        start = jax.tree.map(
+            lambda z: lax.pcast(z, _mesh.ROWS, to="varying"), zero)
+        total, lost = lax.fori_loop(0, -(-local // block), one,
+                                    (start, start))
+        flat, unravel = ravel_pytree(jax.tree.map(jnp.subtract, total, lost))
+        return unravel(lax.psum(flat, _mesh.ROWS))
+
+    row_spec = P(_mesh.ROWS, None)
+    return jax.shard_map(
+        device, mesh=mesh, in_specs=(row_spec,) * (1 + len(per_row)),
+        out_specs=P())(xp, *per_row)
+
+
+class Whitener(NamedTuple):
+    """What :func:`em_whitener` makes of a mixture's parameters."""
+
+    centre: jax.Array       # (d,) where the pass moves the origin to
+    about: jax.Array        # (k, d) the means there
+    proj: jax.Array | None  # the P_j side by side, or the one P, or None
+    t: jax.Array            # (k, d or d8) the whitened means
+    scale: jax.Array | None  # diag and spherical: P_j itself
+    const: jax.Array        # (k,) log pi_j + log det P_j - (d/2) log 2 pi
+
+
+def em_whitener(log_weights, means, prec, cov_type) -> Whitener:
+    """What the E-step needs of the parameters, made once a step outside
+    the pass over the rows.
+
+    The pass works in coordinates moved to ``centre`` (d,), the mixture's
+    own mean: a Gaussian mixture does not change under a translation of
+    the rows, and with the rows centred the products below round against
+    the spread of the data and not against its distance from the origin.
+    ``about`` (k, d) are the means there.  A row's whitened difference to
+    component j is ``y_j = (x - mu_j) P_j``: ``proj`` holds the P_j side
+    by side, (d, k d), so that the k products of a block are ONE GEMM
+    (tied: the one P; diag and spherical: None, P_j is ``scale``), ``t``
+    the whitened means and ``const`` (k,) = log pi_j + log det P_j -
+    (d/2) log 2 pi.  ``prec`` is the Cholesky factor of the precisions as
+    ``GaussianMixture`` keeps it: full (k, d, d) upper, tied (d, d), diag
+    (k, d) and spherical (k,) the reciprocal standard deviations."""
+    k, d = means.shape
+    centre = px.pdot(jnp.exp(log_weights), means)
+    about = means - centre
+    proj, scale = None, None
+    if cov_type == "full":
+        # every component's d columns padded to whole sublane tiles: the
+        # GEMM's (block, k d8) result then IS the (block, k, d8) array of
+        # the k differences, as the chip lays both out, and no copy
+        # stands between the two (70 ms of a 410 ms iteration, PERF.md)
+        proj = jnp.transpose(_pad8(prec), (1, 0, 2)).reshape(d, -1)
+        t = _pad8(px.peinsum("jd,jde->je", about, prec))
+        logdet = jnp.sum(jnp.log(jnp.diagonal(prec, axis1=1, axis2=2)), 1)
+    elif cov_type == "tied":
+        proj, t = prec, px.pdot(about, prec)
+        logdet = jnp.sum(jnp.log(jnp.diagonal(prec)))
+    elif cov_type == "diag":
+        scale, t = prec, about * prec
+        logdet = jnp.sum(jnp.log(prec), axis=1)
+    else:
+        scale, t = prec[:, None], about * prec[:, None]
+        logdet = d * jnp.log(prec)
+    return Whitener(centre, about, proj, t, scale,
+                    log_weights + logdet - 0.5 * d * _LOG2PI)
+
+
+def _pad8(a):
+    """``a`` with zeros after its last axis up to a whole number of
+    sublane tiles (8)."""
+    return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, -a.shape[-1] % 8)])
+
+
+def _em_log_prob(xb, wh: Whitener):
+    """log(pi_j N(x | mu_j, Sigma_j)) of a block's rows ``xb`` (block,
+    d): (block, k).  The (block, k, d) whitened differences are taken as
+    they are, squared and summed: no expanded |z|^2 - 2 z.t + |t|^2,
+    whose terms cancel."""
+    xc = xb - wh.centre
+    z = xc if wh.proj is None else px.pdot_short(xc, wh.proj)
+    if wh.scale is not None:
+        z = z[:, None, :] * wh.scale[None]
+    y = z.reshape(xc.shape[0], -1, wh.t.shape[1]) - wh.t[None]
+    return wh.const[None, :] - 0.5 * jnp.sum(y * y, axis=2)
+
+
+def _em_zero_sums(k, d, cov_type, dtype):
+    """Zeros shaped like the M-step's sums ``(nk, s, second)`` of
+    :func:`_em_block_sums`."""
+    second = {"full": (k * (d + -d % 8), d + 1),
+              "tied": (d, d)}.get(cov_type, (k, d))
+    return (jnp.zeros((k,), dtype), jnp.zeros((k, d), dtype),
+            jnp.zeros(second, dtype))
+
+
+def _em_block_sums(xc, w, resp, about, cov_type):
+    """One block's share of the M-step's sums ``(nk, s, second)``, taken
+    about the fixed points ``about`` (k, d): with ``diff_j = x - a_j``,
+    ``nk = sum r_j``, ``s = sum r_j diff_j`` and the second moments ``sum
+    r_j diff_j diff_j^T`` (full; its s rides the same GEMM as one more
+    column, so ``s`` stays zero until :func:`_em_sums_about`), their
+    diagonals (diag, spherical) or, tied, the rows' own ``sum w x x^T``
+    from which the sum over j follows.  ``resp`` (block, k) holds the
+    weights already.  For full covariances the k weighted differences of
+    a block lie side by side, (block, k d8), and ONE GEMM against the
+    block's rows contracts over the rows."""
+    nk = jnp.sum(resp, axis=0)
+    if cov_type == "full":
+        # columns padded as in :func:`em_whitener`, for the same reason
+        wd = resp[:, :, None] * (_pad8(xc)[:, None, :] - _pad8(about)[None])
+        x1 = jnp.concatenate([xc, jnp.ones_like(xc[:, :1])], axis=1)
+        return nk, jnp.zeros(about.shape, about.dtype), px.peinsum(
+            "bp,bq->pq", wd.reshape(xc.shape[0], -1), x1)
+    diff = xc[:, None, :] - about[None]
+    wd = resp[:, :, None] * diff
+    s = jnp.sum(wd, axis=0)
+    if cov_type == "tied":
+        return nk, s, px.peinsum("bp,bq->pq", xc * w[:, None], xc)
+    return nk, s, jnp.sum(wd * diff, axis=0)
+
+
+def _em_sums_about(sums, about, cov_type):
+    """``(nk, s, S)`` with every moment about ``about``, from what the
+    pass accumulated (full: the GEMM's products against the centred rows
+    and its column of ones; tied: the rows' own second moment)."""
+    nk, s, second = sums
+    k, d = about.shape
+    if cov_type == "full":
+        g = second.reshape(k, -1, d + 1)[:, :d]
+        s = g[:, :, d]
+        return nk, s, g[:, :, :d] - s[:, :, None] * about[:, None, :]
+    if cov_type == "tied":
+        sa = px.peinsum("jp,jq->pq", s, about)
+        second = second - sa - sa.T \
+            - px.peinsum("jp,jq->pq", about * nk[:, None], about)
+    return nk, s, second
+
+
+def em_step(xp, m, log_weights, means, prec, cov_type):
+    """``(nk, s, S, loglik)`` of one EM step in ONE blocked pass over the
+    padded rows ``xp`` (rows, >= d), rows sharded over the mesh's
+    ``rows`` axis, of which the first ``m`` count: the E-step at the
+    parameters given (``prec`` as in :func:`em_whitener`) and the M-step's
+    sums about the means given, ``a_j``: ``nk`` (k,) the summed
+    responsibilities, ``s`` (k, d) ``= sum r_j (x - a_j)``, ``S`` the
+    second moments ``sum r_j (x - a_j)(x - a_j)^T``: (k, d, d) for full
+    covariances, their sum over j (d, d) for tied, their diagonals (k, d)
+    for diag and spherical; ``loglik`` the sum over the rows of log sum_j
+    pi_j N(x | mu_j, Sigma_j).  The new means are ``a_j + s_j / nk_j`` and
+    the new covariances ``S_j / nk_j - (s_j / nk_j)(s_j / nk_j)^T``: what
+    is taken away is second order in how far a mean moved, where raw
+    moments (a_j = 0) would cancel against the means' own size.
+
+    No array of rows x k x d elements exists, and none of rows x k
+    outlives its block: each device runs a loop over blocks of
+    :func:`em_block` rows and keeps compensated running sums, and one
+    packed ``psum`` over ``rows`` ends the step."""
+    k, d = means.shape
+    wh = em_whitener(log_weights, means, prec, cov_type)
+
+    def block(xb, w, _start):
+        with jax.named_scope("dslib.gm.e_step"):
+            logp = _em_log_prob(xb, wh)
+            lse = jax.scipy.special.logsumexp(logp, axis=1)
+            resp = jnp.exp(logp - lse[:, None]) * w[:, None]
+        with jax.named_scope("dslib.gm.m_step"):
+            sums = _em_block_sums(xb - wh.centre, w, resp, wh.about,
+                                  cov_type)
+        return sums, jnp.sum(lse * w)
+
+    sums, loglik = _em_blocks(
+        xp, m, d, k, block,
+        (_em_zero_sums(k, d, cov_type, xp.dtype), jnp.zeros((), xp.dtype)))
+    return (*_em_sums_about(sums, wh.about, cov_type), loglik)
+
+
+def em_start(xp, m, about, cov_type, labels=None, key=None):
+    """``(nk, s, S)`` as :func:`em_step` returns them, about the points
+    ``about`` (k, d), of responsibilities that are given and not
+    computed: the one-hot rows of ``labels`` (rows, 1), or with ``key``
+    a seeded uniform draw normalised over the components, made block by
+    block (the key folded with the block's first row) so that no (rows,
+    k) array exists.  The start of a fit: the same blocked pass, without
+    an E-step."""
+    k, d = about.shape
+    centre = jnp.mean(about, axis=0)
+    about_c = about - centre
+
+    def block(xb, w, start, *lab):
+        if lab:
+            resp = jax.nn.one_hot(lab[0][:, 0], k, dtype=xb.dtype)
+        else:
+            resp = jax.random.uniform(jax.random.fold_in(key, start),
+                                      (xb.shape[0], k), xb.dtype)
+            resp = resp / jnp.sum(resp, axis=1, keepdims=True)
+        return _em_block_sums(xb - centre, w, resp * w[:, None], about_c,
+                              cov_type)
+
+    sums = _em_blocks(xp, m, d, k, block,
+                      _em_zero_sums(k, d, cov_type, xp.dtype),
+                      per_row=() if labels is None else (labels,))
+    return _em_sums_about(sums, about_c, cov_type)
+
+
+def em_loglik(xp, m, log_weights, means, prec, cov_type):
+    """The sum over the first ``m`` rows of log sum_j pi_j N(x | mu_j,
+    Sigma_j): :func:`em_step`'s E-step alone, in the same blocks."""
+    k, d = means.shape
+    wh = em_whitener(log_weights, means, prec, cov_type)
+
+    def block(xb, w, _start):
+        logp = _em_log_prob(xb, wh)
+        return jnp.sum(jax.scipy.special.logsumexp(logp, axis=1) * w)
+
+    return _em_blocks(xp, m, d, k, block, jnp.zeros((), xp.dtype))
+
+
+def em_labels(xp, m, log_weights, means, prec, cov_type):
+    """The most probable component of every row, (rows, 1) int32 with 0
+    on the padding rows: the E-step's log-probabilities block by block,
+    each block's labels written where its rows lie (the rows of a ragged
+    last block that an earlier block has written get the same labels
+    again)."""
+    k, d = means.shape
+    wh = em_whitener(log_weights, means, prec, cov_type)
+    mesh = _mesh.get_mesh()
+    local = xp.shape[0] // mesh.shape[_mesh.ROWS]
+    block = em_block(local, d, k)
+
+    def device(xs):
+        first = lax.axis_index(_mesh.ROWS) * local
+
+        def one(i, out):
+            start, (xb,) = _em_cut(i, block, local, xs)
+            lab = jnp.argmax(_em_log_prob(xb[:, :d], wh), axis=1)
+            rows = first + start + lax.iota(jnp.int32, block)
+            lab = jnp.where(rows < m, lab, 0).astype(jnp.int32)
+            return lax.dynamic_update_slice_in_dim(out, lab, start, 0)
+
+        return lax.fori_loop(
+            0, -(-local // block), one,
+            lax.pcast(jnp.zeros((local,), jnp.int32), _mesh.ROWS,
+                      to="varying"))[:, None]
+
+    return jax.shard_map(device, mesh=mesh, in_specs=(P(_mesh.ROWS, None),),
+                         out_specs=P(_mesh.ROWS, None))(xp)

@@ -264,6 +264,45 @@ def highest_parts(x):
 ONE_PASS = jax.lax.Precision.DEFAULT
 
 
+# where a short contraction is packed (below): backends whose matmul unit
+# is a systolic array much deeper than the contraction
+_PACK_BACKENDS = ("tpu",)
+
+
+def pdot_short(a, b, policy: Policy = FLOAT32):
+    """:func:`pdot` for a contraction much shorter than the MXU is deep:
+    ``a`` (m, c) @ ``b`` (c, n) with c a few tens against a depth of 128.
+
+    Under the float32 policy a 'highest' contraction is six bfloat16
+    passes, and each fills c of the array's 128 rows.  Here the six are
+    packed along the contraction instead: ``[a_hi, a_mid, a_lo, a_hi,
+    a_mid, a_hi] @ [b_hi; b_hi; b_hi; b_mid; b_mid; b_lo]``, ONE
+    bfloat16 GEMM 6c deep accumulated in float32: the same six products
+    of the same :func:`highest_parts`, summed in the same accumulator, in
+    ceil(6 c16 / 128) passes instead of 6, c16 being c in whole tiles of
+    16 (three for c = 50: 88 against 175 ms an EM iteration's E-step at
+    24M x 50, k = 16; PERF.md, PR 29).
+    Decided by what a trace can observe: the float32 policy, float32
+    operands and a backend of ``_PACK_BACKENDS``; everything else is
+    :func:`pdot` as it stands."""
+    f32_ = jnp.dtype(jnp.float32)
+    if policy.name != "float32" or a.dtype != f32_ or b.dtype != f32_ \
+            or jax.default_backend() not in _PACK_BACKENDS:
+        return pdot(a, b, policy)
+    with jax.named_scope("dslib.pdot"):
+        # whole bfloat16 sublane tiles (16) of the contraction a part, so
+        # that the parts lie side by side without a relayout
+        fill = -a.shape[-1] % 16
+        a = jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, fill)])
+        b = jnp.pad(b, [(0, 0)] * (b.ndim - 2) + [(0, fill), (0, 0)])
+        a_hi, a_mid, a_lo = highest_parts(a)
+        b_hi, b_mid, b_lo = highest_parts(b)
+        return jnp.matmul(
+            jnp.concatenate([a_hi, a_mid, a_lo, a_hi, a_mid, a_hi], axis=-1),
+            jnp.concatenate([b_hi, b_hi, b_hi, b_mid, b_mid, b_lo], axis=-2),
+            precision=ONE_PASS, preferred_element_type=f32_)
+
+
 def precise(fn):
     """Trace-time float32-faithful matmul scope for library kernels.
 

@@ -14,7 +14,8 @@ import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SMOKE = os.path.join(REPO, "chip_smoke.py")
-PHASES = ["device", "train", "serve", "bundle", "kernels", "summa"]
+PHASES = ["device", "train", "mixture", "serve", "bundle", "kernels",
+          "summa"]
 
 
 def _run(*args, **env_extra):
@@ -54,6 +55,11 @@ def test_rehearsal_runs_every_phase_stamped(tmp_path):
     assert by["train"]["collectives"]["all-reduce"] >= 1
     # the interpreter's backend keeps the two XLA passes; a TPU reads "fused"
     assert by["train"]["kmeans_step"] == ["two_pass"]
+    # the mixture fit's step is the blocked one on every backend, and its
+    # packed partial sums cross the mesh in one all-reduce
+    assert by["mixture"]["gm_step"] == ["blocked"]
+    assert by["mixture"]["collectives"] == {"all-reduce": 1}
+    assert by["mixture"]["predict_agreement"] >= 0.9999
     assert by["serve"]["traces_after_start"] == 0
     assert by["serve"]["dispatches_per_batch_max"] == 1
     assert by["bundle"]["traces_after_load"] == 0

@@ -86,10 +86,12 @@ class TestFitCommAudit:
     def test_gmm_fit_never_gathers_data(self, rng):
         from dislib_tpu.cluster.gm import _gm_fit
         a, x = self._sharded(rng)
-        resp0 = jnp.ones((a._data.shape[0], 3), jnp.float32) / 3.0
-        hlo = _gm_fit.lower(a._data, a.shape, resp0, "full", 1e-6, 0.0,
-                            3).compile().as_text()
-        # responsibilities are (m, k) row-sharded state — also never gathered
+        import jax
+        hlo = _gm_fit.lower(a._data, a.shape, 3, "full", 1e-6, 0.0, 3,
+                            start={"key": jax.random.PRNGKey(0)}
+                            ).compile().as_text()
+        # responsibilities exist for a block of rows at a time: nothing of
+        # (m, k) elements is there to gather either
         _assert_no_operand_gather(hlo, self.M * 3)
         _assert_no_operand_gather(hlo, self.M * self.N)
         assert "all-reduce" in hlo

@@ -1,0 +1,373 @@
+"""The blocked EM step of ``GaussianMixture`` (``ops/base.py::em_step``)
+held to the plain reference (``benchmark/reference/em.py``: the textbook
+iteration, two passes over the rows, nothing of the program), for all four
+covariance types, on one device and on the suite's virtual mesh, with
+ragged last blocks and padding rows; and what the issue that brought it
+promised of it: no array of rows x k x d or rows x k elements, the shifted
+one-pass covariance where raw moments lose it, ``score`` and ``predict``
+through the same blocks, the counter and the spans, and a start that runs
+no KMeans when all three parameters are given.
+
+Tolerances.  Program and reference are both float32 at 'highest' and sum
+in another order (one pass about the old means against two passes about
+the new ones; blocks of another size): a sum over n rows of float32 terms
+differs by a few 1e-7 of its size between two orders, a mean or a
+covariance entry by that over its own size, and ten iterations compound
+it by the EM map's own contraction (under 1).  So 2e-5 of the distance
+the means moved, 5e-5 of a covariance's norm (5e-4 spherical and tied at
+8 devices: fewer numbers average the same rounding), 2e-6 of a lower
+bound.  Readings on this rig are 10 to 100 times smaller.
+"""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import dislib_tpu as ds
+from dislib_tpu.cluster import GaussianMixture
+from dislib_tpu.cluster import gm as _gm
+from dislib_tpu.ops import base as _ops
+from dislib_tpu.utils import profiling
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark.reference import em as ref  # noqa: E402
+
+COV_TYPES = ("full", "tied", "diag", "spherical")
+K, D = 3, 6
+# 8 x 1126 rows on the mesh, 9001 on one device: with blocks of 512 rows
+# (below) every device ends on a ragged block, and the last rows are padding
+ROWS = 9001
+
+
+def _mesh_of(devices):
+    if devices == 1:
+        ds.init((1, 1), devices=jax.devices()[:1])
+    elif len(jax.devices()) < devices:
+        pytest.skip(f"needs {devices} devices")
+    else:
+        ds.init((devices, 1))
+
+
+@pytest.fixture
+def small_blocks(monkeypatch):
+    """Blocks of one quantum (512 rows), so that a few thousand rows are
+    several blocks and a ragged last one."""
+    monkeypatch.setattr(_ops, "_EM_TILE_BYTES", 4 * K * D * 512)
+
+
+def _data(seed, rows=ROWS, offset=0.0):
+    rng = np.random.RandomState(seed)
+    mu = rng.rand(K, D) * 5 + offset
+    a = np.eye(D) + 0.3 * rng.randn(K, D, D) / np.sqrt(D)
+    comp = rng.randint(0, K, rows)
+    x = mu[comp] + np.einsum("bd,bde->be", rng.randn(rows, D), a[comp])
+    return x.astype(np.float32), mu.astype(np.float32)
+
+
+def _start(mu, cov_type, seed=1, spread=0.5):
+    """``(weights, means, precisions)`` as the ``*_init`` arguments take
+    them, and the covariances the reference starts from."""
+    rng = np.random.RandomState(seed)
+    means = (mu + spread * rng.randn(K, D)).astype(np.float32)
+    prec = {"full": np.tile(np.eye(D, dtype=np.float32), (K, 1, 1)),
+            "tied": np.eye(D, dtype=np.float32),
+            "diag": np.ones((K, D), np.float32),
+            "spherical": np.ones((K,), np.float32)}[cov_type] / 1.5
+    covs = (np.linalg.inv(prec) if prec.ndim >= 2 and cov_type != "diag"
+            else 1.0 / prec).astype(np.float32)
+    return (np.full((K,), 1.0 / K, np.float32), means, prec), covs
+
+
+def _fit(x, start, cov_type, n_iter):
+    weights, means, prec = start
+    return GaussianMixture(
+        n_components=K, covariance_type=cov_type, max_iter=n_iter, tol=0.0,
+        weights_init=weights, means_init=means, precisions_init=prec
+    ).fit(ds.array(x))
+
+
+def _reference_rows(x):
+    """The reference visits whole blocks: the rows in blocks that divide
+    them (9001 = 9001 x 1, so one block)."""
+    return jnp.asarray(x), x.shape[0]
+
+
+def _compared(gm):
+    """A fit as ``ref.compare`` takes it."""
+    return {"weights": gm.weights_, "means": gm.means_,
+            "covariances": gm.covariances_, "history": gm.history_,
+            "lower_bound": gm.lower_bound_, "n_iter": gm.n_iter_}
+
+
+# -- (a) the fit against the reference ----------------------------------------
+
+@pytest.mark.parametrize("devices", [1, 8])
+@pytest.mark.parametrize("cov_type", COV_TYPES)
+def test_fit_agrees_with_the_plain_reference(small_blocks, cov_type,
+                                             devices):
+    _mesh_of(devices)
+    x, mu = _data(3)
+    start, covs0 = _start(mu, cov_type)
+    n_iter = 10
+    gm = _fit(x, start, cov_type, n_iter)
+    xr, block = _reference_rows(x)
+    w, m, c, hist = ref.fit(xr, (start[0], start[1], covs0), n_iter, block,
+                            cov_type=cov_type, reg_covar=1e-6)
+    gaps = ref.compare(_compared(gm), w, m, c, hist,
+                       (start[0], start[1], covs0), n_iter)
+    loose = 5e-4 if cov_type in ("tied", "spherical") else 5e-5
+    assert gaps["n_iter_gap"] == 0
+    assert gaps["first_bound_gap"] < 2e-6, gaps
+    assert gaps["bound_gap"] < 2e-6, gaps
+    assert gaps["means_gap"] < 2e-5, gaps
+    assert gaps["weights_gap"] < 2e-5, gaps
+    assert gaps["covariances_gap"] < loose, gaps
+    assert gm.covariances_.shape == c.shape
+    assert len(gm.history_) == n_iter
+
+
+def test_blocks_are_derived_from_the_shapes():
+    # the cell's shapes: 7 680 rows, which divide 24M, under a 32 MiB tile
+    # of (block, 16, 56) float32
+    assert _ops.em_block(24_000_000, 50, 16) == 7_680
+    assert 24_000_000 % 7_680 == 0
+    # all rows where they fit; a ragged budget block where none divides
+    assert _ops.em_block(9_001, 50, 16) == 9_001
+    assert _ops.em_block(40_000, 50, 16) == 9_216
+    assert _ops.em_block(1_000_003, 50, 16) == 9_216
+    # never under one quantum, however wide a row's tile
+    assert _ops.em_block(10_000, 512, 256) == 512
+
+
+def test_the_packed_short_contraction_is_the_highest_one(monkeypatch):
+    """``pdot_short`` on a backend that packs: the six bfloat16 passes of
+    'highest' side by side along the contraction, so within a few 2^-24 of
+    the float64 product, entry by entry against the entry's own scale;
+    where it does not pack it is ``pdot``."""
+    from dislib_tpu.ops import precision as px
+    rng = np.random.RandomState(0)
+    a = rng.randn(300, 50).astype(np.float32)
+    b = rng.randn(50, 96).astype(np.float32)
+    want = a.astype(np.float64) @ b.astype(np.float64)
+    scale = np.abs(a).astype(np.float64) @ np.abs(b).astype(np.float64)
+    plain = np.asarray(px.pdot_short(jnp.asarray(a), jnp.asarray(b)))
+    assert np.array_equal(plain, np.asarray(px.pdot(jnp.asarray(a),
+                                                    jnp.asarray(b))))
+    monkeypatch.setattr(px, "_PACK_BACKENDS", (jax.default_backend(),))
+    packed = np.asarray(px.pdot_short(jnp.asarray(a), jnp.asarray(b)))
+    assert packed.dtype == np.float32 and packed.shape == (300, 96)
+    assert np.max(np.abs(packed - want) / scale) < 4 * 2.0 ** -24
+    # one pass of bfloat16 would read 2^-9 of the scale: a thousand times
+    one = (a.astype(jnp.bfloat16).astype(np.float64)
+           @ b.astype(jnp.bfloat16).astype(np.float64))
+    assert np.max(np.abs(one - want) / scale) > 1e-4
+    # the bfloat16 policy is left as it is
+    assert np.array_equal(
+        np.asarray(px.pdot_short(jnp.asarray(a), jnp.asarray(b),
+                                 px.BFLOAT16)),
+        np.asarray(px.pdot(jnp.asarray(a), jnp.asarray(b), px.BFLOAT16)))
+
+
+def test_the_fit_with_the_packed_e_step_agrees_with_the_reference(
+        small_blocks, monkeypatch):
+    """What a TPU runs: the full-covariance E-step's GEMM packed."""
+    from dislib_tpu.ops import precision as px
+    monkeypatch.setattr(px, "_PACK_BACKENDS", (jax.default_backend(),))
+    jax.clear_caches()
+    try:
+        ds.init((1, 1), devices=jax.devices()[:1])
+        x, mu = _data(3)
+        start, covs0 = _start(mu, "full")
+        gm = _fit(x, start, "full", 10)
+        xr, block = _reference_rows(x)
+        ref_start = (start[0], start[1], covs0)
+        w, m, c, hist = ref.fit(xr, ref_start, 10, block)
+        gaps = ref.compare(_compared(gm), w, m, c, hist, ref_start, 10)
+        assert gaps["bound_gap"] < 2e-6 and gaps["means_gap"] < 2e-5 \
+            and gaps["covariances_gap"] < 5e-5, gaps
+    finally:
+        jax.clear_caches()              # no packed program is left behind
+
+
+# -- (b) the shifted one-pass covariance --------------------------------------
+
+def test_one_pass_covariance_survives_means_far_from_the_origin(
+        small_blocks):
+    """Rows whose means lie 100 sigma from the origin.  The pass sums
+    about the old means, so what it takes away is second order in how far
+    a mean moved; raw float32 moments, sum r x x^T / n - mu mu^T, take
+    1e4 sigma^2 away from 1e4 sigma^2 and lose the covariance's digits."""
+    ds.init((1, 1), devices=jax.devices()[:1])
+    x, mu = _data(5, offset=100.0 / np.sqrt(D))
+    assert np.linalg.norm(mu, axis=1).min() > 100.0
+    start, covs0 = _start(mu, "full")
+    gm = _fit(x, start, "full", 1)
+    # float64 truth of one iteration, by the two-pass definition
+    x64 = x.astype(np.float64)
+    xr, block = _reference_rows(x)
+    w, m, c, _ = ref.fit(xr, (start[0], start[1], covs0), 1, block)
+    logp = np.asarray(ref.log_prob(
+        xr, jnp.asarray(start[0]), jnp.asarray(start[1]),
+        *ref.precisions_chol(jnp.asarray(covs0), "full", D), "full",
+        "highest"), np.float64)
+    resp = np.exp(logp - logp.max(1, keepdims=True))
+    resp /= resp.sum(1, keepdims=True)
+    nk = resp.sum(0)
+    mean64 = resp.T @ x64 / nk[:, None]
+    cov64 = np.stack([
+        ((x64 - mean64[j]) * resp[:, j:j + 1]).T @ (x64 - mean64[j]) / nk[j]
+        for j in range(K)]) + 1e-6 * np.eye(D)
+
+    def gap(c_):
+        return np.linalg.norm(c_ - cov64) / np.linalg.norm(cov64)
+
+    assert gap(gm.covariances_) < 2e-5, gap(gm.covariances_)
+    assert gap(c) < 2e-5                      # the centred two-pass one
+    assert np.linalg.norm(gm.means_ - mean64) \
+        / np.linalg.norm(mean64 - start[1]) < 2e-5
+    # raw moments in float32, for the contrast
+    x32, r32 = x, resp.astype(np.float32)
+    raw = np.stack([
+        (x32 * r32[:, j:j + 1]).T @ x32 / np.float32(nk[j])
+        - np.outer(mean64[j].astype(np.float32),
+                   mean64[j].astype(np.float32)) for j in range(K)])
+    assert gap(raw) > 50 * gap(gm.covariances_)
+
+
+# -- (c) score and predict ----------------------------------------------------
+
+@pytest.mark.parametrize("devices", [1, 8])
+@pytest.mark.parametrize("cov_type", COV_TYPES)
+def test_score_and_predict_agree_with_the_references_e_step(
+        small_blocks, cov_type, devices):
+    _mesh_of(devices)
+    x, mu = _data(7)
+    start, _ = _start(mu, cov_type)
+    gm = _fit(x, start, cov_type, 3)
+    xa = ds.array(x)
+    xr, block = _reference_rows(x)
+    want, labels = ref.e_step(xr, gm.weights_, gm.means_, gm.covariances_,
+                              block, cov_type)
+    assert gm.score(xa) == pytest.approx(want, rel=2e-6)
+    got = gm.predict(xa).collect().ravel()
+    assert got.shape == (ROWS,) and got.dtype == np.int32
+    # a row may change sides where its two best components tie to the
+    # last bits; none does on this data
+    assert np.array_equal(got, labels)
+
+
+# -- (d) no (m, k) and no (k, m, d) buffer ------------------------------------
+
+def test_the_compiled_fit_holds_no_array_of_the_rows_times_k():
+    """1M x 50, k = 16 on the CPU backend: the compiled fit's temporaries
+    stay under X's own 200 MB (a block's tiles), where the whole-array
+    step held two (k, m, d) arrays of 3.2 GB, 16 times X."""
+    ds.init((1, 1), devices=jax.devices()[:1])
+    m, d, k = 1_000_000, 50, 16
+    x = jax.ShapeDtypeStruct((m, d), jnp.float32)
+    start = (jax.ShapeDtypeStruct((k,), jnp.float32),
+             jax.ShapeDtypeStruct((k, d), jnp.float32),
+             jax.ShapeDtypeStruct((k, d, d), jnp.float32))
+    mem = _gm._gm_fit.lower(x, (m, d), k, "full", 1e-6, 0.0, 10,
+                            start).compile().memory_analysis()
+    if mem is None:
+        pytest.skip("backend reports no memory analysis")
+    assert mem.temp_size_in_bytes < m * d * 4, mem.temp_size_in_bytes
+    # and a start made in the program (a seeded draw) adds none either
+    mem = _gm._gm_fit.lower(
+        x, (m, d), k, "full", 1e-6, 0.0, 10,
+        start={"key": jax.ShapeDtypeStruct((2,), jnp.uint32)}
+    ).compile().memory_analysis()
+    assert mem.temp_size_in_bytes < m * d * 4, mem.temp_size_in_bytes
+
+
+# -- (e) the counter and the spans --------------------------------------------
+
+def test_the_counter_and_the_spans_of_one_fit():
+    x, mu = _data(9, rows=2000)
+    start, _ = _start(mu, "full")
+    jax.clear_caches()                  # so that this fit traces
+    profiling.reset_counters()
+    _fit(x, start, "full", 4)
+    c = profiling.counters()
+    assert c["schedules"]["gm_step:blocked"] == c["trace_by"]["gm_fit"] == 1
+    spans = c["spans"]
+    for name in ("dslib.gm.fit", "dslib.gm.init", "dslib.fitloop.run",
+                 "dslib.fitloop.chunk", "dslib.fitloop.commit"):
+        assert spans[name]["count"] == 1, name
+    # the health vector, the history and the three parameters
+    assert spans["dslib.host_read"]["count"] == c["transfers"] == 5
+    assert c["dispatch_by"] == {"gm_fit": 1}
+    assert spans["dslib.gm.fit"]["total_s"] \
+        >= spans["dslib.fitloop.run"]["total_s"] \
+        >= spans["dslib.gm.init"]["total_s"]
+    # a second fit of the same shapes traces nothing and bumps nothing
+    _fit(x, start, "full", 4)
+    assert profiling.schedule_counters()["gm_step:blocked"] == 1
+
+
+@pytest.mark.parametrize("scope", ["dslib.gm.chol", "dslib.gm.e_step",
+                                   "dslib.gm.m_step", "dslib.gm.close",
+                                   "dslib.pdot"])
+def test_device_scope_is_in_an_op_name(scope):
+    x = ds.random_array((64, D), random_state=0)
+    text = profiling.op_graph(
+        lambda xp, w, mu, c: _gm._gm_fit(xp, x.shape, K, "full", 1e-6, 0.0,
+                                         2, (w, mu, c)),
+        x._data, jnp.full((K,), 1.0 / K), jnp.ones((K, D)),
+        jnp.tile(jnp.eye(D), (K, 1, 1)))
+    assert f"/{scope}/" in text and 'op_name="' in text, scope
+
+
+# -- (f) the start ------------------------------------------------------------
+
+def test_explicit_starts_run_no_kmeans_and_default_starts_do():
+    x, mu = _data(11, rows=2000)
+    start, _ = _start(mu, "full")
+    profiling.reset_counters()
+    _fit(x, start, "full", 2)
+    c = profiling.counters()
+    assert set(c["dispatch_by"]) == {"gm_fit"}
+    assert "dslib.kmeans.init_centers" not in c["spans"]
+    profiling.reset_counters()
+    gm = GaussianMixture(n_components=K, max_iter=2, tol=0.0,
+                         random_state=0).fit(ds.array(x))
+    c = profiling.counters()
+    assert {"kmeans_fit", "kmeans_predict", "gm_fit"} <= set(c["dispatch_by"])
+    assert np.all(np.isfinite(gm.means_))
+    # one of the three left out is made by the start and the rest kept
+    profiling.reset_counters()
+    gm = GaussianMixture(n_components=K, max_iter=1, tol=0.0, random_state=0,
+                         means_init=start[1]).fit(ds.array(x))
+    assert "kmeans_fit" in profiling.counters()["dispatch_by"]
+
+
+@pytest.mark.parametrize("devices", [1, 8])
+@pytest.mark.parametrize("cov_type", COV_TYPES)
+def test_a_random_start_is_a_normalised_draw_made_block_by_block(
+        small_blocks, cov_type, devices):
+    """``init_params='random'``: responsibilities uniform and normalised
+    over the components, drawn a block at a time.  Every component then
+    weighs about 1/k and sits near the rows' mean, on any mesh; the fit
+    from it runs and its bound does not fall."""
+    _mesh_of(devices)
+    x, _ = _data(13)
+    gm = GaussianMixture(n_components=K, covariance_type=cov_type,
+                         init_params="random", max_iter=1, tol=0.0,
+                         random_state=4).fit(ds.array(x))
+    # after ONE iteration from the drawn start the parameters are still
+    # those of nearly equal components around the rows' mean
+    assert np.allclose(gm.weights_, 1.0 / K, atol=0.05)
+    assert np.abs(gm.means_ - x.mean(0)).max() < 0.5
+    more = GaussianMixture(n_components=K, covariance_type=cov_type,
+                           init_params="random", max_iter=8, tol=0.0,
+                           random_state=4).fit(ds.array(x))
+    assert np.all(np.diff(more.history_) > -1e-4)
+    assert more.history_[0] == pytest.approx(gm.history_[0], rel=1e-6)

@@ -126,14 +126,21 @@ class TestPreemptionWatcher:
         flag = tmp_path / "drain"
         monkeypatch.setenv("DSLIB_PREEMPTION_FILE", str(flag))
         path = str(tmp_path / "gm.npz")
-        ck = faults.CallbackCheckpoint(path, every=4, after=1,
+        # every=2: six chunks.  The flag is touched by the snapshot WORKER
+        # after the first save, and the loop polls it right after the next
+        # chunk's dispatch: with three chunks (every=4) only the second
+        # chunk's poll could raise, and a chunk that dispatches faster than
+        # a snapshot is written (the blocked EM step does, once compiled)
+        # polls before the flag is there.  The second save waits for the
+        # first, so the third poll sees the flag whatever the timing.
+        ck = faults.CallbackCheckpoint(path, every=2, after=1,
                                        callback=flag.touch)
         with pytest.raises(Preempted):
             GaussianMixture(**kw).fit(x, checkpoint=ck)
         monkeypatch.delenv("DSLIB_PREEMPTION_FILE")
         clear_preemption()
         res = GaussianMixture(**kw).fit(
-            x, checkpoint=FitCheckpoint(path, every=4))
+            x, checkpoint=FitCheckpoint(path, every=2))
         assert res.n_iter_ == full.n_iter_
         assert res.lower_bound_ == pytest.approx(full.lower_bound_, rel=1e-4)
 
