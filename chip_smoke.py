@@ -143,6 +143,13 @@ def _wall(fn):
     return out, round(time.perf_counter() - t0, 4)
 
 
+def _routes(prof, kernel):
+    """Which routes ``kernel``'s traces took since the counters' reset:
+    the names ``profiling.schedule_counters()`` holds after ``kernel:``."""
+    return sorted(key.split(":", 1)[1] for key in prof.schedule_counters()
+                  if key.startswith(kernel + ":"))
+
+
 def _balanced(peaks, what):
     """Per-device peak bytes within BALANCE_MAX of each other — device 0
     must not have held the whole operand on its way to the mesh."""
@@ -279,9 +286,7 @@ def phase_train(run):
             "centers_max_abs_err_vs_numpy": center_err,
             # which Lloyd step the fit's traces took: the fused kernel on
             # a TPU, the two XLA passes under the interpreter's backend
-            "kmeans_step": sorted(
-                key.split(":", 1)[1] for key in prof.schedule_counters()
-                if key.startswith("kmeans_step:")),
+            "kmeans_step": _routes(prof, "kmeans_step"),
             "collectives": collectives}
 
 
@@ -323,8 +328,7 @@ def phase_mixture(run):
                                   **start).fit(x)
     gm, first_s = _wall(fit)
     _, warm_s = _wall(fit)
-    steps = sorted(key.split(":", 1)[1] for key in prof.schedule_counters()
-                   if key.startswith("gm_step:"))
+    steps, m_steps = _routes(prof, "gm_step"), _routes(prof, "gm_m_step")
 
     want = (start["weights_init"].astype(np.float64),
             start["means_init"].astype(np.float64),
@@ -368,7 +372,8 @@ def phase_mixture(run):
             "covariances_max_abs_err_vs_numpy":
                 float(np.abs(gm.covariances_ - want[2]).max()),
             "predict_agreement": agree,
-            "gm_step": steps, "collectives": collectives,
+            "gm_step": steps, "gm_m_step": m_steps,
+            "collectives": collectives,
             "peak_balance_after_fit": _balanced(run.peak_bytes(),
                                                 "after the mixture fit")}
 

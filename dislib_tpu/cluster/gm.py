@@ -356,6 +356,9 @@ def _gm_fit(xp, shape, k, cov_type, reg_covar, tol, max_iter,
             overrides=(None, None, None), prev_lb0=None, start=None):
     m, n = shape
     _count_schedule("gm_step", "blocked")
+    if cov_type == "full":
+        _count_schedule("gm_m_step", "packed" if _ops.em_packs(n, xp.dtype)
+                        else "six_pass")
     weights0, means0, covs0 = overrides
     if start is not None:
         # the first parameters, where the caller gave not all three: an

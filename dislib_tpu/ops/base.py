@@ -328,11 +328,18 @@ def _em_log_prob(xb, wh: Whitener):
     return wh.const[None, :] - 0.5 * jnp.sum(y * y, axis=2)
 
 
+def em_packs(d: int, dtype) -> bool:
+    """Whether the M-step of full covariances packs its product
+    (:func:`precision.pdot_tall`, d + 1 columns wide): what a trace can
+    observe, and what decides how a block's wide operand is laid out."""
+    return px.packs_tall(d + 1, dtype)
+
+
 def _em_zero_sums(k, d, cov_type, dtype):
     """Zeros shaped like the M-step's sums ``(nk, s, second)`` of
     :func:`_em_block_sums`."""
-    second = {"full": (k * (d + -d % 8), d + 1),
-              "tied": (d, d)}.get(cov_type, (k, d))
+    wide = d * k if em_packs(d, dtype) else k * (d + -d % 8)
+    second = {"full": (wide, d + 1), "tied": (d, d)}.get(cov_type, (k, d))
     return (jnp.zeros((k,), dtype), jnp.zeros((k, d), dtype),
             jnp.zeros(second, dtype))
 
@@ -346,15 +353,26 @@ def _em_block_sums(xc, w, resp, about, cov_type):
     diagonals (diag, spherical) or, tied, the rows' own ``sum w x x^T``
     from which the sum over j follows.  ``resp`` (block, k) holds the
     weights already.  For full covariances the k weighted differences of
-    a block lie side by side, (block, k d8), and ONE GEMM against the
-    block's rows contracts over the rows."""
+    a block lie side by side and ONE product against the block's rows
+    contracts over the rows (:func:`precision.pdot_tall`).  Where that
+    packs, the differences are (block, d, k), unpadded since k fills
+    whole sublane tiles, and ONE fusion writes them once: the barrier
+    keeps the reshape below it, which XLA otherwise pulls up to the two
+    broadcasts and then writes each out as a tile of its own (36 ms of a
+    289 ms iteration, PERF.md, PR 30).  Elsewhere they are (block, k d8)
+    as the six-pass product takes them."""
     nk = jnp.sum(resp, axis=0)
     if cov_type == "full":
-        # columns padded as in :func:`em_whitener`, for the same reason
-        wd = resp[:, :, None] * (_pad8(xc)[:, None, :] - _pad8(about)[None])
+        if em_packs(xc.shape[1], xc.dtype):
+            wd = lax.optimization_barrier(
+                resp[:, None, :] * (xc[:, :, None] - about.T[None]))
+        else:
+            # columns padded as in :func:`em_whitener`, for the same reason
+            wd = resp[:, :, None] * (_pad8(xc)[:, None, :]
+                                     - _pad8(about)[None])
         x1 = jnp.concatenate([xc, jnp.ones_like(xc[:, :1])], axis=1)
-        return nk, jnp.zeros(about.shape, about.dtype), px.peinsum(
-            "bp,bq->pq", wd.reshape(xc.shape[0], -1), x1)
+        return nk, jnp.zeros(about.shape, about.dtype), px.pdot_tall(
+            wd.reshape(xc.shape[0], -1), x1)
     diff = xc[:, None, :] - about[None]
     wd = resp[:, :, None] * diff
     s = jnp.sum(wd, axis=0)
@@ -370,7 +388,9 @@ def _em_sums_about(sums, about, cov_type):
     nk, s, second = sums
     k, d = about.shape
     if cov_type == "full":
-        g = second.reshape(k, -1, d + 1)[:, :d]
+        g = jnp.swapaxes(second.reshape(d, k, d + 1), 0, 1) \
+            if em_packs(d, second.dtype) \
+            else second.reshape(k, -1, d + 1)[:, :d]
         s = g[:, :, d]
         return nk, s, g[:, :, :d] - s[:, :, None] * about[:, None, :]
     if cov_type == "tied":

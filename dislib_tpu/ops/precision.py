@@ -321,3 +321,64 @@ def precise(fn):
         with jax.default_matmul_precision("highest"):
             return fn(*args, **kwargs)
     return wrapped
+
+
+# (Below `precise` on purpose: a Pallas kernel's compile-cache key holds the
+# lines of the frames it was traced under, `precise`'s among them, and the
+# KMeans fit's program keeps its key only while those lines stay.)
+# Columns of one MXU tile: a product whose narrow side fills whole tiles
+# has nothing to pack.
+_MXU_COLUMNS = 128
+
+
+def packs_tall(n: int, dtype, policy: Policy = FLOAT32) -> bool:
+    """Whether :func:`pdot_tall` packs a product ``n`` columns wide of
+    operands of ``dtype``: under the float32 policy, for float32 operands,
+    on a backend of ``_PACK_BACKENDS``, and where the parts of the narrow
+    operand laid side by side take fewer column tiles than six passes of
+    it (n = 51: 2 + 1 + 1 against 6; n = 128: 3 + 2 + 1, nothing won)."""
+    def tiles(columns):
+        return -(-columns // _MXU_COLUMNS)
+
+    return policy.name == "float32" and dtype == jnp.dtype(jnp.float32) \
+        and jax.default_backend() in _PACK_BACKENDS \
+        and tiles(3 * n) + tiles(2 * n) + tiles(n) < 6 * tiles(n)
+
+
+def pdot_tall(a, b, policy: Policy = FLOAT32):
+    """``a.T @ b`` of two tall operands that share their long first axis,
+    ``a`` (rows, m) and ``b`` (rows, n) with n much narrower than the MXU
+    is wide: the weighted Gram product of an EM block, (7680, 800) against
+    (7680, 51).
+
+    A 'highest' contraction is six bfloat16 passes, and with n = 51 each
+    holds a tile of ``a``'s part in the array for 51 columns of ``b``'s.
+    Here ``b``'s parts lie side by side on the narrow side instead:
+    ``a_hi.T @ [b_hi | b_mid | b_lo]``, ``a_mid.T @ [b_hi | b_mid]`` and
+    ``a_lo.T @ b_hi`` are three bfloat16 GEMMs accumulated in float32,
+    a part of ``a`` read once for up to three products, and their (m, n)
+    slices are added, the smallest first.  The same six products of the
+    same :func:`highest_parts` as a six-pass contraction and as
+    :func:`pdot_short`.  Each GEMM splits ``a`` as it reads it, so ``a``
+    is best ONE array in memory and not an expression XLA forms three
+    times (84 against 97 ms an EM iteration's M-step product at 24M x 50,
+    k = 16, the GEMMs taking a quarter of a millisecond for each of their
+    306 columns; PERF.md, PR 30).
+    Decided by :func:`packs_tall`; everything else is
+    ``peinsum("bp,bq->pq", a, b)`` as it stands."""
+    if a.dtype != b.dtype or not packs_tall(b.shape[1], a.dtype, policy):
+        return peinsum("bp,bq->pq", a, b, policy)
+    with jax.named_scope("dslib.pdot"):
+        n = b.shape[1]
+        a_hi, a_mid, a_lo = highest_parts(a)
+        b_hi, b_mid, b_lo = highest_parts(b)
+
+        def down(x, y):
+            return jax.lax.dot_general(
+                x, y, (((0,), (0,)), ((), ())), precision=ONE_PASS,
+                preferred_element_type=jnp.dtype(jnp.float32))
+
+        by_hi = down(a_hi, jnp.concatenate([b_hi, b_mid, b_lo], axis=1))
+        by_mid = down(a_mid, jnp.concatenate([b_hi, b_mid], axis=1))
+        return (by_hi[:, 2 * n:] + by_mid[:, n:] + down(a_lo, b_hi)) \
+            + (by_hi[:, n:2 * n] + by_mid[:, :n]) + by_hi[:, :n]

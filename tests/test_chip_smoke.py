@@ -58,6 +58,8 @@ def test_rehearsal_runs_every_phase_stamped(tmp_path):
     # the mixture fit's step is the blocked one on every backend, and its
     # packed partial sums cross the mesh in one all-reduce
     assert by["mixture"]["gm_step"] == ["blocked"]
+    # its M-step's product is XLA's own six passes here; a TPU reads "packed"
+    assert by["mixture"]["gm_m_step"] == ["six_pass"]
     assert by["mixture"]["collectives"] == {"all-reduce": 1}
     assert by["mixture"]["predict_agreement"] >= 0.9999
     assert by["serve"]["traces_after_start"] == 0

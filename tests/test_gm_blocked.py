@@ -175,25 +175,115 @@ def test_the_packed_short_contraction_is_the_highest_one(monkeypatch):
         np.asarray(px.pdot(jnp.asarray(a), jnp.asarray(b), px.BFLOAT16)))
 
 
-def test_the_fit_with_the_packed_e_step_agrees_with_the_reference(
-        small_blocks, monkeypatch):
-    """What a TPU runs: the full-covariance E-step's GEMM packed."""
+def test_the_packed_tall_product_is_the_highest_one(monkeypatch):
+    """``pdot_tall`` on a backend that packs: the parts of the narrow
+    operand side by side, three bfloat16 GEMMs and their slices added, so
+    within a few 2^-24 of the float64 product, entry by entry against the
+    entry's own scale; where it does not pack it is ``peinsum``."""
+    from dislib_tpu.ops import precision as px
+    rng = np.random.RandomState(1)
+    a = rng.randn(300, 96).astype(np.float32)
+    b = rng.randn(300, 51).astype(np.float32)
+    wide = rng.randn(300, 128).astype(np.float32)
+    want = a.astype(np.float64).T @ b.astype(np.float64)
+    scale = np.abs(a).astype(np.float64).T @ np.abs(b).astype(np.float64)
+
+    def gram(u, v, *policy):
+        return np.asarray(px.peinsum("bp,bq->pq", jnp.asarray(u),
+                                     jnp.asarray(v), *policy))
+
+    def tall(u, v, *policy):
+        return np.asarray(px.pdot_tall(jnp.asarray(u), jnp.asarray(v),
+                                       *policy))
+
+    assert not px.packs_tall(51, np.dtype(np.float32))
+    assert np.array_equal(tall(a, b), gram(a, b))
+    monkeypatch.setattr(px, "_PACK_BACKENDS", (jax.default_backend(),))
+    packed = tall(a, b)
+    assert packed.dtype == np.float32 and packed.shape == (96, 51)
+    assert not np.array_equal(packed, gram(a, b))
+    assert np.max(np.abs(packed - want) / scale) < 4 * 2.0 ** -24
+    # one pass of bfloat16 would read 2^-9 of the scale: a thousand times
+    one = (a.astype(jnp.bfloat16).astype(np.float64).T
+           @ b.astype(jnp.bfloat16).astype(np.float64))
+    assert np.max(np.abs(one - want) / scale) > 1e-4
+    # decided from the shapes: a narrow side that fills whole column
+    # tiles has nothing to pack, nor have operands that are not float32
+    f32 = np.dtype(np.float32)
+    assert [px.packs_tall(n, f32) for n in (50, 51, 64, 100, 128, 256)] \
+        == [True, True, True, False, False, False]
+    assert not px.packs_tall(51, np.dtype(np.float64))
+    assert np.array_equal(tall(a, wide), gram(a, wide))
+    # the bfloat16 policy is left as it is
+    assert not px.packs_tall(51, f32, px.BFLOAT16)
+    assert np.array_equal(tall(a, b, px.BFLOAT16), gram(a, b, px.BFLOAT16))
+
+
+@pytest.fixture
+def packing(monkeypatch):
+    """What a TPU runs: the full-covariance E-step's GEMM packed along its
+    short contraction and the M-step's on its narrow side."""
     from dislib_tpu.ops import precision as px
     monkeypatch.setattr(px, "_PACK_BACKENDS", (jax.default_backend(),))
     jax.clear_caches()
+    yield
+    jax.clear_caches()                  # no packed program is left behind
+
+
+@pytest.mark.parametrize("devices", [1, 8])
+def test_the_fit_with_the_packed_products_agrees_with_the_reference(
+        small_blocks, packing, devices):
+    _mesh_of(devices)
+    x, mu = _data(3)
+    start, covs0 = _start(mu, "full")
+    profiling.reset_counters()
+    gm = _fit(x, start, "full", 10)
+    assert profiling.schedule_counters()["gm_m_step:packed"] == 1
+    xr, block = _reference_rows(x)
+    ref_start = (start[0], start[1], covs0)
+    w, m, c, hist = ref.fit(xr, ref_start, 10, block)
+    gaps = ref.compare(_compared(gm), w, m, c, hist, ref_start, 10)
+    assert gaps["bound_gap"] < 2e-6 and gaps["means_gap"] < 2e-5 \
+        and gaps["covariances_gap"] < 5e-5, gaps
+
+
+def _start_sums(x, about, labels):
+    """``em_start``'s ``(nk, s, S)`` for full covariances about ``about``,
+    from hard labels, on the mesh as it stands."""
+    xa = ds.array(x)
+    lab = ds.array(labels[:, None].astype(np.int32))
+    return [np.asarray(part) for part in jax.jit(
+        lambda xp, lp: _ops.em_start(xp, x.shape[0], jnp.asarray(about),
+                                     "full", labels=lp))(xa._data, lab._data)]
+
+
+@pytest.mark.parametrize("devices", [1, 8])
+def test_the_start_through_the_packed_product_gives_its_present_answers(
+        small_blocks, monkeypatch, devices):
+    """``em_start`` runs the M-step's sums without an E-step: the packed
+    product and the wide operand in its other order give what the
+    six-pass one gives, and that is the float64 sums of the labelled
+    rows."""
+    from dislib_tpu.ops import precision as px
+    _mesh_of(devices)
+    x, mu = _data(17)
+    labels = np.random.RandomState(2).randint(0, K, ROWS)
+    about = mu + 0.25
+    plain = _start_sums(x, about, labels)
+    monkeypatch.setattr(px, "_PACK_BACKENDS", (jax.default_backend(),))
+    jax.clear_caches()
     try:
-        ds.init((1, 1), devices=jax.devices()[:1])
-        x, mu = _data(3)
-        start, covs0 = _start(mu, "full")
-        gm = _fit(x, start, "full", 10)
-        xr, block = _reference_rows(x)
-        ref_start = (start[0], start[1], covs0)
-        w, m, c, hist = ref.fit(xr, ref_start, 10, block)
-        gaps = ref.compare(_compared(gm), w, m, c, hist, ref_start, 10)
-        assert gaps["bound_gap"] < 2e-6 and gaps["means_gap"] < 2e-5 \
-            and gaps["covariances_gap"] < 5e-5, gaps
+        packed = _start_sums(x, about, labels)
     finally:
-        jax.clear_caches()              # no packed program is left behind
+        jax.clear_caches()
+    diff = x.astype(np.float64)[:, None, :] - about.astype(np.float64)[None]
+    hot = np.eye(K)[labels]
+    want = (hot.sum(0), np.einsum("bj,bjp->jp", hot, diff),
+            np.einsum("bj,bjp,bjq->jpq", hot, diff, diff))
+    for got, was, truth in zip(packed, plain, want):
+        assert got.shape == was.shape == truth.shape
+        assert np.linalg.norm(got - was) <= 2e-6 * np.linalg.norm(truth)
+        assert np.linalg.norm(got - truth) <= 2e-6 * np.linalg.norm(truth)
 
 
 # -- (b) the shifted one-pass covariance --------------------------------------
@@ -298,6 +388,9 @@ def test_the_counter_and_the_spans_of_one_fit():
     _fit(x, start, "full", 4)
     c = profiling.counters()
     assert c["schedules"]["gm_step:blocked"] == c["trace_by"]["gm_fit"] == 1
+    # this backend packs nothing: the M-step's product is XLA's six passes
+    assert c["schedules"]["gm_m_step:six_pass"] == 1
+    assert "gm_m_step:packed" not in c["schedules"]
     spans = c["spans"]
     for name in ("dslib.gm.fit", "dslib.gm.init", "dslib.fitloop.run",
                  "dslib.fitloop.chunk", "dslib.fitloop.commit"):
@@ -311,6 +404,24 @@ def test_the_counter_and_the_spans_of_one_fit():
     # a second fit of the same shapes traces nothing and bumps nothing
     _fit(x, start, "full", 4)
     assert profiling.schedule_counters()["gm_step:blocked"] == 1
+    assert profiling.schedule_counters()["gm_m_step:six_pass"] == 1
+
+
+@pytest.mark.parametrize("cov_type", ["tied", "diag", "spherical"])
+def test_the_m_step_counter_counts_full_covariances_only(packing, cov_type):
+    """The other covariance types have no product to pack: their fit says
+    nothing of one, packing backend or not; a full fit there says
+    ``packed``, once a trace."""
+    ds.init((1, 1), devices=jax.devices()[:1])
+    x, mu = _data(9, rows=2000)
+    profiling.reset_counters()
+    _fit(x, _start(mu, cov_type)[0], cov_type, 2)
+    assert not [key for key in profiling.schedule_counters()
+                if key.startswith("gm_m_step:")]
+    for _ in range(2):
+        _fit(x, _start(mu, "full")[0], "full", 2)
+    assert profiling.schedule_counters()["gm_m_step:packed"] == 1
+    assert "gm_m_step:six_pass" not in profiling.schedule_counters()
 
 
 @pytest.mark.parametrize("scope", ["dslib.gm.chol", "dslib.gm.e_step",
