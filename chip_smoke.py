@@ -58,7 +58,7 @@ REHEARSAL = dict(m=4_000, n=20, k=4, buckets=(1, 8),
                  summa_panel=128, ring=(96, 5), forest=(200, 3),
                  forest_trees=2, forest_nodes=4, mixture=(4_000, 10, 3))
 REHEARSAL_DEVICES = 4           # mirrors the four-chip host
-GATE_TOL = 2e-3                 # the bench gate: device vs NumPy Lloyd
+GATE_TOL = 2e-3                 # device vs NumPy Lloyd
 EM_GATE_TOL = 1e-4              # device vs NumPy EM (float64), two iterations
 BALANCE_MAX = 1.5               # per-device peak bytes, max over min
 

@@ -15,8 +15,8 @@ TPU-native redesign — no sklearn, no ragged SV sets:
   per step inside a `lax.while_loop`, step size from the Gershgorin bound
   ``η = 1/max_row_sum(|Q|)``.  ``DSLIB_CSVM_SOLVER=fista`` switches to
   accelerated PG with adaptive restart (same fixed point + stopping
-  rule, fewer sequential steps — the cascade's TPU latency driver; the
-  bench row A/Bs both, see `_use_fista`).
+  rule, fewer sequential steps — the cascade's TPU latency driver; no
+  benchmark cell A/Bs the two yet, see `_use_fista`).
 - The reference's *growing* SV sets become **fixed-capacity index buffers
   with masking** (SURVEY §8 "hard parts" #1): a cascade node is a padded
   vector of sample indices; padded slots get ``C = 0`` so their α is pinned
@@ -580,8 +580,8 @@ def _use_fista() -> bool:
     same fixed point, same stopping rule, typically several-fold fewer
     sequential while_loop steps, which is exactly the latency driver of
     the cascade on TPU (each step is one small GEMV).  'auto' currently
-    keeps plain PG: flipping the default waits for the on-chip A/B the
-    bench row now emits (the CholeskyQR2 precedent — policy changes ride
+    keeps plain PG: flipping the default waits for an on-chip A/B in a
+    benchmark cell (the CholeskyQR2 precedent — policy changes ride
     measurements, not expectations)."""
     import os
     v = os.environ.get("DSLIB_CSVM_SOLVER", "auto")

@@ -59,8 +59,8 @@ def _matmul_kernel(a, b, ta, tb, a_shape, b_shape, policy):
 # panel schedule buys nothing over the partitioner's fused dot, and a
 # small product is usually mid-chain where leaving the fusion graph would
 # cost a whole extra dispatch (module-level so tests can shrink it;
-# ``DSLIB_SUMMA_MIN_DIM`` overrides at runtime — the bench overlap tier
-# sweeps small dims on host rigs without editing source)
+# ``DSLIB_SUMMA_MIN_DIM`` overrides at runtime, so small dims can be
+# swept on host rigs without editing source)
 _SUMMA_MIN_DIM = 256
 
 

@@ -11,15 +11,15 @@ and H = UᵀA symmetric positive semi-definite.  The TPU-native fit
 — two MXU-shaped products per iteration and nothing else, which makes it
 both a capability (polar factors feed subspace orthogonalisation, the
 symmetric eigenproblem via the matrix sign function, and Procrustes
-alignment) and the library's canonical sustained-GFLOPS workload
-(``bench.py::bench_polar``: 4·m·n² FLOPs/iteration, no factorisation on
-the critical path).
+alignment) and a sustained-GEMM workload (4·m·n² FLOPs/iteration, no
+factorisation on the critical path; no benchmark cell runs it yet, so
+its rate on the chip is not measured).
 
 The whole loop — scaling, every iteration, the convergence test, and the
 final H = UᵀA — runs inside ONE jitted program (``lax.while_loop``), so a
 polar call costs ONE dispatch regardless of iteration count; the
 per-iteration dispatch cost of 0 extra is counter-pinned by
-``tests/test_precision.py`` and the bench tier.
+``tests/test_precision.py``.
 
 Mixed precision: the GEMMs route through the library precision policy
 (``ops/precision``) — ``precision="bfloat16"`` contracts bf16-compute /
@@ -142,8 +142,8 @@ def _polar_kernel(ap, shape, policy, max_iter, tol):
     # the loop-carried err describes the PRE-update iterate; on a
     # max_iter exit (the documented rank-deficient case) that would
     # overstate the returned U's error by one whole contraction — report
-    # the RETURNED factor's Gram instead (one extra (n, n) GEMM,
-    # accounted in bench_polar's FLOP formula)
+    # the RETURNED factor's Gram instead (one extra (n, n) GEMM on
+    # top of the loop's 4·m·n² FLOPs an iteration)
     g_final = px.pdot(x.T, x, policy)
     err = jnp.max(jnp.abs(g_final - eye))
     h = px.pdot(x.T, px.f32(ap), policy)                  # H = Uᵀ A

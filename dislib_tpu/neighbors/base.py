@@ -135,7 +135,7 @@ _RING_MIN = 1 << 16
 def _kneighbors_ring(qp, fp, mesh, k, mq, m_fit, overlap="db"):
     # profiled (round-13): this is a HOST dispatch boundary — one program
     # per ring kneighbors call — so "the ring schedule is still exactly
-    # one dispatch" is a counter assertion (tests/test_overlap, bench)
+    # one dispatch" is a counter assertion (tests/test_overlap.py)
     d2, idx = ring_kneighbors(qp, fp, mesh, k, m_fit, overlap=overlap)
     dist = jnp.sqrt(jnp.maximum(d2, 0.0))
     valid_q = lax.broadcasted_iota(jnp.int32, (dist.shape[0], 1), 0) < mq

@@ -22,8 +22,8 @@ software pipeline with a prologue fetch and an epilogue drain:
   data-independent, so the latency-hiding scheduler may run the
   collective concurrently with the GEMM; the loop carry holds exactly
   ONE extra in-flight panel (one panel of live memory, never a copy of
-  the operand — verified per kernel via ``compiled.memory_analysis()``
-  in the bench overlap tier).
+  the operand — verified for the panel rechunk via
+  ``compiled.memory_analysis()`` in ``tests/test_overlap.py``).
 
 Both schedules consume panels in the identical order with identical ops,
 so they are BIT-EQUAL by construction (pinned by ``tests/test_overlap``

@@ -66,7 +66,7 @@ from dislib_tpu.utils.profiling import profiled_jit as _pjit
 __all__ = [
     "requantize_body", "repad_axis", "panel_rechunk", "panel_grow_rechunk",
     "deviceput_rechunk", "reshard", "panel_memory_analysis",
-    "panel_comm_probe", "reshard_sparse", "pick_sparse_schedule",
+    "reshard_sparse", "pick_sparse_schedule",
     "dcn_rechunk", "dcn_supported", "dcn_accounting",
 ]
 
@@ -184,10 +184,10 @@ def _target_coord_tables(src_mesh: Mesh, dst_mesh: Mesh):
 
 @partial(_pjit, static_argnames=("logical_shape", "out_pshape", "src_mesh",
                                  "dst_shape", "tr_key", "tc_key", "steps",
-                                 "overlap", "comm_only"),
+                                 "overlap"),
          name="rechunk_panels")
 def _panel_exchange(data, logical_shape, out_pshape, src_mesh, dst_shape,
-                    tr_key, tc_key, steps, overlap="db", comm_only=False):
+                    tr_key, tc_key, steps, overlap="db"):
     """ONE jitted program: shard_map over the SOURCE mesh; each device
     assembles its TARGET-layout block from ``steps`` masked-psum panel
     broadcasts (the ``ops/summa.py`` collective idiom, ``check_vma`` on).
@@ -197,9 +197,7 @@ def _panel_exchange(data, logical_shape, out_pshape, src_mesh, dst_shape,
     rows-axis broadcast is issued before panel t's cols-broadcast/gather
     assembly consumes it — one extra in-flight panel of live memory
     (verified by :func:`panel_memory_analysis`), bit-equal to the
-    sequential schedule (``overlap="seq"``).  ``comm_only=True`` is the
-    bench tier's broadcast-only variant: the identical collectives with
-    the gather/assemble compute replaced by a (1, 1) touch per panel.
+    sequential schedule (``overlap="seq"``).
 
     ``tr_key``/``tc_key`` are the target-coordinate tables as hashable
     tuples (they ride the jit cache key: a different device mapping is a
@@ -242,36 +240,24 @@ def _panel_exchange(data, logical_shape, out_pshape, src_mesh, dst_shape,
                     blk = pan
                 yield s, blk
 
-        if comm_only:
-            def consume(t, acc, pan):
-                for _, blk in _col_blocks(pan):
-                    acc = acc + blk[:1, :1]
-                return acc
+        def consume(t, acc, pan):
+            owner_r = t // j
+            gr0 = owner_r * m_loc1 + (t % j) * h  # panel's global rows
+            r_in = (ri >= gr0) & (ri < gr0 + h)
+            r_idx = jnp.clip(ri - gr0, 0, h - 1)
+            for s, blk in _col_blocks(pan):
+                gc0 = s * n_loc1
+                c_in = (ci >= gc0) & (ci < gc0 + n_loc1)
+                c_idx = jnp.clip(ci - gc0, 0, n_loc1 - 1)
+                gathered = blk[r_idx][:, c_idx]
+                acc = jnp.where(r_in[:, None] & c_in[None, :],
+                                gathered, acc)
+            return acc
 
-            acc_shape = (1, 1)
-        else:
-            def consume(t, acc, pan):
-                owner_r = t // j
-                gr0 = owner_r * m_loc1 + (t % j) * h  # panel's global rows
-                r_in = (ri >= gr0) & (ri < gr0 + h)
-                r_idx = jnp.clip(ri - gr0, 0, h - 1)
-                for s, blk in _col_blocks(pan):
-                    gc0 = s * n_loc1
-                    c_in = (ci >= gc0) & (ci < gc0 + n_loc1)
-                    c_idx = jnp.clip(ci - gc0, 0, n_loc1 - 1)
-                    gathered = blk[r_idx][:, c_idx]
-                    acc = jnp.where(r_in[:, None] & c_in[None, :],
-                                    gathered, acc)
-                return acc
-
-            acc_shape = (m_loc2, n_loc2)
-
-        acc0 = lax.pcast(jnp.zeros(acc_shape, x_loc.dtype),
+        acc0 = lax.pcast(jnp.zeros((m_loc2, n_loc2), x_loc.dtype),
                          (_mesh.ROWS, _mesh.COLS), to="varying")
         acc = _ov.panel_pipeline(steps, fetch(0, None), fetch, consume,
                                  acc0, _ov.overlapped(overlap))
-        if comm_only:
-            return acc
         # re-assert the pad-and-mask invariant on the NEW canvas: entries
         # outside the logical region are zero no matter what the source
         # pad tail carried
@@ -566,26 +552,16 @@ def _panel_args_grow(data, logical_shape, dst_mesh, panels, overlap=None):
                 overlap=_ov.resolve(overlap))
 
 
-def panel_comm_probe(data, logical_shape, dst_mesh, panels=None,
-                     overlap="seq"):
-    """Broadcast-only variant of the SAME panel-exchange program — the
-    identical masked-psum collectives with the gather/assemble compute
-    replaced by a (1, 1) touch per panel, so the collectives survive
-    DCE.  The bench overlap tier's t_comm_alone denominator."""
-    kw = _panel_args(data, logical_shape, dst_mesh, panels, overlap)
-    return _panel_exchange(data, comm_only=True, **kw)
-
-
 def panel_memory_analysis(data, logical_shape, dst_mesh, panels=None,
                           overlap=None):
     """XLA's own memory accounting of the compiled panel-exchange program
-    — the bench tier's peak-live-buffer proxy.  Returns a dict with
+    — a peak-live-buffer proxy.  Returns a dict with
     ``in_bytes``/``out_bytes``/``temp_bytes`` and ``peak_live_ratio`` =
     (out + temp) / in: a schedule that gathered a full copy would sit at
     ≥ 2.0; the sequential panel schedule stays ≈ 1 + 1/panels and the
     double-buffered one ≈ 1 + 2/panels (the pipelined carry holds ONE
-    extra in-flight panel, never a copy of the operand — the bench
-    overlap tier's documented bound).  ``temp_bytes`` is None when the
+    extra in-flight panel, never a copy of the operand — the bound
+    ``tests/test_overlap.py`` asserts).  ``temp_bytes`` is None when the
     backend exposes no memory analysis (the analytic panel bound is
     reported alongside either way)."""
     kw = _panel_args(data, logical_shape, dst_mesh, panels, overlap)
@@ -810,7 +786,7 @@ def dcn_accounting(data, logical_shape, dst_mesh, panels=None) -> dict:
     - ``messages_per_step_max`` — the per-step gate: ≤ hosts − 1, never
       a function of the panel count;
     - ``deviceput_bytes`` — the bytes ANY schedule must move across
-      hosts (rows whose owning host changes), the bench floor;
+      hosts (rows whose owning host changes), the floor;
     - ``flat_messages`` / ``flat_bytes_moved`` — what the FLAT panel
       exchange would cost on the same topology: every per-rank panel
       broadcast crosses to every other host (O(panels) messages).

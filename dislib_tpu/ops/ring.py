@@ -28,8 +28,7 @@ jit static resolved by the CALLERS via ``ops/overlap.resolve`` (the
 estimator tier pickers), so a ``DSLIB_OVERLAP`` flip retraces; both
 kernels stay plain ``jax.jit`` (NOT profiled) because they are invoked
 from inside other jitted programs — the dispatch-count boundary is their
-outer kernel.  ``comm_only=True`` builds the rotation-only variant of
-the same program (the bench overlap tier's t_comm_alone denominator).
+outer kernel.
 """
 
 from __future__ import annotations
@@ -51,10 +50,9 @@ def _rotate(perm, *arrays):
     return tuple(lax.ppermute(a, _mesh.ROWS, perm) for a in arrays)
 
 
-@partial(jax.jit, static_argnames=("mesh", "k", "m_fit", "overlap",
-                                   "comm_only"))
+@partial(jax.jit, static_argnames=("mesh", "k", "m_fit", "overlap"))
 @precise
-def ring_kneighbors(qp, fp, mesh, k, m_fit, overlap="db", comm_only=False):
+def ring_kneighbors(qp, fp, mesh, k, m_fit, overlap="db"):
     """(distances², indices) of the k nearest fitted rows per query row.
 
     qp, fp: canonically sharded padded backings (rows over 'rows', features
@@ -76,17 +74,6 @@ def ring_kneighbors(qp, fp, mesh, k, m_fit, overlap="db", comm_only=False):
             return _rotate(perm, *prev)     # one ICI hop per carried array
 
         pan0 = (f, f_sq0, ids0)
-
-        if comm_only:
-            def consume(t, acc, pan):
-                f_cur, fsq_cur, ids_cur = pan
-                return (acc + f_cur[:1, :1] + fsq_cur[:1][None]
-                        + ids_cur[:1][None].astype(acc.dtype))
-
-            acc0 = lax.pcast(jnp.zeros((1, 1), q.dtype),
-                             (_mesh.ROWS, _mesh.COLS), to="varying")
-            return _ov.panel_pipeline(nrows, pan0, fetch, consume, acc0,
-                                      _ov.overlapped(overlap))
 
         def consume(t, carry, pan):
             best_d, best_i = carry
@@ -117,12 +104,10 @@ def ring_kneighbors(qp, fp, mesh, k, m_fit, overlap="db", comm_only=False):
                                             acc0, _ov.overlapped(overlap))
         return jnp.maximum(best_d, 0.0), best_i
 
-    out_specs = P(_mesh.ROWS, _mesh.COLS) if comm_only \
-        else (P(_mesh.ROWS, None), P(_mesh.ROWS, None))
     return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(_mesh.ROWS, _mesh.COLS), P(_mesh.ROWS, _mesh.COLS)),
-        out_specs=out_specs,
+        out_specs=(P(_mesh.ROWS, None), P(_mesh.ROWS, None)),
         check_vma=True,
     )(qp, fp)
 
@@ -146,10 +131,10 @@ def ring_auto(flag, mesh, large):
     return mesh.shape[_mesh.ROWS] > 1 and large
 
 
-@partial(jax.jit, static_argnames=("mesh", "overlap", "comm_only"))
+@partial(jax.jit, static_argnames=("mesh", "overlap"))
 @precise
 def ring_neigh_count_min(xp, eps2, vals, colmask, sentinel, mesh,
-                         overlap="db", comm_only=False):
+                         overlap="db"):
     """Per-row (ε-neighbor count, min over neighbor vals) of a row-sharded
     dataset against itself — `ops/tiled.neigh_count_min` distributed over
     the mesh 'rows' axis.
@@ -224,18 +209,6 @@ def ring_neigh_count_min(xp, eps2, vals, colmask, sentinel, mesh,
 
         pan0 = (x, row_ids, v, cm)
 
-        if comm_only:
-            def consume(t, acc, pan):
-                xc, idc, vc, cmc = pan
-                return (acc + xc[:1, :1] + vc[:1][None]
-                        + idc[:1][None].astype(acc.dtype)
-                        + cmc[:1][None].astype(acc.dtype))
-
-            acc0 = lax.pcast(jnp.zeros((1, 1), x.dtype),
-                             (_mesh.ROWS, _mesh.COLS), to="varying")
-            return _ov.panel_pipeline(nrows, pan0, fetch, consume, acc0,
-                                      _ov.overlapped(overlap))
-
         def consume(t, acc, pan):
             xc, idc, vc, cmc = pan
             cnt, mn = pair_pass(xc, idc, vc, cmc, acc[0], acc[1])
@@ -255,11 +228,9 @@ def ring_neigh_count_min(xp, eps2, vals, colmask, sentinel, mesh,
         mn = lax.pmin(mn, _mesh.COLS)
         return cnt, mn
 
-    out_specs = P(_mesh.ROWS, _mesh.COLS) if comm_only \
-        else (P(_mesh.ROWS), P(_mesh.ROWS))
     return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(_mesh.ROWS, _mesh.COLS), P(_mesh.ROWS), P(_mesh.ROWS)),
-        out_specs=out_specs,
+        out_specs=(P(_mesh.ROWS), P(_mesh.ROWS)),
         check_vma=True,
     )(xp, vals, colmask)

@@ -30,8 +30,8 @@ f64 for x64-mode f64 operands under the float32-floor policy) — the
 
 Memory: per device, the live set is the local sparse buffers (O(nnz/p)),
 the local B block, the output block, and ONE in-flight panel (two under
-db) of B — never a densified A, never a gathered B.  The bench sparse
-tier pins this through ``compiled.memory_analysis()``.
+db) of B — never a densified A, never a gathered B
+(``tests/test_spmm.py`` pins this through ``compiled.memory_analysis()``).
 
 Entry locality (the round-17 fix of the measured 0.87× panel-count
 inflation): the default ``layout="slots"`` path consumes the
@@ -81,8 +81,8 @@ def _fit_steps(requested, k_pad):
 def spmm_steps(mesh=None, panels=None) -> int:
     """Panel count of the SpMM schedule: ``DSLIB_SPMM_PANELS`` (default
     4), clamped to ≥ 2 so the double-buffered pipeline has something to
-    overlap.  The kernel's own step formula, exposed for the bench
-    tier's memory gate (the ``summa_steps`` precedent).
+    overlap.  The kernel's own step formula (the ``summa_steps``
+    precedent).
 
     Unlike SUMMA's lcm-locked panel count, SpMM's panels DECOUPLE from
     the mesh: a panel may span several owner row-ranks (each
@@ -99,11 +99,11 @@ def spmm_steps(mesh=None, panels=None) -> int:
 
 
 @partial(_pjit, static_argnames=("mesh", "policy", "overlap", "steps",
-                                 "m_local", "comm_only", "layout"),
+                                 "m_local", "layout"),
          name="spmm_panels")
 @px.precise
 def spmm_panels(data, lrows, cols, counts, bp, mesh, policy, steps,
-                m_local, overlap="db", comm_only=False, layout="masked"):
+                m_local, overlap="db", layout="masked"):
     """C = A @ B: sharded sparse buffers × canonically sharded dense.
 
     Under ``layout="masked"``, ``data``/``lrows``/``cols``/``counts``
@@ -118,10 +118,6 @@ def spmm_panels(data, lrows, cols, counts, bp, mesh, policy, steps,
     dtype, canonically sharded — M_pad = p · m_local by the
     representation's canonical-row-split invariant, so the output IS a
     valid dense ds-array backing.
-
-    ``comm_only=True`` is the bench tier's broadcast-only variant of the
-    SAME program (identical collectives, the gather/segment compute
-    replaced by a (1, 1) panel touch) — the t_comm_alone denominator.
 
     ONE dispatch end to end under every ``overlap`` schedule: the panel
     loop is a ``fori_loop`` inside this single jitted program.
@@ -171,12 +167,7 @@ def spmm_panels(data, lrows, cols, counts, bp, mesh, policy, steps,
                             jnp.zeros((), bc.dtype))
             return lax.psum(pan, _mesh.ROWS)
 
-        if comm_only:
-            def consume(t, acc, pan):
-                return acc + pan[:1, :1].astype(acc.dtype)
-
-            acc_shape = (1, 1)
-        elif layout == "slots":
+        if layout == "slots":
             def consume(t, acc, pan):
                 # panel t's OWN slot range: nse_p entries, not nse — the
                 # per-panel count masks the quantum tail (poisoned view
@@ -188,8 +179,6 @@ def spmm_panels(data, lrows, cols, counts, bp, mesh, policy, steps,
                 contrib = (g * w[:, None]).astype(acc.dtype)
                 return acc + jax.ops.segment_sum(contrib, lrd[t],
                                                  num_segments=m_local)
-
-            acc_shape = (m_local, n_loc)
         else:
             def consume(t, acc, pan):
                 off = t * h              # the panel's global B-row window
@@ -200,9 +189,7 @@ def spmm_panels(data, lrows, cols, counts, bp, mesh, policy, steps,
                 return acc + jax.ops.segment_sum(contrib, lr,
                                                  num_segments=m_local)
 
-            acc_shape = (m_local, n_loc)
-
-        acc0 = lax.pcast(jnp.zeros(acc_shape, acc_dt),
+        acc0 = lax.pcast(jnp.zeros((m_local, n_loc), acc_dt),
                          (_mesh.ROWS, _mesh.COLS), to="varying")
         return _ov.panel_pipeline(steps, fetch(0, None), fetch, consume,
                                   acc0, _ov.overlapped(overlap))
@@ -258,28 +245,13 @@ def spmm(a, b, *, precision=None, overlap=None, panels=None, layout=None):
                  reg_shape=(a.block_size[0], b._reg_shape[1]))
 
 
-def spmm_comm_probe(a, b, overlap="seq"):
-    """Broadcast-only variant of the SAME SpMM program (identical
-    collectives, compute replaced by a (1, 1) panel touch) — the bench
-    tier's t_comm_alone denominator."""
-    from dislib_tpu.data.array import ensure_canonical
-    mesh = _mesh.get_mesh()
-    rep = a.sharded(mesh)
-    b = ensure_canonical(b)
-    bd = b._data
-    return spmm_panels(rep.data, rep.lrows, rep.cols, rep.counts_dev,
-                       bd, mesh, px.resolve(None),
-                       _fit_steps(spmm_steps(mesh), bd.shape[0]),
-                       rep.m_local, overlap=overlap, comm_only=True)
-
-
 def spmm_memory_analysis(a, b, *, precision=None, overlap=None,
                          panels=None, layout=None):
-    """XLA's own accounting of the compiled SpMM program — the bench
-    tier's O(nnz)-scaled peak-live proxy.  Returns input/output/temp
+    """XLA's own accounting of the compiled SpMM program — an
+    O(nnz)-scaled peak-live proxy.  Returns input/output/temp
     bytes plus ``temp_vs_dense``: temp as a fraction of what a densified
     A alone would allocate (the densify route's floor) — the number the
-    O(nnz) claim gates on.  Analyses the DEFAULT (slot-range) program
+    O(nnz) claim rests on (``tests/test_spmm.py``).  Analyses the DEFAULT (slot-range) program
     unless ``layout="masked"``."""
     from dislib_tpu.data.array import ensure_canonical, _padded_shape
     mesh = _mesh.get_mesh()
@@ -316,7 +288,7 @@ def spmm_memory_analysis(a, b, *, precision=None, overlap=None,
 
 def spmm_masking_work(a, b=None, *, panels=None):
     """Per-dispatch entry-touch accounting of the two SpMM layouts — the
-    bench tier's masking-inflation evidence.  ``masked_work`` is what
+    masking-inflation evidence.  ``masked_work`` is what
     the legacy layout executes (every one of the nse slots re-masked on
     every panel: steps·nse); ``slots_work`` is what the slot-range
     layout executes (one nse_p slot range per panel: steps·nse_p ≈

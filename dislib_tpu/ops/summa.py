@@ -30,7 +30,7 @@ chain, and ``overlap="pallas"`` lowers the panel GEMM through
 ``ops/pallas_kernels``.  All schedules consume panels in identical
 order, so ``db`` and ``seq`` are bit-equal (``tests/test_overlap``); the
 double buffer's cost is ONE extra in-flight panel pair, never a copy of
-an operand (bench overlap tier verifies via XLA memory analysis).
+an operand.
 
 Mixed precision: the local panel GEMMs contract via the library precision
 policy (``ops/precision.pdot``) — bf16-compute / f32-accumulate under the
@@ -70,16 +70,16 @@ def summa_steps(mesh=None) -> int:
     the panel width is the largest chunk that lives whole on exactly one
     cols-rank of A AND one rows-rank of B.  THE step-count formula of
     :func:`summa_matmul` (the kernel calls this too), exposed so
-    per-panel consumers (the bench overlap tier's one-extra-panel memory
-    gate) stay anchored to the kernel instead of re-deriving it."""
+    per-panel consumers stay anchored to the kernel instead of
+    re-deriving it."""
     r, c = _mesh.mesh_shape(mesh)
     return r * c // math.gcd(r, c)
 
 
-@partial(_pjit, static_argnames=("mesh", "policy", "overlap", "comm_only"),
+@partial(_pjit, static_argnames=("mesh", "policy", "overlap"),
          name="summa_matmul")
 @px.precise
-def summa_matmul(ap, bp, mesh, policy, overlap="db", comm_only=False):
+def summa_matmul(ap, bp, mesh, policy, overlap="db"):
     """C = A @ B over canonically (rows, cols)-sharded padded operands.
 
     ``ap`` (M_pad, K_pad) and ``bp`` (K_pad, N_pad) must agree on K_pad
@@ -89,16 +89,11 @@ def summa_matmul(ap, bp, mesh, policy, overlap="db", comm_only=False):
 
     ``overlap`` is the resolved panel schedule (``ops/overlap.resolve``
     — callers resolve so the ``DSLIB_OVERLAP`` env flip retraces as a
-    static).  ``comm_only=True`` is the bench overlap tier's
-    broadcast-only variant of the SAME program: the identical panel
-    fetch loop with the GEMMs replaced by a (1, 1) touch of each panel
-    (so the collectives survive DCE) — the t_comm_alone denominator of
-    the comm-hidden fraction.
+    static).
 
     ONE dispatch end to end under every schedule: the panel loop is a
     ``lax.fori_loop`` inside this single jitted program — counter-pinned
-    by ``tests/test_precision.py``/``tests/test_overlap.py`` and the
-    bench tier's ``dispatches_per_op``.
+    by ``tests/test_precision.py``/``tests/test_overlap.py``.
     """
     nrows = mesh.shape[_mesh.ROWS]
     ncols = mesh.shape[_mesh.COLS]
@@ -150,27 +145,18 @@ def summa_matmul(ap, bp, mesh, policy, overlap="db", comm_only=False):
                 b_pan = lax.psum(b_pan, _mesh.ROWS)
             return a_pan, b_pan
 
-        if comm_only:
-            def consume(t, acc, pan):
-                a_pan, b_pan = pan
-                return acc + a_pan[:1, :1] + b_pan[:1, :1]
-
-            acc_shape = (1, 1)
-        else:
-            def consume(t, acc, pan):
-                a_pan, b_pan = pan
-                with jax.named_scope("dslib.summa.gemm"):
-                    if overlap == "pallas":
-                        from dislib_tpu.ops import pallas_kernels as _pk
-                        return acc + _pk.panel_gemm(a_pan, b_pan, policy)
-                    return acc + px.pdot(a_pan, b_pan, policy)
-
-            acc_shape = (m_loc, n_loc)
+        def consume(t, acc, pan):
+            a_pan, b_pan = pan
+            with jax.named_scope("dslib.summa.gemm"):
+                if overlap == "pallas":
+                    from dislib_tpu.ops import pallas_kernels as _pk
+                    return acc + _pk.panel_gemm(a_pan, b_pan, policy)
+                return acc + px.pdot(a_pan, b_pan, policy)
 
         # seed the accumulator as device-varying up front so the fori_loop
         # carry's replication type is stable round over round (the ring
         # kernels' check_vma idiom)
-        acc0 = lax.pcast(jnp.zeros(acc_shape, acc_dt),
+        acc0 = lax.pcast(jnp.zeros((m_loc, n_loc), acc_dt),
                          (_mesh.ROWS, _mesh.COLS), to="varying")
         return _ov.panel_pipeline(steps, fetch(0, None), fetch, consume,
                                   acc0, _ov.overlapped(overlap))

@@ -3,9 +3,9 @@
 The directory is part of the cache key, so it must not move between
 processes or runs: ``JAX_COMPILATION_CACHE_DIR`` when the environment sets
 it (JAX reads that variable itself, and no code here sets another), else
-``<checkout>/.jax_cache`` next to the package.  ``chip_smoke.py``,
-``bench.py`` and the benchmark's harness call :func:`enable`; side files
-that must be shared between a run's processes (bench setup caches) follow
+``<checkout>/.jax_cache`` next to the package.  ``chip_smoke.py`` and
+the benchmark's harness (``benchmark/harness.py``) call :func:`enable`;
+side files that must be shared between a run's processes follow
 :func:`resolve_dir`.
 
 The checkout's own directory must not be part of a key either.  JAX

@@ -47,9 +47,9 @@ end-to-end**, from four pieces that compose:
   ``promote``.
 
 See the user guide's "Serving & hot-swap" and "Deployment bundles &
-multi-tenant serving" sections for the end-to-end story and
-`bench.py::bench_serving` / ``bench_serving_fleet`` for the
-regression-gated numbers.
+multi-tenant serving" sections for the end-to-end story.  No benchmark
+cell serves yet: serving throughput and latency on the chip are not
+measured (``PERF.md`` section 7 lists the cell that would).
 """
 
 from dislib_tpu.serving.buckets import (DEFAULT_BUCKETS, BucketLadderError,

@@ -195,7 +195,7 @@ class PredictServer:
         self._t_last = None
         # per-tenant observability (round 15): latency windows, request
         # tallies, and shed counts keyed by the submit() tenant label —
-        # the fleet bench and the router read THESE numbers rather than
+        # the router and any load test read THESE numbers rather than
         # timing around the server
         self._shed = 0
         self._tenant_lat: dict[str, deque] = {}
@@ -605,7 +605,7 @@ class PredictServer:
         rejected by backpressure — total, and per tenant), and the
         per-batch dispatch distribution (the 1-dispatch-per-batch
         invariant as a number; oversize split requests legitimately cost
-        one dispatch per piece).  The fleet bench reads ITS headline
+        one dispatch per piece).  A load test reads its headline
         numbers from here — the server is its own observability source.
         Dispatch deltas read the process-wide profiling counters —
         concurrent non-serving device work in the same process would

@@ -284,7 +284,7 @@ def schedule_counters() -> dict:
     """``{"kernel:schedule": count}`` tallies since the last
     ``reset_counters()`` — the overlap router's observability surface
     (``DSLIB_OVERLAP`` routing is asserted through this in
-    ``tests/test_overlap.py`` and the bench overlap tier)."""
+    ``tests/test_overlap.py`` and ``tests/test_spmm.py``)."""
     with _COUNTERS_LOCK:
         return dict(_COUNTERS.schedules)
 
@@ -328,7 +328,7 @@ def counters() -> dict:
 
 
 def reset_counters() -> None:
-    """Zero every tally, the spans' too (tests and bench regions)."""
+    """Zero every tally, the spans' too (tests and chip_smoke.py)."""
     with _COUNTERS_LOCK:
         _COUNTERS.dispatches = 0
         _COUNTERS.traces = 0

@@ -7,8 +7,8 @@ never a silently corrupt model.
 
 The full matrix is `slow` (run via ``tools/chaos_soak.sh --matrix``,
 which appends the machine-readable ``CHAOS_MATRIX_SUMMARY`` line — per
-cell verdicts + the process resilience counters — to the local bench
-JSONL).  A 2-estimator smoke subset rides tier-1, shapes mirroring
+cell verdicts + the process resilience counters — to the JSONL file it
+is given).  A 2-estimator smoke subset rides tier-1, shapes mirroring
 ``tests/test_health.py`` so its kernels are suite-wide cache hits.
 
 ``DSLIB_MATRIX_SEED`` (default 0) seeds the data draws, so a failing

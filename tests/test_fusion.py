@@ -32,6 +32,12 @@ def _x(rng, m=37, n=11):
 
 
 class TestFusionCorrectness:
+    @pytest.mark.xfail(
+        os.environ.get("DSLIB_TEST_TPU") != "1", strict=True,
+        raises=AssertionError,
+        reason="on XLA:CPU the fused program and the per-op path differ in "
+               "the last place or two (8 of 12 entries, 2e-7 relative).  "
+               "If this passes on the CPU, remove the marker.")
     def test_chain_bitmatches_eager(self, rng, monkeypatch):
         x = _x(rng)
         fused = ds.matmul((ds.array(x, block_size=(16, 8)) * 2.0 + 1.0).T,

@@ -754,31 +754,3 @@ class TestSummaMinDimKnob:
         assert mb._summa_min_dim() == mb._SUMMA_MIN_DIM
         monkeypatch.setenv("DSLIB_SUMMA_MIN_DIM", "512")
         assert mb._summa_min_dim() == 512
-
-
-# ---------------------------------------------------------------------------
-# 9. comm-only probes: same collectives, no compute (bench denominator)
-# ---------------------------------------------------------------------------
-
-class TestCommOnlyProbes:
-    def test_probes_run_and_shape(self):
-        skip_unless_devices(8)
-        from dislib_tpu.ops.summa import summa_matmul
-        from dislib_tpu.ops.ring import ring_kneighbors
-        from dislib_tpu.ops import rechunk as _rc
-        ds.init((4, 2))
-        mesh = _mesh.get_mesh()
-        a = ds.array(_mk((96, 64))).force()
-        b = ds.array(_mk((64, 80), seed=1)).force()
-        out = summa_matmul(a._data, b._data, mesh, px.FLOAT32,
-                           overlap="seq", comm_only=True)
-        assert out.shape == (4, 2) and np.isfinite(np.asarray(out)).all()
-        f = ds.array(_mk((40, 8), seed=2)).force()
-        q = ds.array(_mk((16, 8), seed=3)).force()
-        out = ring_kneighbors(q._data, f._data, mesh, 3, 40,
-                              overlap="seq", comm_only=True)
-        assert out.shape == (4, 2)
-        ds.init((2, 4))
-        dst = _mesh.get_mesh()
-        probe = _rc.panel_comm_probe(a._data, a.shape, dst, 4)
-        assert np.isfinite(np.asarray(probe)).all()

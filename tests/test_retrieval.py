@@ -402,6 +402,13 @@ class TestRetrievalServing:
         assert st["acme"]["serving"]["requests"] == 1
         assert st["acme"]["deadline_shed"] == 0
 
+    @pytest.mark.xfail(
+        os.environ.get("DSLIB_TEST_TPU") != "1", strict=True,
+        raises=jax.errors.JaxRuntimeError,
+        reason="the CPU client cannot serialise the IVF search executable "
+               "(UNIMPLEMENTED: `LessThan` is not serializable), so "
+               "export_bundle raises before the child starts.  If this "
+               "passes on the CPU, remove the marker.")
     def test_bundle_roundtrip_fresh_subprocess(self, rng, tmp_path):
         """The headline cold-start claim: a process that never saw the
         index serves [ids|scores] off the bundle with ZERO traces."""

@@ -27,10 +27,6 @@ MUTATION_ALLOWLIST = {
     "tests/conftest.py",
     "tests/mp_worker.py",
     "examples/multihost_launch.py",
-    # bench smoke children fake a 2-D mesh for the SUMMA tier with
-    # virtual host devices (the conftest bootstrap, applied pre-import
-    # in the per-config subprocess); device-count flag only
-    "bench.py",
     # round-19 two-process dryrun worker: each rank bootstraps 2 virtual
     # CPU devices pre-import (the mp_worker precedent); device-count
     # flag only
