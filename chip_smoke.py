@@ -3,12 +3,14 @@
 
 One process, which holds the chip for the whole run; no child touches JAX.
 It drives the library boundary a user drives — ingest, scaler, a
-checkpointed KMeans fit, a Gaussian mixture fit, the predict server, a
-deployment bundle, and the three Pallas kernels inside their callers — at
-the full width of the north-star model of ``BASELINE.json`` (KMeans
-1 000 000 x 100, k=10) and of its mixture (1 000 000 x 50, k=16), and
-checks every result by the repo's own means (a NumPy Lloyd oracle, a NumPy
-EM oracle, the direct predict path, bit-equality across a bundle round
+checkpointed KMeans fit, a Gaussian mixture fit, a randomized SVD, the
+predict server, a deployment bundle, and the three Pallas kernels inside
+their callers — at the full width of the north-star model of
+``BASELINE.json`` (KMeans 1 000 000 x 100, k=10), of its mixture
+(1 000 000 x 50, k=16) and of its randomized SVD's sketch (256 of 1024
+columns), and checks every result by the repo's own means (a NumPy Lloyd
+oracle, a NumPy EM oracle, NumPy's singular values in float64, the
+direct predict path, bit-equality across a bundle round
 trip, the XLA schedule of each kernel inside
 ``ops/precision.ERROR_BOUNDS``).
 
@@ -50,16 +52,20 @@ import numpy as np
 FULL = dict(m=1_000_000, n=100, k=10, buckets=(1, 8, 64, 512),
             request_rows=(1, 3, 8, 17, 64, 200, 512), n_requests=36,
             summa_panel=8192, ring=(200_000, 10), forest=(100_000, 20),
-            forest_trees=4, forest_nodes=8, mixture=(1_000_000, 50, 16))
+            forest_trees=4, forest_nodes=8, mixture=(1_000_000, 50, 16),
+            rsvd=(262_144, 1024, 128, 128, 512, 0.98))
 # the same phases at a size the CPU backend and the Pallas interpreter
 # finish in seconds
 REHEARSAL = dict(m=4_000, n=20, k=4, buckets=(1, 8),
                  request_rows=(1, 3, 8), n_requests=9,
                  summa_panel=128, ring=(96, 5), forest=(200, 3),
-                 forest_trees=2, forest_nodes=4, mixture=(4_000, 10, 3))
+                 forest_trees=2, forest_nodes=4, mixture=(4_000, 10, 3),
+                 rsvd=(6_000, 96, 12, 12, 48, 0.9))
 REHEARSAL_DEVICES = 4           # mirrors the four-chip host
 GATE_TOL = 2e-3                 # device vs NumPy Lloyd
 EM_GATE_TOL = 1e-4              # device vs NumPy EM (float64), two iterations
+RSVD_VALUES_TOL = 1e-4          # device S vs NumPy's (float64), over S[0]
+RSVD_ORTH_TOL = 5e-6            # max |U^T U - I|, summed in float64
 BALANCE_MAX = 1.5               # per-device peak bytes, max over min
 
 
@@ -378,6 +384,55 @@ def phase_mixture(run):
                                                 "after the mixture fit")}
 
 
+def phase_rsvd(run):
+    """``ds.random_svd`` at the sketch width of the benchmark's cell (one
+    256-column block) and as many rows as the host can factor in float64:
+    S against NumPy's singular values, U's orthogonality summed in
+    float64, one dispatch a call, and the shard-local factorisation the
+    traces took (``tsqr_local``).  The rank is half the sketch, so that
+    two power iterations bring the kept values to float32's accuracy
+    (the cell keeps 246 of 256, and is held to a reference of the same
+    algorithm instead)."""
+    import dislib_tpu as ds
+    from dislib_tpu.utils import profiling as prof
+
+    m, n, nsv, over, directions, ratio = run.cfg["rsvd"]
+    rng = np.random.RandomState(2)
+    w = np.linalg.qr(rng.randn(n, directions))[0]
+    mix = ((ratio ** np.arange(directions))[:, None] * w.T)
+    x_host = (rng.randn(m, directions).astype(np.float32)
+              @ mix.astype(np.float32)
+              + 1e-5 * rng.randn(m, n).astype(np.float32))
+    x = ds.array(x_host)
+    prof.reset_counters()
+
+    def call():
+        u, s, v = ds.random_svd(x, iters=2, nsv=nsv, oversample=over,
+                                random_state=7)
+        s, v = s.collect(), v.collect()
+        u.block_until_ready()
+        return u, s, v
+    (u, s, v), first_s = _wall(call)
+    _, warm_s = _wall(call)
+    assert prof.counters()["dispatch_by"] == {"random_svd": 2}
+    routes = _routes(prof, "tsqr_local")
+
+    x64 = x_host.astype(np.float64)
+    want = np.sqrt(np.linalg.eigvalsh(x64.T @ x64)[::-1][:nsv])
+    values_gap = float(np.abs(np.ravel(s) - want).max() / want[0])
+    assert values_gap <= RSVD_VALUES_TOL, values_gap
+    u64 = np.asarray(u.collect(), np.float64)
+    orth = float(np.abs(u64.T @ u64 - np.eye(nsv)).max())
+    assert orth <= RSVD_ORTH_TOL, orth
+    v64 = np.asarray(v, np.float64)
+    resid = float(np.linalg.norm(x64 @ v64 - u64 * np.ravel(s))
+                  / np.linalg.norm(want))
+    return {"first_call_s": first_s, "warm_call_s": warm_s,
+            "shape": [m, n], "nsv": nsv, "sketch": nsv + over,
+            "values_gap_vs_numpy": values_gap, "u_orthogonality": orth,
+            "residual": resid, "tsqr_local": routes}
+
+
 def phase_serve(run):
     import dislib_tpu as ds
     from dislib_tpu.serving import PredictServer, ServePipeline
@@ -642,6 +697,7 @@ def main(argv=None) -> int:
     run.phase("device", phase_device)
     run.phase("train", phase_train)
     run.phase("mixture", phase_mixture)
+    run.phase("rsvd", phase_rsvd)
     run.phase("serve", phase_serve)
     run.phase("bundle", phase_bundle)
     run.phase("kernels", phase_kernels)

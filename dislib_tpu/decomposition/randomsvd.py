@@ -24,13 +24,20 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from dislib_tpu.data.array import Array, _repad
+from dislib_tpu.data.array import Array
 from dislib_tpu.math import matmul
 from dislib_tpu.decomposition.tsqr import (tsqr, _tsqr_shardmap,
                                            _use_cholqr)
 from dislib_tpu.parallel import mesh as _mesh
+from dislib_tpu.ops import base as _ops
 from dislib_tpu.ops import precision as px
 from dislib_tpu.ops.base import precise
+from dislib_tpu.utils.profiling import new_call as _new_call
+from dislib_tpu.utils.profiling import profiled_jit as _pjit
+from dislib_tpu.utils.profiling import span as _span
+
+
+_CALL = "dslib.rsvd.call"
 
 
 def random_svd(a: Array, iters: int = 2, epsilon: float | None = None,
@@ -42,14 +49,29 @@ def random_svd(a: Array, iters: int = 2, epsilon: float | None = None,
     Returns (U, S, V) with U (m, k), S (1, k), V (n, k); ``k`` defaults to
     ``nsv`` (number of singular values) + oversampling, truncated to nsv.
 
+    The test matrix is a contract: for an integer ``random_state`` it is
+    ``jax.random.normal(jax.random.PRNGKey(random_state), (n, nsv +
+    oversample), jnp.float32)`` (the sketch capped at n), so that another
+    implementation of the algorithm can be handed the same draw.
+
     ``precision``: mixed-precision policy (None → the
     ``DSLIB_MATMUL_PRECISION`` default).  The policy governs the sketch /
     power-iteration / projection / back-multiplication GEMMs (all the
     O(mn·sketch) FLOPs); the tsQR re-orthonormalisations and the small
     (sketch, n) SVD stay float32 — bounds in
     ``ops/precision.ERROR_BOUNDS``.
+
+    A dense float32 array takes ONE dispatch, which returns before the
+    device is done.  No product of it contracts more than about 10^4 rows
+    at a time: the products over the rows and the orthonormalisations'
+    Grams are compensated sums over row blocks (``ops/base.py``).
     """
-    policy = px.resolve(precision)
+    with _span(_CALL, call=_new_call()):
+        return _random_svd(a, iters, nsv, k, oversample, random_state,
+                           px.resolve(precision))
+
+
+def _random_svd(a, iters, nsv, k, oversample, random_state, policy):
     m, n = a.shape
     nsv = nsv if nsv is not None else (k if k is not None else min(m, n, 6))
     sketch = min(n, nsv + oversample)
@@ -62,12 +84,13 @@ def random_svd(a: Array, iters: int = 2, epsilon: float | None = None,
         # (x64-mode CPU rig) keep the composed path's dtype fidelity
         mesh = _mesh.get_mesh()
         p = mesh.shape[_mesh.ROWS]
-        u_log, s, vt = _random_svd_fused(
+        q = _mesh.pad_quantum()
+        u, s, v = _random_svd_fused(
             a._data, jax.random.PRNGKey(seed), a.shape, iters, sketch,
-            nsv, mesh, p, cholqr=_use_cholqr(), policy=policy)
-        u = Array._from_logical_padded(_repad(u_log, (m, nsv)), (m, nsv))
-        v = Array._from_logical(vt.T[:, :nsv])
-        return u, Array._from_logical(s[:nsv].reshape(1, -1)), v
+            nsv, mesh, p, q, cholqr=_use_cholqr(), policy=policy)
+        return (Array._from_logical_padded(u, (m, nsv)),
+                Array._from_logical_padded(s, (1, nsv)),
+                Array._from_logical_padded(v, (n, nsv)))
 
     omega = Array._from_logical(_omega_of(jax.random.PRNGKey(seed), n, sketch))
 
@@ -96,18 +119,27 @@ def random_svd(a: Array, iters: int = 2, epsilon: float | None = None,
     return u, s_arr, v
 
 
-@partial(jax.jit, static_argnames=("a_shape", "iters", "sketch", "nsv",
-                                   "cholqr", "policy",
-                                   "mesh", "p"))
+@partial(_pjit, name="random_svd",
+         static_argnames=("a_shape", "iters", "sketch", "nsv", "cholqr",
+                          "policy", "mesh", "p", "quantum"))
 @precise
 def _random_svd_fused(a_pad, key, a_shape, iters, sketch, nsv, mesh, p,
-                      *, cholqr, policy=px.FLOAT32):
-    """Sketch + power iterations + projection + SVD as one XLA program.
+                      quantum, *, cholqr, policy=px.FLOAT32):
+    """Sketch + power iterations + projection + SVD as one XLA program,
+    its three results padded to the mesh ``quantum`` and zero beyond their
+    logical shapes, as ``Array`` holds them.
 
     Quantum-padded rows/cols of ``a_pad`` are zero, so they contribute
     nothing to any GEMM; tsQR's Q rows at zero input rows are zero for a
     full-column-rank sketch (Q_i R = 0 with R invertible ⇒ Q_i = 0), which
-    keeps the returned U's logical crop exact."""
+    keeps the returned U's logical crop exact.
+
+    The two products that contract the rows, AᵀQ of a power iteration and
+    QᵀA of the projection (its transpose), are ONE blocked pass each
+    (``ops/base.py::blocked_row_sums``): a block's product at a time, the
+    blocks' products in compensated sums, one ``psum`` over the mesh's
+    rows.  The products along a row (A·Ω, A·Q_z, Q·U_b) contract n or the
+    sketch and stay whole."""
     m, n = a_shape
     av = px.f32(a_pad[:, :n])
     av = lax.with_sharding_constraint(av, _mesh.row_sharding())
@@ -122,15 +154,36 @@ def _random_svd_fused(a_pad, key, a_shape, iters, sketch, nsv, mesh, p,
         q, _ = _tsqr_shardmap(y, mesh, p, cholqr=cholqr)
         return q[:rows]
 
-    q = ortho(px.pdot(av, _omega_of(key, n, sketch), policy))
-    for _ in range(iters):
-        qz = ortho(px.pdot(av.T, q, policy))
-        q = ortho(px.pdot(av, qz, policy))
+    def down(q):
+        """Aᵀ·Q, (n, sketch), the rows contracted a block at a time."""
+        return _ops.blocked_row_sums(
+            av, m, n, _ops.contract_row_bytes(4 * (n + sketch)),
+            lambda xb, w, _start, qb: px.pdot_tall(xb, qb * w[:, None],
+                                                   policy),
+            jnp.zeros((n, sketch), av.dtype), per_row=(q,))
 
-    b = px.pdot(q.T, av, policy)             # (sketch, n), replicated
-    ub, s, vt = jnp.linalg.svd(b, full_matrices=False)
-    u = px.pdot(q, ub[:, :nsv], policy)      # (M_pad, nsv)
-    return u[:m], s, vt
+    with jax.named_scope("dslib.rsvd.sketch"):
+        q = ortho(px.pdot(av, _omega_of(key, n, sketch), policy))
+    for _ in range(iters):
+        with jax.named_scope("dslib.rsvd.power"):
+            qz = ortho(down(q))
+            q = ortho(px.pdot(av, qz, policy))
+
+    with jax.named_scope("dslib.rsvd.project"):
+        b = down(q).T                        # (sketch, n), replicated
+    with jax.named_scope("dslib.rsvd.small_svd"):
+        ub, s, vt = jnp.linalg.svd(b, full_matrices=False)
+    with jax.named_scope("dslib.rsvd.lift"):
+        u = px.pdot(q, ub[:, :nsv], policy)  # (M_pad, nsv)
+
+    def padded(x, shape):
+        fill = [(0, -(-want // quantum) * quantum - have)
+                for want, have in zip(shape, x.shape)]
+        x = jnp.pad(x, fill) if any(f for _, f in fill) else x
+        return lax.with_sharding_constraint(x, _mesh.data_sharding())
+
+    return (padded(u[:m], (m, nsv)), padded(s[None, :nsv], (1, nsv)),
+            padded(vt.T[:, :nsv], (n, nsv)))
 
 
 def _omega_of(key, n, sketch):

@@ -26,8 +26,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from dislib_tpu.data.array import Array
 from dislib_tpu.parallel import mesh as _mesh
+from dislib_tpu.ops import base as _ops
 from dislib_tpu.ops import precision as px
 from dislib_tpu.ops.base import precise
+from dislib_tpu.utils.profiling import count_schedule as _count_schedule
 
 
 def tsqr(a: Array, mode: str = "reduced", indexes=None, precision=None):
@@ -82,60 +84,138 @@ def _use_cholqr() -> bool:
     return v == "1"
 
 
+def _panel_block(rows: int, n: int) -> int:
+    """Rows of one block of a shard's (rows, n) panel: what ``ops/base.py``
+    derives for a block that holds a row of n float32 four times (in, out,
+    and the parts a 'highest' product splits it in), and no more rows than
+    one product may contract."""
+    return _ops.row_block(rows, _ops.contract_row_bytes(16 * n))
+
+
+def local_qr_route(rows: int, n: int, cholqr: bool) -> str:
+    """Which shard-local factorisation a (rows, n) panel takes, by what a
+    trace can observe: ``blocked`` (CholeskyQR2 over row blocks),
+    ``one_product`` (CholeskyQR2 of a panel that is one block) or
+    ``householder_tree``.  ``_tsqr_shardmap`` counts it once a trace under
+    ``schedule_counters()["tsqr_local:<route>"]``."""
+    if not cholqr:
+        return "householder_tree"
+    return "blocked" if rows > _panel_block(rows, n) else "one_product"
+
+
+def _over_blocks(rows, block, step, carry):
+    """``carry`` after ``step(start, size, carry)`` for every block of a
+    panel's rows in turn: whole blocks in a loop, and what is left (a block
+    and the ragged rest, as one step of its own static size) after it."""
+    full = max(rows // block - bool(rows % block), 0)
+    carry = lax.fori_loop(0, full, lambda i, c: step(i * block, block, c),
+                          carry)
+    done = full * block
+    return step(done, rows - done, carry) if done < rows else carry
+
+
+def _gram(q):
+    """``q.T @ q`` of a shard's tall panel as the compensated sum of its
+    blocks' Grams (``ops/base.py::local_row_sums``): no product contracts
+    more than a block's rows.  A ragged last block starts early and the
+    rows an earlier block has seen weigh nothing."""
+    rows, n = q.shape
+    block = _panel_block(rows, n)
+    ragged = rows % block != 0
+
+    def one(qb, w, _start):
+        return px.pdot_tall(qb * w[:, None] if ragged else qb, qb)
+
+    with jax.named_scope("dslib.tsqr.gram"):
+        return _ops.local_row_sums(q, (), 0, rows, block, one,
+                                   jnp.zeros((n, n), q.dtype))
+
+
+def _apply(q, right):
+    """``q @ right`` for a shard's tall ``q`` (rows, n) and a small
+    ``right`` (n, n), block by block as a GEMM written where the block
+    lay: no second panel exists unless the caller still needs ``q``."""
+    rows, n = q.shape
+
+    def one(start, size, out):
+        qb = lax.dynamic_slice_in_dim(out, start, size)
+        return lax.dynamic_update_slice_in_dim(out, px.pdot(qb, right),
+                                               start, 0)
+
+    with jax.named_scope("dslib.tsqr.apply"):
+        return _over_blocks(rows, _panel_block(rows, n), one, q)
+
+
 def _cholqr2(a):
-    """CholeskyQR2: two rounds of Gram → Cholesky → triangular solve.
+    """CholeskyQR2: two rounds of Gram → Cholesky → R⁻¹ applied, over the
+    rows in blocks, the second round's application left to the caller.
 
     (Lit.: 'Large Scale Distributed Linear Algebra With Tensor Processing
     Units', arXiv:2112.09017 — QR via Cholesky of AᵀA is the TPU-native
     tall-skinny factorisation; the second round restores orthogonality to
     O(u) whenever the first Cholesky succeeds, i.e. cond(A) ≲ u^(-1/2).)
 
-    Returns (Q, R, ok): ``ok`` is False when the result is unusable — the
-    Gram Cholesky broke down (NaN/inf), OR round 1's orthogonality error
-    was too large for round 2's O(u) restoration to apply.  The latter is
-    measured from the ALREADY-COMPUTED second factor: by construction
-    R₂ᵀR₂ = Q₁ᵀQ₁ (to Cholesky rounding), so ‖R₂ᵀR₂ − I‖_max IS round 1's
-    orthogonality error at O(n³) cost — no m-sized Gram of Q₂ needed.
-    The CholeskyQR2 guarantee (final orthogonality O(u)) holds whenever
-    that error is ≪ 1; the 0.1 threshold is conservative.  The explicit
-    check matters because in the cond(A) band around u^(-1/2) the
-    Cholesky can stay finite while orthogonality quietly degrades —
-    finiteness alone does not guarantee quality.  The caller falls back
-    to the Householder tree on ok=False, so ill-conditioned inputs lose
-    speed, never accuracy."""
-    def one_round(q):
-        g = q.T @ q
-        ell = jnp.linalg.cholesky(g)                 # G = L Lᵀ, R = Lᵀ
-        q_next = jax.scipy.linalg.solve_triangular(ell, q.T, lower=True).T
-        return q_next, ell.T
+    Returns (Q₁, R₂⁻¹, R, ok): Q = ``_apply(Q₁, R₂⁻¹)`` and R = R₂R₁.
+    Each round's Gram is :func:`_gram` (a float32 product that contracts a
+    million rows of squares reads 2e-5 low on the chip, which is 1e-5 of
+    orthogonality; PERF.md), its R⁻¹ = L⁻ᵀ is formed once, (n, n), and
+    applied as a GEMM a block (:func:`_apply`), so no transposed or solved
+    copy of the panel exists.
 
-    q1, r1 = one_round(a)
-    q2, r2 = one_round(q1)
-    r = r2 @ r1
+    ``ok`` is False when the result is unusable — the Gram Cholesky broke
+    down (NaN/inf), OR round 1's orthogonality error was too large for
+    round 2's O(u) restoration to apply.  The latter is measured from the
+    second factor: by construction R₂ᵀR₂ = Q₁ᵀQ₁ (to Cholesky rounding),
+    so ‖R₂ᵀR₂ − I‖_max IS round 1's orthogonality error at O(n³) cost — no
+    m-sized Gram of Q₂ needed, and known BEFORE R₂⁻¹ is applied, so the
+    caller decides between the application and the fall-back without
+    holding both.  The CholeskyQR2 guarantee (final orthogonality O(u))
+    holds whenever that error is ≪ 1; the 0.1 threshold is conservative.
+    The explicit check matters because in the cond(A) band around u^(-1/2)
+    the Cholesky can stay finite while orthogonality quietly degrades —
+    finiteness alone does not guarantee quality.  The caller falls back to
+    the Householder tree on ok=False, so ill-conditioned inputs lose speed,
+    never accuracy."""
     n = a.shape[1]
-    round1_err = jnp.max(jnp.abs(r2.T @ r2 - jnp.eye(n, dtype=r2.dtype)))
-    ok = jnp.all(jnp.isfinite(q2)) & jnp.all(jnp.isfinite(r)) \
+    eye = jnp.eye(n, dtype=a.dtype)
+
+    def factor(q):
+        g = _gram(q)
+        with jax.named_scope("dslib.tsqr.chol"):
+            ell = jnp.linalg.cholesky(g)             # G = L Lᵀ, R = Lᵀ
+            return ell.T, jax.scipy.linalg.solve_triangular(
+                ell, eye, lower=True).T              # R, R⁻¹ = L⁻ᵀ
+
+    r1, r1_inv = factor(a)
+    q1 = _apply(a, r1_inv)
+    r2, r2_inv = factor(q1)
+    r = r2 @ r1
+    round1_err = jnp.max(jnp.abs(r2.T @ r2 - eye))
+    ok = jnp.all(jnp.isfinite(r2_inv)) & jnp.all(jnp.isfinite(r)) \
         & (round1_err < 0.1)
-    return q2, r, ok
+    return q1, r2_inv, r, ok
 
 
 def _local_qr(a, cholqr, policy=px.FLOAT32):
     """Shard-local tall-skinny QR: CholeskyQR2 when ``cholqr`` (with an
     in-program fallback to the Householder tree on Cholesky breakdown),
-    the batched Householder reduction tree otherwise.  ``cholqr`` is a
+    the Householder reduction tree otherwise.  ``cholqr`` is a
     trace-time static (threaded from `_use_cholqr()` through the jit cache
     key, so flipping the env var retraces instead of being ignored).
     ``policy`` governs only the reduction tree's batched Q-apply GEMMs;
-    the Householder/Cholesky factorisations themselves are pinned f32."""
+    the Householder/Cholesky factorisations themselves are pinned f32.
+    The branch is taken before the second round is applied: the
+    well-conditioned call writes Q over Q₁, and the fall-back's only
+    panel is its own result."""
     if not cholqr:
         return _local_tsqr(a, policy)
-    q_c, r_c, ok = _cholqr2(a)
+    q1, r2_inv, r_c, ok = _cholqr2(a)
     # tuple(): jnp.linalg.qr yields a QRResult NamedTuple — a different
     # pytree type than the true branch's plain tuple
     return lax.cond(ok,
-                    lambda op: (q_c, r_c),
-                    lambda op: tuple(_local_tsqr(op, policy)),
-                    a)
+                    lambda q, op: (_apply(q, r2_inv), r_c),
+                    lambda q, op: tuple(_local_tsqr(op, policy)),
+                    q1, a)
 
 
 def _split_count(rows: int, n: int, target: int = 8) -> int:
@@ -159,9 +239,13 @@ def _local_tsqr(a, policy=px.FLOAT32):
     Householder-tree numerics as the cross-shard tsQR, so stability is
     unchanged; shapes are static so the whole tree is one traced program.
     Degrades to a plain ``jnp.linalg.qr`` when the input is too short to
-    split (the CPU-rig test shapes and the p·n R-stack at small p).
+    split (the CPU-rig test shapes and the p·n R-stack at small p).  A
+    panel of more than one :func:`_panel_block` takes the tree's first
+    level block by block (:func:`_local_tsqr_blocked`).
     """
     rows, n = a.shape
+    if rows > _panel_block(rows, n) >= n:
+        return _local_tsqr_blocked(a, policy)
     s = _split_count(rows, n)
     if s == 1:
         return jnp.linalg.qr(a, mode="reduced")
@@ -169,6 +253,39 @@ def _local_tsqr(a, policy=px.FLOAT32):
     q1, r = _local_tsqr(r0.reshape(s * n, n), policy)
     q = px.pdot(q0, q1.reshape(s, n, n), policy)             # batched GEMM
     return q.reshape(rows, n), r
+
+
+def _local_tsqr_blocked(a, policy):
+    """:func:`_local_tsqr` of a panel taller than one block, its first
+    level a loop: every block's Householder QR written where the block
+    lay, the blocks' R factors stacked and handed to the tree, and the
+    tree's Q applied block by block.  The same tree, with a block's
+    temporaries where the batched level holds two more panels (at 1.5M x
+    256 beside a 6 GiB array that is the difference between fitting the
+    chip and not)."""
+    rows, n = a.shape
+    block = _panel_block(rows, n)
+
+    def factor(start, size, carry):
+        q, stack = carry
+        qb, rb = jnp.linalg.qr(lax.dynamic_slice_in_dim(q, start, size),
+                               mode="reduced")
+        return (lax.dynamic_update_slice_in_dim(q, qb, start, 0),
+                lax.dynamic_update_slice_in_dim(stack, rb,
+                                                start // block * n, 0))
+
+    q0, stack = _over_blocks(
+        rows, block, factor,
+        (a, _ops.varying_like(jnp.zeros((rows // block * n, n), a.dtype), a)))
+    q1, r = _local_tsqr(stack, policy)
+
+    def lift(start, size, q):
+        qb = lax.dynamic_slice_in_dim(q, start, size)
+        top = lax.dynamic_slice_in_dim(q1, start // block * n, n)
+        return lax.dynamic_update_slice_in_dim(q, px.pdot(qb, top, policy),
+                                               start, 0)
+
+    return _over_blocks(rows, block, lift, q0), r
 
 
 @partial(jax.jit, static_argnames=("mesh", "p", "cholqr", "policy"))
@@ -179,6 +296,7 @@ def _tsqr_shardmap(av, mesh, p, *, cholqr, policy=px.FLOAT32):
     jit cache key, otherwise an env flip after the first trace would be
     silently ignored."""
     n = av.shape[1]
+    _count_schedule("tsqr_local", local_qr_route(av.shape[0] // p, n, cholqr))
 
     def local(a_shard):
         q1, r1 = _local_qr(a_shard, cholqr, policy)          # (m/p, n), (n, n)

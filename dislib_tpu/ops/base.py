@@ -185,18 +185,14 @@ COVARIANCE_TYPES = ("full", "tied", "diag", "spherical")
 
 def em_block(rows: int, d: int, k: int) -> int:
     """Rows of one block of the blocked EM pass over ``rows`` rows on a
-    device: all of them where they fit the tile budget; else the largest
-    whole number of quanta under the budget that divides ``rows``, if one
-    of at least half the budget does; else the budget's, and the last
-    block is ragged (it then starts early and its rows that an earlier
-    block has seen weigh nothing)."""
-    most = max(_EM_TILE_BYTES // (4 * k * (d + -d % 8)) // _EM_ROW_QUANTUM,
-               1) * _EM_ROW_QUANTUM
-    if rows <= most:
-        return rows
-    whole = [b for b in range(most, most // 2 - 1, -_EM_ROW_QUANTUM)
-             if rows % b == 0]
-    return whole[0] if whole else most
+    device: :func:`row_block` at what a row costs there."""
+    return row_block(rows, _em_row_bytes(k, d))
+
+
+def _em_row_bytes(k: int, d: int) -> int:
+    """What a row costs in an EM block's largest temporary: its (k, d)
+    float32 tile of whitened differences, d in whole sublane tiles."""
+    return 4 * k * (d + -d % 8)
 
 
 def _em_cut(i, block, local, *arrays):
@@ -207,48 +203,30 @@ def _em_cut(i, block, local, *arrays):
     return start, [lax.dynamic_slice_in_dim(a, start, block) for a in arrays]
 
 
-def _em_blocks(xp, m, d, k, body, zero, per_row=()):
-    """The sums over all row blocks of ``body(xb, w, start,
-    *per_row_blocks)``, a block's partial sums (a pytree shaped like
-    ``zero``), over the padded, row-sharded ``xp``: every device of the
-    mesh's ``rows`` axis loops over its own rows' blocks, and ONE ``psum``
-    of the packed totals crosses the devices (none on a mesh of one).
-    ``xb`` (block, d) are a block's rows with the padding columns
-    cropped, ``w`` (block,) their weights (1; 0 on the padding rows past
-    ``m`` and on the rows of a ragged last block that an earlier block
-    has seen), ``start`` the block's first row in the whole array.
-    ``per_row`` are further (rows, ...) arrays cut the same way.
-
-    A device's running totals are compensated (Kahan): what an addition
-    rounds away is carried and given back, so that thousands of blocks
-    add up as exactly as two, whatever the block's size.  Plain float32
-    totals over the 3125 blocks of 24M rows read 2e-6 of a lower bound
-    and 1e-5 of a covariance against the reference (PERF.md, PR 29)."""
+def blocked_row_sums(xp, m, d, row_bytes, body, zero, per_row=()):
+    """The library's blocked pass over the rows: the sums over all row
+    blocks of ``body(xb, w, start, *per_row_blocks)``, a block's partial
+    sums (a pytree shaped like ``zero``), over the padded, row-sharded
+    ``xp``: every device of the mesh's ``rows`` axis loops over its own
+    rows' blocks (:func:`local_row_sums`), and ONE ``psum`` of the packed
+    totals crosses the devices (none on a mesh of one).  ``xb`` (block, d)
+    are a block's rows with the padding columns cropped, ``w`` (block,)
+    their weights (1; 0 on the padding rows past ``m`` and on the rows of
+    a ragged last block that an earlier block has seen), ``start`` the
+    block's first row in the whole array.  ``per_row`` are further (rows,
+    ...) arrays cut the same way.  ``row_bytes`` is the caller's tile
+    budget: what one row costs in a block's largest temporary, from which
+    :func:`row_block` derives the block."""
     from jax.flatten_util import ravel_pytree
     mesh = _mesh.get_mesh()
     local = xp.shape[0] // mesh.shape[_mesh.ROWS]
-    block = em_block(local, d, k)
+    block = row_block(local, row_bytes)
 
     def device(xs, *others):
-        first = lax.axis_index(_mesh.ROWS) * local
-
-        def one(i, carry):
-            total, lost = carry
-            start, (xb, *cut) = _em_cut(i, block, local, xs, *others)
-            rows = start + lax.iota(jnp.int32, block)
-            w = ((rows >= i * block) & (first + rows < m)).astype(xs.dtype)
-            # leaf by leaf, each in the shape its block's GEMM writes it
-            part = jax.tree.map(jnp.subtract,
-                                body(xb[:, :d], w, first + start, *cut), lost)
-            grown = jax.tree.map(jnp.add, total, part)
-            return grown, jax.tree.map(lambda g, t, p: (g - t) - p,
-                                       grown, total, part)
-
-        start = jax.tree.map(
-            lambda z: lax.pcast(z, _mesh.ROWS, to="varying"), zero)
-        total, lost = lax.fori_loop(0, -(-local // block), one,
-                                    (start, start))
-        flat, unravel = ravel_pytree(jax.tree.map(jnp.subtract, total, lost))
+        total = local_row_sums(
+            xs, others, lax.axis_index(_mesh.ROWS) * local, m, block,
+            lambda xb, *rest: body(xb[:, :d], *rest), zero)
+        flat, unravel = ravel_pytree(total)
         return unravel(lax.psum(flat, _mesh.ROWS))
 
     row_spec = P(_mesh.ROWS, None)
@@ -432,8 +410,8 @@ def em_step(xp, m, log_weights, means, prec, cov_type):
                                   cov_type)
         return sums, jnp.sum(lse * w)
 
-    sums, loglik = _em_blocks(
-        xp, m, d, k, block,
+    sums, loglik = blocked_row_sums(
+        xp, m, d, _em_row_bytes(k, d), block,
         (_em_zero_sums(k, d, cov_type, xp.dtype), jnp.zeros((), xp.dtype)))
     return (*_em_sums_about(sums, wh.about, cov_type), loglik)
 
@@ -460,9 +438,10 @@ def em_start(xp, m, about, cov_type, labels=None, key=None):
         return _em_block_sums(xb - centre, w, resp * w[:, None], about_c,
                               cov_type)
 
-    sums = _em_blocks(xp, m, d, k, block,
-                      _em_zero_sums(k, d, cov_type, xp.dtype),
-                      per_row=() if labels is None else (labels,))
+    sums = blocked_row_sums(
+        xp, m, d, _em_row_bytes(k, d), block,
+        _em_zero_sums(k, d, cov_type, xp.dtype),
+        per_row=() if labels is None else (labels,))
     return _em_sums_about(sums, about_c, cov_type)
 
 
@@ -476,7 +455,8 @@ def em_loglik(xp, m, log_weights, means, prec, cov_type):
         logp = _em_log_prob(xb, wh)
         return jnp.sum(jax.scipy.special.logsumexp(logp, axis=1) * w)
 
-    return _em_blocks(xp, m, d, k, block, jnp.zeros((), xp.dtype))
+    return blocked_row_sums(xp, m, d, _em_row_bytes(k, d), block,
+                            jnp.zeros((), xp.dtype))
 
 
 def em_labels(xp, m, log_weights, means, prec, cov_type):
@@ -508,3 +488,80 @@ def em_labels(xp, m, log_weights, means, prec, cov_type):
 
     return jax.shard_map(device, mesh=mesh, in_specs=(P(_mesh.ROWS, None),),
                          out_specs=P(_mesh.ROWS, None))(xp)
+
+
+# -- the blocked pass over the rows, for every tenant -------------------------
+# (Below everything else on purpose: the KMeans fit's program keeps its
+# compile-cache key only while the lines above stay where they are.)
+# The EM step was its first tenant and the tile budget keeps its name; the
+# tall orthonormalisation and the randomized SVD's products over the rows
+# (decomposition/tsqr.py, randomsvd.py) are the second.
+_CONTRACT_ROWS = 8192        # most rows one product contracts, at the budget
+
+
+def row_block(rows: int, row_bytes: int) -> int:
+    """Rows of one block of a blocked pass over ``rows`` rows on a device,
+    ``row_bytes`` being what a row costs in a block's largest temporary:
+    all of them where they fit the tile budget ``_EM_TILE_BYTES``; else the
+    largest whole number of quanta under the budget that divides ``rows``,
+    if one of at least half the budget does; else the budget's, and the
+    last block is ragged (it then starts early and its rows that an
+    earlier block has seen weigh nothing)."""
+    most = max(_EM_TILE_BYTES // row_bytes // _EM_ROW_QUANTUM,
+               1) * _EM_ROW_QUANTUM
+    if rows <= most:
+        return rows
+    whole = [b for b in range(most, most // 2 - 1, -_EM_ROW_QUANTUM)
+             if rows % b == 0]
+    return whole[0] if whole else most
+
+
+def contract_row_bytes(row_bytes: int) -> int:
+    """``row_bytes`` for a pass whose body is a product that contracts a
+    block's rows: at least what keeps a block to ``_CONTRACT_ROWS`` rows
+    under the budget.  A float32 product that contracts more than about
+    10^4 rows of same-signed terms reads low on the chip (1.3e-5 at
+    100 000 rows, 5e-9 at 7 680; PERF.md, PR 29), and a Gram's diagonal
+    is a sum of squares whatever the data."""
+    return max(row_bytes, _EM_TILE_BYTES // _CONTRACT_ROWS)
+
+
+def varying_like(z, xs):
+    """``z`` typed as varying over the mesh axes ``xs`` varies over: what
+    a loop's carry that starts from a constant and is updated from a
+    shard's rows has to be inside a ``shard_map`` that checks it; ``z``
+    itself on no mesh."""
+    axes = tuple(getattr(jax.typeof(xs), "vma", ()))
+    return lax.pcast(z, axes, to="varying") if axes else z
+
+
+def local_row_sums(xs, others, first, m, block, body, zero):
+    """One device's share of :func:`blocked_row_sums`, for a caller that
+    is inside a ``shard_map`` already (or on no mesh at all): the
+    compensated sum over the blocks of ``block`` rows of ``body(xb, w,
+    start, *other_blocks)`` over this device's rows ``xs`` and the
+    ``others`` cut the same way, ``first`` being the device's first row
+    in the whole array, of which the first ``m`` rows count.
+
+    The running totals are compensated (Kahan): what an addition rounds
+    away is carried and given back, so that thousands of blocks add up as
+    exactly as two, whatever the block's size.  Plain float32 totals over
+    the 3125 blocks of 24M rows read 2e-6 of a lower bound and 1e-5 of a
+    covariance against the reference (PERF.md, PR 29)."""
+    local = xs.shape[0]
+
+    def one(i, carry):
+        total, lost = carry
+        start, (xb, *cut) = _em_cut(i, block, local, xs, *others)
+        rows = start + lax.iota(jnp.int32, block)
+        w = ((rows >= i * block) & (first + rows < m)).astype(xs.dtype)
+        # leaf by leaf, each in the shape its block's GEMM writes it
+        part = jax.tree.map(jnp.subtract,
+                            body(xb, w, first + start, *cut), lost)
+        grown = jax.tree.map(jnp.add, total, part)
+        return grown, jax.tree.map(lambda g, t, p: (g - t) - p,
+                                   grown, total, part)
+
+    start = jax.tree.map(lambda z: varying_like(z, xs), zero)
+    total, lost = lax.fori_loop(0, -(-local // block), one, (start, start))
+    return jax.tree.map(jnp.subtract, total, lost)

@@ -14,8 +14,8 @@ import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SMOKE = os.path.join(REPO, "chip_smoke.py")
-PHASES = ["device", "train", "mixture", "serve", "bundle", "kernels",
-          "summa"]
+PHASES = ["device", "train", "mixture", "rsvd", "serve", "bundle",
+          "kernels", "summa"]
 
 
 def _run(*args, **env_extra):
@@ -62,6 +62,11 @@ def test_rehearsal_runs_every_phase_stamped(tmp_path):
     assert by["mixture"]["gm_m_step"] == ["six_pass"]
     assert by["mixture"]["collectives"] == {"all-reduce": 1}
     assert by["mixture"]["predict_agreement"] >= 0.9999
+    # the randomized SVD's panels take the Householder tree here; a TPU
+    # reads ["blocked", "one_product"]
+    assert by["rsvd"]["tsqr_local"] == ["householder_tree"]
+    assert by["rsvd"]["values_gap_vs_numpy"] <= 1e-4
+    assert by["rsvd"]["u_orthogonality"] <= 5e-6
     assert by["serve"]["traces_after_start"] == 0
     assert by["serve"]["dispatches_per_batch_max"] == 1
     assert by["bundle"]["traces_after_load"] == 0
