@@ -388,8 +388,9 @@ def phase_rsvd(run):
     """``ds.random_svd`` at the sketch width of the benchmark's cell (one
     256-column block) and as many rows as the host can factor in float64:
     S against NumPy's singular values, U's orthogonality summed in
-    float64, one dispatch a call, and the shard-local factorisation the
-    traces took (``tsqr_local``).  The rank is half the sketch, so that
+    float64, one dispatch a call, the shard-local factorisation the
+    traces took (``tsqr_local``) and how Q was assembled
+    (``tsqr_assemble``).  The rank is half the sketch, so that
     two power iterations bring the kept values to float32's accuracy
     (the cell keeps 246 of 256, and is held to a reference of the same
     algorithm instead)."""
@@ -416,6 +417,8 @@ def phase_rsvd(run):
     _, warm_s = _wall(call)
     assert prof.counters()["dispatch_by"] == {"random_svd": 2}
     routes = _routes(prof, "tsqr_local")
+    assembled = _routes(prof, "tsqr_assemble")
+    assert assembled == ["folded"], assembled
 
     x64 = x_host.astype(np.float64)
     want = np.sqrt(np.linalg.eigvalsh(x64.T @ x64)[::-1][:nsv])
@@ -430,7 +433,8 @@ def phase_rsvd(run):
     return {"first_call_s": first_s, "warm_call_s": warm_s,
             "shape": [m, n], "nsv": nsv, "sketch": nsv + over,
             "values_gap_vs_numpy": values_gap, "u_orthogonality": orth,
-            "residual": resid, "tsqr_local": routes}
+            "residual": resid, "tsqr_local": routes,
+            "tsqr_assemble": assembled}
 
 
 def phase_serve(run):

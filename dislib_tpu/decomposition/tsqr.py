@@ -2,12 +2,34 @@
 plus a pairwise tree reduction of R factors; SURVEY.md §3.2).
 
 TPU-native design (BASELINE config 3: "tsQR on 65536x256 — _little_qr +
-all_gather(R) over ICI"): one `shard_map` over the mesh 'rows' axis.
+all_gather(R) over ICI"): one `shard_map` over the mesh 'rows' axis, in
+which a shard passes over its tall (m/p, n) panel four times and no more.
 
-    per shard:  A_i = Q1_i R_i           (local Householder QR, MXU)
-    collective: R_stack = all_gather(R_i)  — ONE all_gather over ICI; with
+    per shard:  G₁ = AᵢᵀAᵢ = R₁ᵀR₁ ;  Q₁ = Aᵢ R₁⁻¹       (passes 1 and 2)
+                G₂ = Q₁ᵀQ₁ = R₂ᵀR₂ ;  Rᵢ = R₂R₁          (pass 3)
+    collective: R_stack = all_gather(Rᵢ)  — ONE all_gather over ICI; with
                 n cols small this is the whole communication volume
-    per shard:  R_stack = Q2 R ;  Q_i = Q1_i @ Q2[i]   (local GEMM)
+    per shard:  R_stack = Q₂ R  (the same factorisation, of p·n rows)
+                Qᵢ = Q₁ (R₂⁻¹ Q₂ᵢ)                        (pass 4)
+
+That is a blocked CholeskyQR2 (:func:`_cholqr2`): each Gram the compensated
+sum of the Grams of blocks of at most 8 192 rows (a float32 product that
+contracts more rows reads low on the chip), each R⁻¹ = L⁻ᵀ formed once,
+(n, n), and applied as ONE product that reads a panel and writes the
+other (:func:`_apply`), in float32 'highest' throughout.  The second
+round's application is left until Q₂ is known, so that R₂⁻¹ and this
+shard's (n, n) block of Q₂ are multiplied first and the panel is read
+once for both: there is no separate Q₁·Q₂ product.  A call holds two
+panels (its operand and one more); nothing panel-sized is copied or cut.
+
+Round 1 is judged before round 2 is applied (``ok``: ‖R₂ᵀR₂ − I‖ < 0.1 and
+everything finite).  Where it fails, the same program factors the
+ORIGINAL panel by a Householder reduction tree instead
+(:func:`_local_tsqr`: blocks' QRs in place, the stack of their R factors
+factored by the same tree, the tree's Q applied as one batched product),
+and the last pass multiplies that Q by Q₂ᵢ: ill-conditioned panels lose
+speed, never accuracy.  ``DSLIB_TSQR_CHOLQR`` chooses the tree outright
+(the default off a TPU); the assembly is the same one application.
 
 The reference's arity-2 reduction tree is log2(p) rounds of pairwise R
 merges shipped between workers; the all_gather collapses that tree into a
@@ -40,10 +62,11 @@ def tsqr(a: Array, mode: str = "reduced", indexes=None, precision=None):
     indices after factorisation.
 
     ``precision``: mixed-precision policy (None → the
-    ``DSLIB_MATMUL_PRECISION`` default).  The policy governs the Q
-    assembly/application GEMMs (the FLOP-dominant tall products); the
-    local panel factorisations and the R-stack merge stay float32 —
-    bounds in ``ops/precision.ERROR_BOUNDS``.
+    ``DSLIB_MATMUL_PRECISION`` default).  The policy governs the
+    Householder tree's Q-application GEMMs, where that tree runs; the
+    panel factorisations, the R-stack merge and the one product that
+    assembles Q (CholeskyQR2's second application with Q₂ in it) stay
+    float32 — bounds in ``ops/precision.ERROR_BOUNDS``.
     """
     if mode not in ("reduced", "r"):
         raise ValueError(f"unsupported mode {mode!r}")
@@ -103,11 +126,18 @@ def local_qr_route(rows: int, n: int, cholqr: bool) -> str:
     return "blocked" if rows > _panel_block(rows, n) else "one_product"
 
 
+def _whole_blocks(rows, block):
+    """How many blocks of a panel's rows are taken whole, one like the
+    other; what is left after them, if anything, is ONE more step: a block
+    and the ragged rest together."""
+    return max(rows // block - bool(rows % block), 0)
+
+
 def _over_blocks(rows, block, step, carry):
     """``carry`` after ``step(start, size, carry)`` for every block of a
     panel's rows in turn: whole blocks in a loop, and what is left (a block
     and the ragged rest, as one step of its own static size) after it."""
-    full = max(rows // block - bool(rows % block), 0)
+    full = _whole_blocks(rows, block)
     carry = lax.fori_loop(0, full, lambda i, c: step(i * block, block, c),
                           carry)
     done = full * block
@@ -133,22 +163,20 @@ def _gram(q):
 
 def _apply(q, right):
     """``q @ right`` for a shard's tall ``q`` (rows, n) and a small
-    ``right`` (n, n), block by block as a GEMM written where the block
-    lay: no second panel exists unless the caller still needs ``q``."""
-    rows, n = q.shape
-
-    def one(start, size, out):
-        qb = lax.dynamic_slice_in_dim(out, start, size)
-        return lax.dynamic_update_slice_in_dim(out, px.pdot(qb, right),
-                                               start, 0)
-
+    ``right`` (n, n): ONE product that reads the panel once and writes
+    the result once, into the other of the two panels a call holds.  It
+    contracts n, not the rows, so it stays whole (the bias of a long
+    float32 contraction, PERF.md section 7, is the Grams' matter); XLA
+    splits ``q`` into its bfloat16 parts inside the GEMM's own fusion,
+    and nothing is cut out or copied around it."""
     with jax.named_scope("dslib.tsqr.apply"):
-        return _over_blocks(rows, _panel_block(rows, n), one, q)
+        return px.pdot(q, right)
 
 
 def _cholqr2(a):
-    """CholeskyQR2: two rounds of Gram → Cholesky → R⁻¹ applied, over the
-    rows in blocks, the second round's application left to the caller.
+    """CholeskyQR2: two rounds of Gram → Cholesky → R⁻¹ applied, the
+    second round's application left to the caller: three of the four
+    passes over the panel.
 
     (Lit.: 'Large Scale Distributed Linear Algebra With Tensor Processing
     Units', arXiv:2112.09017 — QR via Cholesky of AᵀA is the TPU-native
@@ -156,11 +184,13 @@ def _cholqr2(a):
     O(u) whenever the first Cholesky succeeds, i.e. cond(A) ≲ u^(-1/2).)
 
     Returns (Q₁, R₂⁻¹, R, ok): Q = ``_apply(Q₁, R₂⁻¹)`` and R = R₂R₁.
-    Each round's Gram is :func:`_gram` (a float32 product that contracts a
-    million rows of squares reads 2e-5 low on the chip, which is 1e-5 of
-    orthogonality; PERF.md), its R⁻¹ = L⁻ᵀ is formed once, (n, n), and
-    applied as a GEMM a block (:func:`_apply`), so no transposed or solved
-    copy of the panel exists.
+    Each round's Gram is :func:`_gram`, over the rows in blocks (a float32
+    product that contracts a million rows of squares reads 2e-5 low on
+    the chip, which is 1e-5 of orthogonality; PERF.md), its R⁻¹ = L⁻ᵀ is
+    formed once, (n, n), and round 1's is applied as one product
+    (:func:`_apply`) that reads ``a`` and writes Q₁ beside it: ``a`` stays
+    as it is, for the fall-back, and no transposed, solved or copied
+    panel exists.
 
     ``ok`` is False when the result is unusable — the Gram Cholesky broke
     down (NaN/inf), OR round 1's orthogonality error was too large for
@@ -168,9 +198,10 @@ def _cholqr2(a):
     second factor: by construction R₂ᵀR₂ = Q₁ᵀQ₁ (to Cholesky rounding),
     so ‖R₂ᵀR₂ − I‖_max IS round 1's orthogonality error at O(n³) cost — no
     m-sized Gram of Q₂ needed, and known BEFORE R₂⁻¹ is applied, so the
-    caller decides between the application and the fall-back without
-    holding both.  The CholeskyQR2 guarantee (final orthogonality O(u))
-    holds whenever that error is ≪ 1; the 0.1 threshold is conservative.
+    caller decides between Q₁ and the fall-back without holding both,
+    and applies R₂⁻¹ together with whatever else multiplies Q.  The
+    CholeskyQR2 guarantee (final orthogonality O(u)) holds whenever that
+    error is ≪ 1; the 0.1 threshold is conservative.
     The explicit check matters because in the cond(A) band around u^(-1/2)
     the Cholesky can stay finite while orthogonality quietly degrades —
     finiteness alone does not guarantee quality.  The caller falls back to
@@ -197,25 +228,36 @@ def _cholqr2(a):
 
 
 def _local_qr(a, cholqr, policy=px.FLOAT32):
-    """Shard-local tall-skinny QR: CholeskyQR2 when ``cholqr`` (with an
-    in-program fallback to the Householder tree on Cholesky breakdown),
-    the Householder reduction tree otherwise.  ``cholqr`` is a
-    trace-time static (threaded from `_use_cholqr()` through the jit cache
-    key, so flipping the env var retraces instead of being ignored).
-    ``policy`` governs only the reduction tree's batched Q-apply GEMMs;
-    the Householder/Cholesky factorisations themselves are pinned f32.
-    The branch is taken before the second round is applied: the
-    well-conditioned call writes Q over Q₁, and the fall-back's only
-    panel is its own result."""
+    """Shard-local tall-skinny QR, its last application left to the
+    caller: ``(panel, factor, R)`` with Q = ``panel @ factor``, ``factor``
+    (n, n).  CholeskyQR2 when ``cholqr``: (Q₁, R₂⁻¹, R₂R₁) where round 1
+    was good enough (:func:`_cholqr2`'s ``ok``), and in the same program
+    (the Householder tree's Q of the ORIGINAL panel, I, its R) where it
+    was not; the tree and I otherwise.  The ``cond`` chooses and applies
+    nothing: its well-conditioned branch hands Q₁ back as it got it, and
+    its fall-back ends in a product that writes a panel it does not read
+    (:func:`_local_tsqr_blocked`), so XLA lets both results be the buffer
+    Q₁ lies in and copies neither (read in the text compiled for a v5e,
+    PERF.md section 5; a fall-back that ended in a loop over its own
+    panel cost the well-conditioned branch a copy of Q₁).  The caller's
+    one application (:func:`_tsqr_shardmap`) carries whatever else
+    multiplies Q from the right.  ``cholqr`` is a trace-time static
+    (threaded from `_use_cholqr()` through the jit cache key, so flipping
+    the env var retraces instead of being ignored).  ``policy`` governs
+    only the reduction tree's batched Q-apply GEMMs; the
+    Householder/Cholesky factorisations and the applications of R⁻¹ are
+    pinned f32."""
+    eye = _ops.varying_like(jnp.eye(a.shape[1], dtype=a.dtype), a)
+
+    def tree(op):
+        q, r = _local_tsqr(op, policy)
+        return q, eye, r
+
     if not cholqr:
-        return _local_tsqr(a, policy)
+        return tree(a)
     q1, r2_inv, r_c, ok = _cholqr2(a)
-    # tuple(): jnp.linalg.qr yields a QRResult NamedTuple — a different
-    # pytree type than the true branch's plain tuple
-    return lax.cond(ok,
-                    lambda q, op: (_apply(q, r2_inv), r_c),
-                    lambda q, op: tuple(_local_tsqr(op, policy)),
-                    q1, a)
+    return lax.cond(ok, lambda q, op: (q, r2_inv, r_c),
+                    lambda q, op: tree(op), q1, a)
 
 
 def _split_count(rows: int, n: int, target: int = 8) -> int:
@@ -259,10 +301,12 @@ def _local_tsqr_blocked(a, policy):
     """:func:`_local_tsqr` of a panel taller than one block, its first
     level a loop: every block's Householder QR written where the block
     lay, the blocks' R factors stacked and handed to the tree, and the
-    tree's Q applied block by block.  The same tree, with a block's
-    temporaries where the batched level holds two more panels (at 1.5M x
-    256 beside a 6 GiB array that is the difference between fitting the
-    chip and not)."""
+    tree's Q applied to all blocks in ONE batched product that reads that
+    panel and writes another (a loop that wrote each block where it lay
+    cut the block out first, a copy of it).  The same tree, with a
+    block's temporaries where the batched first level holds two more
+    panels (at 1.5M x 256 beside a 6 GiB array that is the difference
+    between fitting the chip and not)."""
     rows, n = a.shape
     block = _panel_block(rows, n)
 
@@ -279,39 +323,53 @@ def _local_tsqr_blocked(a, policy):
         (a, _ops.varying_like(jnp.zeros((rows // block * n, n), a.dtype), a)))
     q1, r = _local_tsqr(stack, policy)
 
-    def lift(start, size, q):
-        qb = lax.dynamic_slice_in_dim(q, start, size)
-        top = lax.dynamic_slice_in_dim(q1, start // block * n, n)
-        return lax.dynamic_update_slice_in_dim(q, px.pdot(qb, top, policy),
-                                               start, 0)
-
-    return _over_blocks(rows, block, lift, q0), r
+    whole = _whole_blocks(rows, block)          # as the loop above cut them
+    tops = q1.reshape(rows // block, n, n)
+    q = px.pdot(q0[:whole * block].reshape(whole, block, n), tops[:whole],
+                policy).reshape(whole * block, n)
+    if whole * block < rows:
+        q = jnp.concatenate([q, px.pdot(q0[whole * block:], tops[whole],
+                                        policy)])
+    return q, r
 
 
 @partial(jax.jit, static_argnames=("mesh", "p", "cholqr", "policy"))
 @precise
 def _tsqr_shardmap(av, mesh, p, *, cholqr, policy=px.FLOAT32):
-    """``cholqr`` is REQUIRED (no default): every caller must resolve
+    """(Q, R) of a row-sharded tall ``av``: a shard's local factorisation
+    (:func:`_local_qr`), ONE ``all_gather`` of the (n, n) factors, the same
+    factorisation of their stack, and ONE application to the tall panel,
+    which carries this shard's block of Q₂ and whatever factor the local
+    route left unapplied: Q_i = panel · (factor · Q₂ᵢ).  Counted once a
+    trace: ``schedule_counters()["tsqr_local:<route>"]`` and
+    ``["tsqr_assemble:folded"]``.
+
+    ``cholqr`` is REQUIRED (no default): every caller must resolve
     `_use_cholqr()` at its own trace boundary and thread it through its
     jit cache key, otherwise an env flip after the first trace would be
     silently ignored."""
     n = av.shape[1]
     _count_schedule("tsqr_local", local_qr_route(av.shape[0] // p, n, cholqr))
+    _count_schedule("tsqr_assemble", "folded")
 
     def local(a_shard):
-        q1, r1 = _local_qr(a_shard, cholqr, policy)          # (m/p, n), (n, n)
+        panel, factor, r1 = _local_qr(a_shard, cholqr, policy)   # (m/p, n)
         r_stack = lax.all_gather(r1, _mesh.ROWS)             # (p, n, n) — ICI
         r_stack = r_stack.reshape(p * n, n)
-        q2, r = _local_qr(r_stack, cholqr, policy)           # redundant per shard
+        panel2, factor2, r = _local_qr(r_stack, cholqr, policy)  # per shard
         idx = lax.axis_index(_mesh.ROWS)
-        q2_i = lax.dynamic_slice(q2, (idx * n, 0), (n, n))
+        # this shard's (n, n) block of Q₂, and with it everything that
+        # multiplies the tall panel from the right, formed BEFORE the
+        # panel is touched again: Q_i = panel · (factor · Q₂ᵢ)
+        q2_i = px.pdot(lax.dynamic_slice(panel2, (idx * n, 0), (n, n)),
+                       factor2)
         # R is computed identically on every shard, but the static
         # varying-axes analysis can't see that through the local QR; a
         # psum/p makes the replication PROVABLE so check_vma stays ON
         # (SURVEY §6 race-detection row: shard_map replication checking is
         # the collective-correctness sanitizer).  Cost: one (n, n) psum.
         r = lax.psum(r, _mesh.ROWS) / p
-        return px.pdot(q1, q2_i, policy), r
+        return _apply(panel, px.pdot(factor, q2_i)), r
 
     q, r = jax.shard_map(
         local, mesh=mesh,

@@ -65,6 +65,8 @@ def test_rehearsal_runs_every_phase_stamped(tmp_path):
     # the randomized SVD's panels take the Householder tree here; a TPU
     # reads ["blocked", "one_product"]
     assert by["rsvd"]["tsqr_local"] == ["householder_tree"]
+    # on every route Q is one application that carries the tree's Q2
+    assert by["rsvd"]["tsqr_assemble"] == ["folded"]
     assert by["rsvd"]["values_gap_vs_numpy"] <= 1e-4
     assert by["rsvd"]["u_orthogonality"] <= 5e-6
     assert by["serve"]["traces_after_start"] == 0

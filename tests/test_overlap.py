@@ -13,6 +13,7 @@ described v5e:2x2, a copy's start and its done straddle a GEMM under db
 and never under seq).
 """
 
+import contextlib
 import re
 
 import jax
@@ -488,6 +489,20 @@ def describe_v5e():
     return describe
 
 
+@contextlib.contextmanager
+def _compile_cache_off():
+    """A compile for a described chip is written to the persistent cache
+    and cannot be read back without one: off around such compiles."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_on)
+
+
 class TestCompiledOverlapAudit:
     """The tentpole's scheduling claim.  ``round_pipeline`` traces one
     flat block whose order is data dependence through its barriers, so
@@ -540,23 +555,17 @@ class TestCompiledOverlapAudit:
         the fold's add fused into every GEMM but the first, and under db
         the next round's two copies are in flight across every GEMM but
         the last; under seq none ever is."""
-        from jax.experimental.compilation_cache import compilation_cache
         from jax.sharding import Mesh, NamedSharding, PartitionSpec
         from dislib_tpu.ops.summa import summa_matmul, summa_steps
         topo = describe_v5e(topology)
-        cache_was_on = jax.config.jax_enable_compilation_cache
-        jax.config.update("jax_enable_compilation_cache", False)
-        compilation_cache.reset_cache()
         mesh = Mesh(np.array(topo.devices).reshape(grid),
                     (_mesh.ROWS, _mesh.COLS))
         arg = jax.ShapeDtypeStruct(
             (2048, 2048), jnp.float32, sharding=NamedSharding(
                 mesh, PartitionSpec(_mesh.ROWS, _mesh.COLS)))
-        try:
+        with _compile_cache_off():
             text = summa_matmul.lower(arg, arg, mesh, px.FLOAT32,
                                       overlap=overlap).compile().as_text()
-        finally:
-            jax.config.update("jax_enable_compilation_cache", cache_was_on)
         assert "all-reduce" not in text and " while(" not in text
         gemms, in_flight, across = [], set(), []
         for line in text[text.index("\nENTRY "):].splitlines():
@@ -577,6 +586,44 @@ class TestCompiledOverlapAudit:
         assert sum("convolution_add" in n for n in gemms) == rounds - 1
         assert across == ([2] * (rounds - 1) + [0] if overlap == "db"
                           else [0] * rounds), across
+
+
+class TestCompiledTallOrthonormalisation:
+    """What the TPU's compiler makes of ``decomposition/tsqr.py`` (it
+    lives here because one file a run may describe a topology: the
+    fixture above).  The CPU's compiler gives the conditional between Q1
+    and the fall-back a panel of its own; the TPU's does not, and that is
+    what the cell runs."""
+
+    def test_tpu_program_copies_no_panel_and_holds_two(self, describe_v5e):
+        """A shard's blocked CholeskyQR2 with its Householder fall-back,
+        compiled for a described v5e (8 blocks of 8 192 rows, 128
+        columns): no ``copy`` of panel size anywhere (the conditional's
+        well-conditioned branch hands Q1 back in the buffer it came in,
+        its fall-back ends in a product that writes that buffer), and one
+        panel of temporaries beside the operand's and the result's."""
+        import importlib
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec
+        tsqr = importlib.import_module("dislib_tpu.decomposition.tsqr")
+        topo = describe_v5e("v5e:2x2")
+        mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1),
+                    (_mesh.ROWS, _mesh.COLS))
+        m, n = 65_536, 128
+        assert tsqr.local_qr_route(m, n, True) == "blocked"
+        arg = jax.ShapeDtypeStruct(
+            (m, n), jnp.float32, sharding=NamedSharding(
+                mesh, PartitionSpec(_mesh.ROWS, None)))
+        with _compile_cache_off():
+            # the panel a temporary of the program's own, as random_svd's is
+            compiled = jax.jit(lambda x: tsqr._tsqr_shardmap(
+                x + 1.0, mesh, 1, cholqr=True)).lower(arg).compile()
+        text = compiled.as_text()
+        assert " conditional(" in text          # the fall-back is there
+        copies = re.findall(rf"(\S+) = f32\[{m},{n}\]\S* copy\(", text)
+        assert not copies, copies
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < 1.1 * m * n * 4, \
+            mem.temp_size_in_bytes
 
 
 # ---------------------------------------------------------------------------

@@ -8,7 +8,10 @@ what the issue that brought it promised: Grams that are compensated sums
 of blocks' Grams, an ill-conditioned panel that still takes the
 Householder tree, no temporary of A's size, one dispatch a call, the
 counter, the span and the scopes, and the draw of the test matrix as a
-contract.
+contract; and what the issue that folded the assembly promised: a tall
+orthonormalisation passes over its panel four times (Gram, application,
+Gram, application), its Q the old composition (Q1 R2^-1) Q2_i, counted
+under ``tsqr_assemble:folded``.
 
 Tolerances.  Program and reference are both float32 at 'highest' and
 orthonormalise by different factorisations (CholeskyQR2 or the program's
@@ -21,6 +24,7 @@ the limits below.
 """
 
 import importlib
+import itertools
 import os
 import sys
 
@@ -184,7 +188,7 @@ def test_the_blocked_gram_is_the_float64_gram(small_blocks, rows):
 
 
 @pytest.mark.parametrize("rows", [512, 4096, 5001])
-def test_a_product_applied_block_by_block_is_the_product(small_blocks, rows):
+def test_a_product_applied_to_a_panel_is_the_product(small_blocks, rows):
     rng = np.random.RandomState(rows)
     q = rng.randn(rows, 32).astype(np.float32)
     right = rng.randn(32, 32).astype(np.float32)
@@ -232,12 +236,129 @@ def test_the_tree_over_blocks_is_a_householder_qr(small_blocks, monkeypatch):
     assert np.allclose(rh, np.triu(rh))
 
 
+def _shards(x, n, p):
+    """The rows ``ds.tsqr`` hands each of p devices: the array's padded
+    backing, cut evenly."""
+    av = np.asarray(ds.array(x)._data[:, :n])
+    return np.split(av, p)
+
+
+def _old_composition(shards):
+    """Q as the program assembled it before the fold, (Q1 R2^-1) Q2_i, in
+    float64 from the program's own factors: every shard's local
+    factorisation, the same factorisation of the stacked R factors, and
+    the two products a shard, one after the other."""
+    n = shards[0].shape[1]
+    local = jax.jit(_ops.precise(
+        lambda a: _tsqr._local_qr(a, True)))
+    firsts = [[np.asarray(t, np.float64) for t in local(jnp.asarray(a))]
+              for a in shards]
+    stack = np.concatenate([r for _, _, r in firsts]).astype(np.float32)
+    panel2, factor2, r = (np.asarray(t, np.float64)
+                          for t in local(jnp.asarray(stack)))
+    q2 = panel2 @ factor2
+    return np.concatenate([(panel @ factor) @ q2[i * n:(i + 1) * n]
+                           for i, (panel, factor, _) in enumerate(firsts)]), r
+
+
+@pytest.mark.parametrize("devices", [1, 8])
+@pytest.mark.parametrize("cond", [1e1, 1e3, 1e6, 1e8])
+def test_the_folded_assembly_is_the_old_composition(small_blocks,
+                                                    monkeypatch, devices,
+                                                    cond):
+    """One application of R2^-1 Q2_i against two, one after the other: the
+    same Q to rounding, on both routes (the fall-back's factor is I), on
+    one device and across eight; Q orthogonal, Q R the panel, R upper
+    triangular with a positive diagonal where Cholesky made it (a
+    Householder R's signs are the reflectors')."""
+    monkeypatch.setenv("DSLIB_TSQR_CHOLQR", "1")
+    _mesh_of(devices)
+    n = 16
+    x = _conditioned(ROWS, n, cond)
+    q, r = ds.tsqr(ds.array(x))
+    qh, rh = (np.asarray(a.collect(), np.float64) for a in (q, r))
+    assert np.abs(qh.T @ qh - np.eye(n)).max() < 2e-6
+    assert np.abs(qh @ rh - x).max() < 1e-6
+    assert np.array_equal(rh, np.triu(rh))
+    if cond <= 1e3:
+        assert (np.diag(rh) > 0).all()
+    want_q, want_r = _old_composition(_shards(x, n, devices))
+    assert np.abs(qh - want_q[:ROWS]).max() < 5e-6
+    assert np.abs(rh - want_r).max() < 5e-6 * np.abs(want_r).max()
+
+
+def _sub_jaxprs(eqn):
+    """The jaxprs an equation runs, in order; of a ``cond`` the branch of
+    a true predicate alone (the well-conditioned one), of a ``while`` its
+    body."""
+    if eqn.primitive.name == "cond":
+        return [eqn.params["branches"][1].jaxpr]
+    if eqn.primitive.name == "while":
+        return [eqn.params["body_jaxpr"].jaxpr]
+    found = []
+    for value in eqn.params.values():
+        for v in value if isinstance(value, (tuple, list)) else (value,):
+            v = getattr(v, "jaxpr", v)
+            if hasattr(v, "eqns"):
+                found.append(v)
+    return found
+
+
+def _panel_passes(jaxpr, rows, block, in_loop=False):
+    """What touches a shard's (rows, n) panel, in program order along the
+    well-conditioned path: ``gram`` for every product that contracts the
+    panel's rows (whole, or a block of them inside a loop), ``apply`` for
+    every one that keeps them."""
+    tall = {rows, block} if in_loop else {rows}
+    passes = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            (contract, _), _ = eqn.params["dimension_numbers"]
+            lhs = eqn.invars[0].aval.shape
+            if lhs and lhs[0] in tall:
+                passes.append("gram" if 0 in contract else "apply")
+        for sub in _sub_jaxprs(eqn):
+            passes += _panel_passes(
+                sub, rows, block,
+                in_loop or eqn.primitive.name in ("while", "scan"))
+    return passes
+
+
+@pytest.mark.parametrize("devices", [1, 8])
+def test_a_tall_panel_is_passed_over_four_times(small_blocks, devices):
+    """The shard body of ``_tsqr_shardmap`` on the Cholesky route: Gram,
+    application, Gram, and exactly ONE product that touches the panel
+    after the second Gram, the application that carries R2^-1 and the
+    tree's Q2 together (before the fold: R2^-1's, then Q1 Q2)."""
+    _mesh_of(devices)
+    from dislib_tpu.parallel import mesh as _mesh
+    n, rows = 16, 20480 // devices
+    block = _tsqr._panel_block(rows, n)
+    assert n * devices < block < rows      # several blocks; a stack is one
+    jaxpr = jax.make_jaxpr(lambda a: _tsqr._tsqr_shardmap(
+        a, _mesh.get_mesh(), devices, cholqr=True))(
+            jnp.ones((20480, n), jnp.float32))
+    # a Gram that a TPU packs into three products (``pdot_tall``) is one
+    # pass; two applications one after the other are two
+    passes = [kind for kind, run in itertools.groupby(
+        _panel_passes(jaxpr.jaxpr, rows, block))
+        for _ in (run if kind == "apply" else [kind])]
+    assert passes == ["gram", "apply", "gram", "apply"]
+
+
 # -- (d) no temporary of A's size -----------------------------------------------
 
 def test_the_compiled_call_holds_less_than_a_beside_a():
     """262 144 x 1024 to rank 246, compiled for the CPU backend and not
-    run: the call's temporaries (two (m, 256) panels and a block's tiles)
-    stay under A's own 1 GiB, and its results are what they are."""
+    run: the call's temporaries stay at three (m, 256) panels and a
+    block's tiles (two panels of the orthonormalisation's own, and one
+    that the CPU's compiler gives the conditional between Q1 and the
+    fall-back; the TPU's gives it none: two panels, audited on its own
+    compiled text in ``test_overlap.py``), and its results are what they
+    are."""
+    if jax.default_backend() != "cpu":
+        pytest.skip("what the TPU's compiler holds is audited on its own "
+                    "text, tests/test_overlap.py")
     ds.init((1, 1), devices=jax.devices()[:1])
     m, n, sketch, nsv = 262_144, 1024, 256, 246
     from dislib_tpu.parallel import mesh as _mesh
@@ -247,7 +368,8 @@ def test_the_compiled_call_holds_less_than_a_beside_a():
         _mesh.get_mesh(), 1, 1, cholqr=True).compile().memory_analysis()
     if mem is None:
         pytest.skip("backend reports no memory analysis")
-    assert mem.temp_size_in_bytes < m * n * 4, mem.temp_size_in_bytes
+    assert mem.temp_size_in_bytes < 3.1 * m * sketch * 4, \
+        mem.temp_size_in_bytes
     assert mem.output_size_in_bytes < 1.01 * 4 * (m * nsv + nsv + n * nsv)
 
 
@@ -271,6 +393,8 @@ def test_the_counter_the_span_and_the_one_dispatch(small_blocks, monkeypatch):
     # the power iterations are one block each
     assert c["schedules"]["tsqr_local:blocked"] == 1
     assert c["schedules"]["tsqr_local:one_product"] == 1
+    # and each of the two traces assembled Q in one application
+    assert c["schedules"]["tsqr_assemble:folded"] == 2
     s.collect(), v.collect()
     assert profiling.span_totals()["dslib.host_read"]["count"] == 2
     # a second call of the same shapes traces nothing and bumps nothing
@@ -279,6 +403,25 @@ def test_the_counter_the_span_and_the_one_dispatch(small_blocks, monkeypatch):
     assert c["dispatch_by"] == {"random_svd": 2}
     assert c["trace_by"]["random_svd"] == 1
     assert c["schedules"]["tsqr_local:blocked"] == 1
+    assert c["schedules"]["tsqr_assemble:folded"] == 2
+
+
+@pytest.mark.parametrize("route", ["1", "0"])
+def test_the_assembly_is_counted_once_a_trace(monkeypatch, route):
+    """``tsqr_assemble:folded`` beside ``tsqr_local:<route>``: once a
+    trace of ``_tsqr_shardmap``, on the Cholesky route and on the tree's,
+    and not again for a call the cache serves."""
+    monkeypatch.setenv("DSLIB_TSQR_CHOLQR", route)
+    ds.init((1, 1), devices=jax.devices()[:1])
+    x = ds.array(_conditioned(1000, 16, 1e1, seed=int(route)))
+    jax.clear_caches()
+    profiling.reset_counters()
+    ds.tsqr(x)
+    ds.tsqr(x)
+    assert profiling.schedule_counters() == {
+        "tsqr_assemble:folded": 1,
+        "tsqr_local:" + ("one_product" if route == "1"
+                         else "householder_tree"): 1}
 
 
 @pytest.mark.parametrize("scope", [
