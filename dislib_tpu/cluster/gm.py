@@ -356,6 +356,8 @@ def _gm_fit(xp, shape, k, cov_type, reg_covar, tol, max_iter,
             overrides=(None, None, None), prev_lb0=None, start=None):
     m, n = shape
     _count_schedule("gm_step", "blocked")
+    _count_schedule("gm_e_step", "triangle" if _ops.em_cuts(cov_type, xp.dtype)
+                    else "whole")
     if cov_type == "full":
         _count_schedule("gm_m_step", "packed" if _ops.em_packs(n, xp.dtype)
                         else "six_pass")

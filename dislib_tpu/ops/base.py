@@ -240,10 +240,46 @@ class Whitener(NamedTuple):
 
     centre: jax.Array       # (d,) where the pass moves the origin to
     about: jax.Array        # (k, d) the means there
-    proj: jax.Array | None  # the P_j side by side, or the one P, or None
-    t: jax.Array            # (k, d or d8) the whitened means
+    proj: tuple | jax.Array | None  # full: a group's right operand each;
+    #                                 tied: the one P; else None
+    t: tuple | jax.Array    # the whitened means: full a group's (e, k8)
+    #                         each; else (k, d)
     scale: jax.Array | None  # diag and spherical: P_j itself
     const: jax.Array        # (k,) log pi_j + log det P_j - (d/2) log 2 pi
+
+
+def em_cuts(cov_type: str, dtype) -> bool:
+    """Whether the E-step's product is cut along its factors' triangle
+    (:func:`em_groups`): for full covariances, where
+    :func:`precision.pdot_short` packs.  What a trace can observe."""
+    return cov_type == "full" and px.packs_short(dtype, dtype)
+
+
+def em_groups(d: int, dtype) -> tuple:
+    """``((e0, e1, c), ...)``: how the full-covariance E-step cuts the d
+    columns e of every factor into groups, and the ``c`` leading rows of
+    the factors that the product of group ``[e0, e1)`` contracts.
+
+    A factor is upper triangular, so column e holds nothing under row e
+    and a product of the columns up to e1 needs the first e1 rows alone.
+    Where the product is packed (:func:`em_cuts`) that decides its
+    passes through the MXU, ``ceil(6 c / depth)`` with c the rows in
+    whole chunks of 16, and the columns are cut where that number changes
+    (d = 50: [0, 16) in one pass 96 deep, [16, 32) in two, 192, [32, 50)
+    in three, 384: with k = 16, 15 passes of a column tile for the whole
+    product's 21; d <= 16 is one group, the whole product).  Where it is
+    not packed a pass costs the same whatever it holds, and there is one
+    group of all d rows."""
+    if not em_cuts("full", dtype):
+        return ((0, d, d),)
+    groups = []
+    for c in range(px.SHORT_CHUNK, d + px.SHORT_CHUNK, px.SHORT_CHUNK):
+        passes = -(-6 * c // px._MXU_COLUMNS)
+        if groups and groups[-1][0] == passes:
+            groups[-1] = (passes, groups[-1][1], min(c, d), c)
+        else:
+            groups.append((passes, c - px.SHORT_CHUNK, min(c, d), c))
+    return tuple((e0, e1, c) for _, e0, e1, c in groups)
 
 
 def em_whitener(log_weights, means, prec, cov_type) -> Whitener:
@@ -255,24 +291,41 @@ def em_whitener(log_weights, means, prec, cov_type) -> Whitener:
     the rows, and with the rows centred the products below round against
     the spread of the data and not against its distance from the origin.
     ``about`` (k, d) are the means there.  A row's whitened difference to
-    component j is ``y_j = (x - mu_j) P_j``: ``proj`` holds the P_j side
-    by side, (d, k d), so that the k products of a block are ONE GEMM
-    (tied: the one P; diag and spherical: None, P_j is ``scale``), ``t``
-    the whitened means and ``const`` (k,) = log pi_j + log det P_j -
-    (d/2) log 2 pi.  ``prec`` is the Cholesky factor of the precisions as
+    component j is ``y_j = (x - mu_j) P_j``, ``t`` holds the whitened
+    means and ``const`` (k,) = log pi_j + log det P_j - (d/2) log 2 pi.
+    ``prec`` is the Cholesky factor of the precisions as
     ``GaussianMixture`` keeps it: full (k, d, d) upper, tied (d, d), diag
-    (k, d) and spherical (k,) the reciprocal standard deviations."""
+    (k, d) and spherical (k,) the reciprocal standard deviations.
+
+    Full covariances: the k factors' columns lie side by side e-major,
+    column (e, j) being column e of P_j, the components in whole sublane
+    tiles (k8: zero columns after the k-th), so that a product's (block,
+    e k8) result IS the (block, e, k8) array of the differences as the
+    chip lays both out, no copy between, and the sum of their squares
+    over e adds whole vregs.  ``proj`` and ``t`` hold one right operand
+    and one (e, k8) slice of the whitened means a group of
+    :func:`em_groups`: the group's columns of the ``c`` rows it contracts.
+    Where the product packs they are split and packed HERE
+    (:func:`precision.short_right`: bfloat16 (96, 256), (192, 256), (384,
+    288) at d = 50, k = 16) and the pass over the rows carries them:
+    nothing of them is rebuilt in a block.  Only the upper triangle of
+    ``prec`` is read.  Tied: ``proj`` is the one P; diag and spherical:
+    None, P_j is ``scale``."""
     k, d = means.shape
     centre = px.pdot(jnp.exp(log_weights), means)
     about = means - centre
     proj, scale = None, None
     if cov_type == "full":
-        # every component's d columns padded to whole sublane tiles: the
-        # GEMM's (block, k d8) result then IS the (block, k, d8) array of
-        # the k differences, as the chip lays both out, and no copy
-        # stands between the two (70 ms of a 410 ms iteration, PERF.md)
-        proj = jnp.transpose(_pad8(prec), (1, 0, 2)).reshape(d, -1)
-        t = _pad8(px.peinsum("jd,jde->je", about, prec))
+        upper = jnp.triu(prec)
+        cols = _pad8(jnp.transpose(upper, (1, 2, 0)))           # (d, e, k8)
+        whitened = _pad8(px.peinsum("jd,jde->ej", about, upper))
+        groups = em_groups(d, cols.dtype)
+        proj = tuple(cols[:c, e0:e1].reshape(min(c, d), -1)
+                     for e0, e1, c in groups)
+        if em_cuts(cov_type, cols.dtype):
+            proj = tuple(px.short_right(right, px.SHORT_CHUNK)
+                         for right in proj)
+        t = tuple(whitened[e0:e1] for e0, e1, _ in groups)
         logdet = jnp.sum(jnp.log(jnp.diagonal(prec, axis1=1, axis2=2)), 1)
     elif cov_type == "tied":
         proj, t = prec, px.pdot(about, prec)
@@ -295,10 +348,25 @@ def _pad8(a):
 
 def _em_log_prob(xb, wh: Whitener):
     """log(pi_j N(x | mu_j, Sigma_j)) of a block's rows ``xb`` (block,
-    d): (block, k).  The (block, k, d) whitened differences are taken as
-    they are, squared and summed: no expanded |z|^2 - 2 z.t + |t|^2,
-    whose terms cancel."""
+    d): (block, k).  The whitened differences are taken as they are,
+    squared and summed: no expanded |z|^2 - 2 z.t + |t|^2, whose terms
+    cancel.  Full covariances: ONE product a group of ``wh.proj`` and the
+    group's share of the sum over e, its differences (block, e, k8) with
+    e in the middle; where the product packs, the block's rows are split
+    and packed once (:func:`precision.short_left`) and every group reads
+    the leading columns it contracts.  The other types: (block, k, d)
+    differences, summed over d."""
     xc = xb - wh.centre
+    if isinstance(wh.proj, tuple):
+        packs = em_cuts("full", xc.dtype)
+        left = px.short_left(xc, px.SHORT_CHUNK) if packs else xc
+        sq = 0.0
+        for right, t in zip(wh.proj, wh.t):
+            z = px.pdot_packed(left, right) if packs \
+                else px.pdot(left, right)
+            y = z.reshape(xc.shape[0], *t.shape) - t[None]
+            sq = sq + jnp.sum(y * y, axis=1)
+        return wh.const[None, :] - 0.5 * sq[:, :wh.const.shape[0]]
     z = xc if wh.proj is None else px.pdot_short(xc, wh.proj)
     if wh.scale is not None:
         z = z[:, None, :] * wh.scale[None]

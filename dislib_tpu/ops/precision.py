@@ -282,25 +282,25 @@ def pdot_short(a, b, policy: Policy = FLOAT32):
     ceil(6 c16 / 128) passes instead of 6, c16 being c in whole tiles of
     16 (three for c = 50: 88 against 175 ms an EM iteration's E-step at
     24M x 50, k = 16; PERF.md, PR 29).
-    Decided by what a trace can observe: the float32 policy, float32
-    operands and a backend of ``_PACK_BACKENDS``; everything else is
-    :func:`pdot` as it stands."""
-    f32_ = jnp.dtype(jnp.float32)
-    if policy.name != "float32" or a.dtype != f32_ or b.dtype != f32_ \
-            or jax.default_backend() not in _PACK_BACKENDS:
+    Decided by what a trace can observe (:func:`packs_short`): the
+    float32 policy, float32 operands and a backend of ``_PACK_BACKENDS``;
+    everything else is :func:`pdot` as it stands.
+
+    Its two halves are functions of their own, below ``precise`` beside
+    :func:`pdot_tall`: :func:`short_right` packs ``b``, and
+    :func:`pdot_packed` multiplies a packed left operand
+    (:func:`short_left`) by it; this is the two in a row with the
+    contraction in ONE chunk.  A caller whose ``b`` serves many products
+    packs it once and keeps it, and one whose ``b`` is upper triangular
+    cuts the contraction in chunks of 16, so that a group of ``b``'s
+    columns reads a prefix of the packed left operand and no more.  The
+    mixture's E-step does both: its factors are packed once an
+    iteration, its rows' parts once a block, and it makes three products
+    of them (``ops/base.py::em_whitener``, ``_em_log_prob``)."""
+    if not packs_short(a.dtype, b.dtype, policy):
         return pdot(a, b, policy)
-    with jax.named_scope("dslib.pdot"):
-        # whole bfloat16 sublane tiles (16) of the contraction a part, so
-        # that the parts lie side by side without a relayout
-        fill = -a.shape[-1] % 16
-        a = jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, fill)])
-        b = jnp.pad(b, [(0, 0)] * (b.ndim - 2) + [(0, fill), (0, 0)])
-        a_hi, a_mid, a_lo = highest_parts(a)
-        b_hi, b_mid, b_lo = highest_parts(b)
-        return jnp.matmul(
-            jnp.concatenate([a_hi, a_mid, a_lo, a_hi, a_mid, a_hi], axis=-1),
-            jnp.concatenate([b_hi, b_hi, b_hi, b_mid, b_mid, b_lo], axis=-2),
-            precision=ONE_PASS, preferred_element_type=f32_)
+    whole = a.shape[-1] + -a.shape[-1] % SHORT_CHUNK
+    return pdot_packed(short_left(a, whole), short_right(b, whole))
 
 
 def precise(fn):
@@ -382,3 +382,73 @@ def pdot_tall(a, b, policy: Policy = FLOAT32):
         by_mid = down(a_mid, jnp.concatenate([b_hi, b_mid], axis=1))
         return (by_hi[:, 2 * n:] + by_mid[:, n:] + down(a_lo, b_hi)) \
             + (by_hi[:, n:2 * n] + by_mid[:, :n]) + by_hi[:, :n]
+
+
+# Rows of a packed short contraction that lie together: one bfloat16
+# sublane tile.  A chunk is a whole number of them.
+SHORT_CHUNK = 16
+
+
+def packs_short(a_dtype, b_dtype, policy: Policy = FLOAT32) -> bool:
+    """Whether :func:`pdot_short` packs a product of operands of these
+    dtypes: under the float32 policy, for float32 operands, on a backend
+    of ``_PACK_BACKENDS``."""
+    f32_ = jnp.dtype(jnp.float32)
+    return policy.name == "float32" and a_dtype == f32_ and b_dtype == f32_ \
+        and jax.default_backend() in _PACK_BACKENDS
+
+
+def _short_packed(x, chunk, order, axis):
+    """The bfloat16 parts of ``x`` laid along ``axis`` (its contraction,
+    zero-filled to whole chunks) chunk by chunk of ``chunk`` (whole
+    ``SHORT_CHUNK``s, so that the pieces lie side by side without a
+    relayout), and inside a chunk in ``order``: a stack of the
+    parts over the chunks, between two barriers.  The first, on the parts
+    as (.., chunks, chunk) arrays, has them split ONCE, in one fusion, and
+    the stack written by six that only move them.  The second, on the
+    packed operand, keeps the TPU's compiler from folding what a caller
+    does with the product (a reshape of its columns, a sum over them)
+    into the GEMM as a convolution that runs at half its speed.  What
+    the two leave is one copy of the operand, chunk-major from the
+    part-major order the compiler stacks in (PERF.md, PR 37, has the
+    spellings that lost)."""
+    with jax.named_scope("dslib.pdot"):
+        fill = [(0, 0)] * x.ndim
+        fill[axis] = (0, -x.shape[axis] % chunk)
+        parts = jax.lax.optimization_barrier([
+            p.reshape(*p.shape[:axis], -1, chunk, *p.shape[axis + 1:])
+            for p in highest_parts(jnp.pad(x, fill))])
+        packed = jnp.stack([parts[p] for p in order], axis=axis + 1)
+        return jax.lax.optimization_barrier(packed.reshape(
+            *x.shape[:axis], -1, *x.shape[axis + 1:]))
+
+
+def short_left(a, chunk):
+    """The left operand of a packed short contraction: float32 ``a`` (m,
+    c) as bfloat16 (m, 6 c'), ``[hi, mid, lo, hi, mid, hi]`` of the first
+    ``chunk`` columns, then of the next, ..., c' being c in whole chunks.
+    The leading ``6 r`` columns, r a whole number of chunks, are the
+    packed operand of a product that contracts the first r columns of
+    ``a`` alone (with its rows on the lanes, as the chip holds a tall
+    operand, a prefix of the columns is the same memory: no copy)."""
+    return _short_packed(a, chunk, (0, 1, 2, 0, 1, 0), a.ndim - 1)
+
+
+def short_right(b, chunk):
+    """The right operand to match :func:`short_left`: float32 ``b`` (c, n)
+    as bfloat16 (6 c', n), ``[hi; hi; hi; mid; mid; lo]`` chunk by
+    chunk."""
+    return _short_packed(b, chunk, (0, 0, 0, 1, 1, 2), b.ndim - 2)
+
+
+def pdot_packed(left, right):
+    """ONE bfloat16 GEMM accumulated in float32 of the leading columns of
+    a :func:`short_left` by a :func:`short_right` of those columns' rows
+    alone, both in the same chunks: the six products of a 'highest'
+    contraction over those columns."""
+    with jax.named_scope("dslib.pdot"):
+        return jnp.matmul(
+            jax.lax.slice_in_dim(left, 0, right.shape[-2],
+                                 axis=left.ndim - 1),
+            right, precision=ONE_PASS,
+            preferred_element_type=jnp.dtype(jnp.float32))

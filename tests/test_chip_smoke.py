@@ -58,6 +58,9 @@ def test_rehearsal_runs_every_phase_stamped(tmp_path):
     # the mixture fit's step is the blocked one on every backend, and its
     # packed partial sums cross the mesh in one all-reduce
     assert by["mixture"]["gm_step"] == ["blocked"]
+    # its E-step's product is ONE whole GEMM here; a TPU, which packs it,
+    # cuts it along the factors' triangle and reads "triangle"
+    assert by["mixture"]["gm_e_step"] == ["whole"]
     # its M-step's product is XLA's own six passes here; a TPU reads "packed"
     assert by["mixture"]["gm_m_step"] == ["six_pass"]
     assert by["mixture"]["collectives"] == {"all-reduce": 1}

@@ -20,6 +20,7 @@ bound.  Readings on this rig are 10 to 100 times smaller.
 """
 
 import os
+import re
 import sys
 
 import jax
@@ -239,6 +240,7 @@ def test_the_fit_with_the_packed_products_agrees_with_the_reference(
     profiling.reset_counters()
     gm = _fit(x, start, "full", 10)
     assert profiling.schedule_counters()["gm_m_step:packed"] == 1
+    assert profiling.schedule_counters()["gm_e_step:triangle"] == 1
     xr, block = _reference_rows(x)
     ref_start = (start[0], start[1], covs0)
     w, m, c, hist = ref.fit(xr, ref_start, 10, block)
@@ -284,6 +286,163 @@ def test_the_start_through_the_packed_product_gives_its_present_answers(
         assert got.shape == was.shape == truth.shape
         assert np.linalg.norm(got - was) <= 2e-6 * np.linalg.norm(truth)
         assert np.linalg.norm(got - truth) <= 2e-6 * np.linalg.norm(truth)
+
+
+# -- (a') the E-step cut along the factors' triangle --------------------------
+
+def _mixture(seed, d, k):
+    """Parameters of a mixture as the E-step takes them, in float64:
+    log-weights, means, and the upper Cholesky factors of the precisions
+    (``_chol_precisions``' convention), and 300 rows around the means."""
+    rng = np.random.RandomState(seed)
+    weights = rng.rand(k) + 0.5
+    means = 3.0 * rng.rand(k, d)
+    a = np.eye(d) + 0.4 * rng.randn(k, d, d) / np.sqrt(d)
+    covs = np.einsum("jpd,jqd->jpq", a, a)
+    prec = np.stack([np.linalg.inv(np.linalg.cholesky(c)).T for c in covs])
+    x = means[rng.randint(0, k, 300)] + rng.randn(300, d)
+    return np.log(weights / weights.sum()), means, prec, x
+
+
+def _log_prob(x, log_weights, means, prec):
+    """``_em_log_prob`` of the rows ``x`` at these parameters, as the
+    backend at hand runs it."""
+    f32 = [jnp.asarray(a, jnp.float32) for a in (x, log_weights, means, prec)]
+    return np.asarray(jax.jit(lambda xb, lw, mu, p: _ops._em_log_prob(
+        xb, _ops.em_whitener(lw, mu, p, "full")))(*f32))
+
+
+@pytest.mark.parametrize("k", [1, 3, 16])
+@pytest.mark.parametrize("d", [8, 16, 17, 50, 64, 100])
+def test_the_cut_e_step_is_the_whole_one(monkeypatch, d, k):
+    """The full-covariance E-step cut along its factors' triangle (what a
+    backend that packs runs) against the one GEMM of every other backend
+    and against float64: the same six products less those with the zeros
+    under a factor's diagonal, so both within float32 rounding of the
+    truth; the groups as the shapes give them; and the strictly lower
+    triangle of the factors is never read."""
+    from dislib_tpu.ops import precision as px
+    log_w, means, prec, x = _mixture(100 * d + k, d, k)
+    y = np.einsum("bjd,jde->bje", x[:, None, :] - means[None], prec)
+    want = log_w + np.log(np.diagonal(prec, axis1=1, axis2=2)).sum(1) \
+        - 0.5 * d * np.log(2 * np.pi) - 0.5 * (y * y).sum(2)
+    # what an entry's rounding is measured against: d squared differences
+    room = 1e-6 * (np.abs(y) * np.einsum(
+        "bjd,jde->bje", np.abs(x[:, None, :] - means[None]),
+        np.abs(prec))).sum(2) + 1e-6 * np.abs(want)
+    f32 = np.dtype(np.float32)
+    assert _ops.em_groups(d, f32) == ((0, d, d),)
+    whole = _log_prob(x, log_w, means, prec)
+    assert whole.shape == (300, k) and np.all(np.abs(whole - want) <= room)
+    poisoned = np.where(np.tri(d, k=-1, dtype=bool), np.nan, prec)
+    assert np.array_equal(_log_prob(x, log_w, means, poisoned), whole)
+
+    monkeypatch.setattr(px, "_PACK_BACKENDS", (jax.default_backend(),))
+    groups = _ops.em_groups(d, f32)
+    assert groups == {
+        8: ((0, 8, 16),), 16: ((0, 16, 16),),
+        17: ((0, 16, 16), (16, 17, 32)),
+        50: ((0, 16, 16), (16, 32, 32), (32, 50, 64)),
+        64: ((0, 16, 16), (16, 32, 32), (32, 64, 64)),
+        100: ((0, 16, 16), (16, 32, 32), (32, 64, 64), (64, 80, 80),
+              (80, 96, 96), (96, 100, 112))}[d]
+    wh = _ops.em_whitener(*(jnp.asarray(a, jnp.float32)
+                            for a in (log_w, means, prec)), "full")
+    k8 = k + -k % 8
+    assert [(r.shape, r.dtype) for r in wh.proj] \
+        == [((6 * c, (e1 - e0) * k8), jnp.bfloat16) for e0, e1, c in groups]
+    assert [t.shape for t in wh.t] == [(e1 - e0, k8) for e0, e1, _ in groups]
+    cut = _log_prob(x, log_w, means, prec)
+    assert cut.shape == (300, k) and np.all(np.abs(cut - want) <= room)
+    assert np.array_equal(_log_prob(x, log_w, means, poisoned), cut)
+    # not the same program: another order of the same sums
+    assert d <= 16 or not np.array_equal(cut, whole)
+
+
+@pytest.mark.parametrize("devices", [1, 8])
+def test_fit_score_and_predict_through_the_cut_e_step(monkeypatch, packing,
+                                                      devices):
+    """A fit whose factors have two groups of columns (d = 20), on blocks
+    of 512 rows: ``fit``, ``score`` and ``predict`` run the one cut
+    E-step, counted once a trace of ``_gm_fit``, and agree with the plain
+    reference's."""
+    d = 20
+    monkeypatch.setattr(_ops, "_EM_TILE_BYTES", 4 * K * (d + 4) * 512)
+    _mesh_of(devices)
+    assert len(_ops.em_groups(d, np.dtype(np.float32))) == 2
+    rng = np.random.RandomState(21)
+    mu = 4.0 * rng.rand(K, d)
+    a = np.eye(d) + 0.3 * rng.randn(K, d, d) / np.sqrt(d)
+    comp = rng.randint(0, K, ROWS)
+    x = (mu[comp] + np.einsum("bd,bde->be", rng.randn(ROWS, d), a[comp])
+         ).astype(np.float32)
+    start = (np.full((K,), 1.0 / K, np.float32),
+             (mu + 0.3 * rng.randn(K, d)).astype(np.float32),
+             np.tile(np.eye(d, dtype=np.float32), (K, 1, 1)))
+    profiling.reset_counters()
+    gm = GaussianMixture(
+        n_components=K, max_iter=5, tol=0.0, weights_init=start[0],
+        means_init=start[1], precisions_init=start[2]).fit(ds.array(x))
+    assert profiling.schedule_counters()["gm_e_step:triangle"] == 1
+    assert "gm_e_step:whole" not in profiling.schedule_counters()
+    ref_start = (start[0], start[1], start[2])      # P = I: covariances I
+    w, m, c, hist = ref.fit(jnp.asarray(x), ref_start, 5, ROWS)
+    gaps = ref.compare(_compared(gm), w, m, c, hist, ref_start, 5)
+    assert gaps["first_bound_gap"] < 2e-6 and gaps["bound_gap"] < 2e-6 \
+        and gaps["means_gap"] < 2e-5 and gaps["covariances_gap"] < 5e-5, gaps
+    xa = ds.array(x)
+    want, labels = ref.e_step(jnp.asarray(x), gm.weights_, gm.means_,
+                              gm.covariances_, ROWS, "full")
+    assert gm.score(xa) == pytest.approx(want, rel=2e-6)
+    assert np.array_equal(gm.predict(xa).collect().ravel(), labels)
+
+
+def _block_computation(text):
+    """``(inside, outside)``: the lines of the computation of an HLO text
+    that holds the E-step's products, a block's body, and all the others."""
+    for block in text.split("\n}\n"):
+        if re.search(r" dot\(.*dslib\.gm\.e_step/dslib\.pdot", block):
+            return block.splitlines(), text.replace(block, "").splitlines()
+    raise AssertionError("no computation holds an E-step product")
+
+
+def test_the_block_loop_holds_one_product_a_group_and_no_split(packing):
+    """The program handed to the compiler, at the cell's widths: a
+    block's body holds one E-step product a group, each against an
+    operand that comes in as an argument, and nothing there converts to
+    bfloat16, or concatenates, anything shaped like a factor's operand:
+    the split happens once an iteration, outside the loop."""
+    ds.init((1, 1), devices=jax.devices()[:1])
+    m, d, k = 4 * 7_680, 50, 16
+    assert _ops.em_block(m, d, k) == 7_680
+    text = _gm._gm_fit.lower(
+        jax.ShapeDtypeStruct((m, d), jnp.float32), (m, d), k, "full", 1e-6,
+        0.0, 2, (jax.ShapeDtypeStruct((k,), jnp.float32),
+                 jax.ShapeDtypeStruct((k, d), jnp.float32),
+                 jax.ShapeDtypeStruct((k, d, d), jnp.float32))
+    ).as_text(dialect="hlo", debug_info=True)
+    inside, outside = _block_computation(text)
+    products = [line for line in inside
+                if " dot(" in line and "dslib.gm.e_step" in line]
+    groups = _ops.em_groups(d, np.dtype(np.float32))
+    assert len(products) == len(groups) == 3
+    rights = [f"bf16[{6 * c},{(e1 - e0) * k}]" for e0, e1, c in groups]
+    assert rights == ["bf16[96,256]", "bf16[192,256]", "bf16[384,288]"]
+    for right, product in zip(rights, products):
+        arg = re.search(r" dot\(\S+, (\S+)\)", product).group(1)
+        assert [line for line in inside if re.match(
+            rf"\s*{re.escape(arg)} = {re.escape(right)}\S* parameter\(",
+            line)], (right, product)
+    # a block's rows are split in the loop; the factors are not
+    made = [line for line in inside
+            if " convert(" in line or " concatenate(" in line]
+    assert any(re.search(r"= bf16\[7680,", line) for line in made)
+    assert not [line for line in made
+                if re.search(r"= bf16\[(\d+,)*(256|288)\]", line)], made
+    # ... but once an iteration, outside it
+    for right in rights:
+        assert [line for line in outside if f"= {right}" in line
+                and " parameter(" not in line], right
 
 
 # -- (b) the shifted one-pass covariance --------------------------------------
@@ -388,7 +547,10 @@ def test_the_counter_and_the_spans_of_one_fit():
     _fit(x, start, "full", 4)
     c = profiling.counters()
     assert c["schedules"]["gm_step:blocked"] == c["trace_by"]["gm_fit"] == 1
-    # this backend packs nothing: the M-step's product is XLA's six passes
+    # this backend packs nothing: the E-step's product is ONE whole GEMM,
+    # the M-step's XLA's six passes
+    assert c["schedules"]["gm_e_step:whole"] == 1
+    assert "gm_e_step:triangle" not in c["schedules"]
     assert c["schedules"]["gm_m_step:six_pass"] == 1
     assert "gm_m_step:packed" not in c["schedules"]
     spans = c["spans"]
@@ -404,6 +566,7 @@ def test_the_counter_and_the_spans_of_one_fit():
     # a second fit of the same shapes traces nothing and bumps nothing
     _fit(x, start, "full", 4)
     assert profiling.schedule_counters()["gm_step:blocked"] == 1
+    assert profiling.schedule_counters()["gm_e_step:whole"] == 1
     assert profiling.schedule_counters()["gm_m_step:six_pass"] == 1
 
 
@@ -418,10 +581,14 @@ def test_the_m_step_counter_counts_full_covariances_only(packing, cov_type):
     _fit(x, _start(mu, cov_type)[0], cov_type, 2)
     assert not [key for key in profiling.schedule_counters()
                 if key.startswith("gm_m_step:")]
+    # nor a triangle to cut: their E-step is whole, packed or not
+    assert profiling.schedule_counters()["gm_e_step:whole"] == 1
     for _ in range(2):
         _fit(x, _start(mu, "full")[0], "full", 2)
     assert profiling.schedule_counters()["gm_m_step:packed"] == 1
     assert "gm_m_step:six_pass" not in profiling.schedule_counters()
+    assert profiling.schedule_counters()["gm_e_step:triangle"] == 1
+    assert profiling.schedule_counters()["gm_e_step:whole"] == 1
 
 
 @pytest.mark.parametrize("scope", ["dslib.gm.chol", "dslib.gm.e_step",

@@ -626,6 +626,86 @@ class TestCompiledTallOrthonormalisation:
             mem.temp_size_in_bytes
 
 
+class TestCompiledMixtureEStep:
+    """What the TPU's compiler makes of the mixture's cut E-step
+    (``ops/base.py::_em_log_prob`` over ``precision.short_left``,
+    ``short_right``, ``pdot_packed``), at the widths of the cell
+    ``gmm_fit_sustained`` (it lives here for the fixture above)."""
+
+    def test_tpu_block_loop_has_three_products_and_no_split_of_the_factors(
+            self, describe_v5e, monkeypatch):
+        """``_gm_fit`` for a described v5e, 8 blocks of 7 680 rows, d =
+        50, k = 16.  In the loop over the blocks: three E-step products
+        and no more, plain GEMMs whose result is the whole (block, e k8)
+        array (not the convolution over e that the compiler folds a
+        group's sum into where nothing stands in its way), each against
+        an operand the loop carries (nothing in the loop writes anything
+        shaped like one: the factors are split and packed once an
+        iteration); the block's rows split in ONE fusion, packed by six
+        that only move the parts, and copied once into the chunk-major
+        order the products read; and no more ops a block than the
+        one-GEMM program had (27)."""
+        import importlib
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec
+        gm = importlib.import_module("dislib_tpu.cluster.gm")
+        topo = describe_v5e("v5e:2x2")
+        mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1),
+                    (_mesh.ROWS, _mesh.COLS))
+        # a described chip is not the default backend: say that it packs
+        monkeypatch.setattr(px, "_PACK_BACKENDS", (jax.default_backend(),))
+        was = _mesh.get_mesh()
+        _mesh.set_mesh(mesh)
+        m, d, k = 8 * 7_680, 50, 16
+        rows = NamedSharding(mesh, PartitionSpec(_mesh.ROWS, None))
+        whole = NamedSharding(mesh, PartitionSpec())
+        try:
+            with _compile_cache_off():
+                text = gm._gm_fit.lower(
+                    jax.ShapeDtypeStruct((m, d), jnp.float32, sharding=rows),
+                    (m, d), k, "full", 1e-6, 0.0, 2,
+                    (jax.ShapeDtypeStruct((k,), jnp.float32, sharding=whole),
+                     jax.ShapeDtypeStruct((k, d), jnp.float32,
+                                          sharding=whole),
+                     jax.ShapeDtypeStruct((k, d, d), jnp.float32,
+                                          sharding=whole))
+                ).compile().as_text()
+        finally:
+            _mesh.set_mesh(was)
+            jax.clear_caches()          # no packed program is left behind
+        e_gemm = "dslib.gm.e_step/dslib.pdot/dot_general"
+        body = next(block for block in re.split(r"\n\}\n", text)
+                    if re.search(rf" fusion\(.*kind=kOutput.*{e_gemm}",
+                                 block))
+        ops = [_OP_RE.match(line) for line in body.splitlines()]
+        ops = [(m_.group(1), m_.group(2), m_.group(3), m_.string)
+               for m_ in ops if m_]
+        gemms = [name for name, _, op, line in ops
+                 if op == "fusion" and "kind=kOutput" in line
+                 and e_gemm in line]
+        assert len(gemms) == 3, gemms
+        shapes = sorted(shape.split("{")[0] for name, shape, _, _ in ops
+                        if name in gemms)
+        assert shapes == ["f32[7680,256]", "f32[7680,256]",
+                          "f32[7680,288]"], shapes
+        # the factors' operands: read, never written, in the loop
+        for right in ("bf16[96,256]", "bf16[192,256]", "bf16[384,288]"):
+            made = [name for name, shape, op, _ in ops
+                    if shape.startswith(right) and op not in (
+                        "get-tuple-element", "bitcast", "parameter")]
+            assert not made, (right, made)
+        # the block's operand: one split, six writes, one copy
+        split = [name for name, shape, op, _ in ops if op == "fusion"
+                 and shape.startswith("(bf16[7680,64]")]
+        assert len(split) == 1, split
+        left = [(name, op) for name, shape, op, _ in ops
+                if shape.startswith("bf16[7680,4,6,16]")]
+        assert [op for _, op in left] == ["fusion"] * 6 + ["copy"], left
+        device_ops = [name for name, shape, op, _ in ops
+                      if op in ("fusion", "copy", "slice", "convolution")
+                      and not shape.startswith(("f32[]", "s32[]", "pred[]"))]
+        assert len(device_ops) <= 27, device_ops
+
+
 # ---------------------------------------------------------------------------
 # 5. schedule-equivalence grid: panel rechunk
 # ---------------------------------------------------------------------------

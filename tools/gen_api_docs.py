@@ -42,6 +42,7 @@ SECTIONS = [
       "lanczos_svd"]),
     ("Precision policy (mixed-precision linalg)", "dislib_tpu.ops.precision",
      ["Policy", "resolve", "to_compute", "f32", "pdot", "pdot_short",
+      "packs_short", "short_left", "short_right", "pdot_packed",
       "pdot_tall", "packs_tall", "peinsum", "precise"]),
     ("Overlap schedules (comm–compute pipelining)", "dislib_tpu.ops.overlap",
      ["resolve", "overlapped", "panel_pipeline", "round_pipeline",
