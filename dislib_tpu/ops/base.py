@@ -478,9 +478,13 @@ def em_step(xp, m, log_weights, means, prec, cov_type):
                                   cov_type)
         return sums, jnp.sum(lse * w)
 
-    sums, loglik = blocked_row_sums(
-        xp, m, d, _em_row_bytes(k, d), block,
-        (_em_zero_sums(k, d, cov_type, xp.dtype), jnp.zeros((), xp.dtype)))
+    # the walk itself (a block's cut, its row mask, the compensated running
+    # sums: 0.6% of the cell's device time) lies under this scope alone
+    with jax.named_scope("dslib.gm.pass"):
+        sums, loglik = blocked_row_sums(
+            xp, m, d, _em_row_bytes(k, d), block,
+            (_em_zero_sums(k, d, cov_type, xp.dtype),
+             jnp.zeros((), xp.dtype)))
     return (*_em_sums_about(sums, wh.about, cov_type), loglik)
 
 

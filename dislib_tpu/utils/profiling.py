@@ -24,14 +24,14 @@ TPU-native equivalent: the library marks its own layer boundaries, and
 - `annotate(name)` — both at once, for user code: `jax.named_scope` +
   `span` — the Extrae user-events analog.
 - `op_graph(fn, *args)` — compiled-HLO text of a jitted function — the
-  `--graph` task-DAG analog; the scopes show in its `op_name`s.
-- dispatch/retrace counters (round-7 fusion PR): every library kernel is
-  wrapped by :func:`profiled_jit`, which counts one *dispatch* per call
-  and one *trace* per (re)compilation.  `dispatch_count()` is how the
-  fusion layer's "a chain of ops is ONE XLA program" claim becomes a
-  measured number (and a test assertion), and `trace_count()` is the
-  retrace guard — a cache-key regression shows up as extra traces, not
-  as a silent 20-second recompile on chip.
+  `--graph` task-DAG analog.
+- dispatch/retrace counters: every library kernel is wrapped by
+  :func:`profiled_jit`, which counts one *dispatch* per call and one
+  *trace* per (re)compilation (`dispatch_count()`, `trace_count()`: "a
+  chain of ops is ONE XLA program" as a test assertion; a cache-key
+  regression shows as extra traces, not as a silent recompile on chip).
+- the catalogue: `profiled_jit` keeps the signature of every call that
+  compiled; `program_scopes()` names a capture's ops by their scopes.
 
 Naming rule.  `dslib.<module>.<phase>`, lower case, a fixed string at its
 site: shapes, indices and iteration numbers go into `**stats`, never into
@@ -195,11 +195,14 @@ def profiled_jit(fn=None, *, name: str | None = None, before=None,
     def dispatch(*args, **kwargs):
         with _COUNTERS_LOCK:
             _COUNTERS.dispatches += 1
-            _COUNTERS.dispatch_by[label] = \
-                _COUNTERS.dispatch_by.get(label, 0) + 1
+            by, traces = _COUNTERS.dispatch_by, _COUNTERS.trace_by.get(label)
+            by[label] = by.get(label, 0) + 1
         if before is not None:
             before()
-        return jitted(*args, **kwargs)
+        done = jitted(*args, **kwargs)  # line and column as they were: D12
+        if _COUNTERS.trace_by.get(label) != traces:     # this call compiled
+            _remember_program(label, jitted, args, kwargs)
+        return done
 
     dispatch.jitted = jitted
     dispatch.lower = jitted.lower       # AOT access (HLO audits) counts a
@@ -350,3 +353,163 @@ def memory_stats():
     exposes no allocator stats (CPU) map to None.
     """
     return {str(d): d.memory_stats() for d in jax.local_devices()}
+
+
+# ---------------------------------------------------------------------------
+# the catalogue of compiled programs
+# ---------------------------------------------------------------------------
+
+# imported here, not at the top: a program with a Pallas kernel has the lines
+# of `traced` and `dispatch` in its cache key, so nothing above them moves
+# (ROADMAP.md D12)
+import re                   # noqa: E402
+import warnings             # noqa: E402
+import numpy as np          # noqa: E402
+
+# (label, treedef, leaves) -> [jitted, row or None]: what was compiled, by the
+# abstract signature of the call that compiled it.  No device buffer; it
+# describes the jit cache, is bounded as JAX bounds that (the oldest
+# signature goes first), and is no tally.
+_PROGRAMS: dict = {}
+_MAX_PROGRAMS = 4096
+
+_INSTRUCTION_RE = re.compile(r"^\s*(ROOT\s+)?%?([\w.-]+) = ")
+_COMPUTATION_RE = re.compile(r"^(?:ENTRY\s+)?%?([\w.-]+)\s.*->.*\{\s*$")
+_OP_NAME_RE = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+_FUSION_CALLS_RE = re.compile(r"\sfusion\(.*\bcalls=%?([\w.-]+)")
+_SCOPE_RE = re.compile(r"dslib\.[\w.]+")
+
+
+def _remember_program(label, jitted, args, kwargs) -> None:
+    """Store the abstract signature of a call that compiled (``dispatch``
+    calls this once a compilation, never once a dispatch).  A call made
+    under another trace stores nothing: its ops are the outer program's."""
+    leaves, tree = jax.tree_util.tree_flatten((args, kwargs))
+    if any(isinstance(a, jax.core.Tracer) for a in leaves):
+        return
+    key = (label, tree, tuple(_abstract(a) for a in leaves))
+    with _COUNTERS_LOCK:
+        if key not in _PROGRAMS and len(_PROGRAMS) >= _MAX_PROGRAMS:
+            del _PROGRAMS[next(iter(_PROGRAMS))]
+        _PROGRAMS.setdefault(key, [jitted, None])
+
+
+def _abstract(a):
+    """A leaf of a call as the catalogue keeps it: an array as its shape,
+    dtype and (where the caller placed it) sharding; a static as it is."""
+    if isinstance(a, jax.Array):
+        return jax.ShapeDtypeStruct(
+            a.shape, a.dtype, weak_type=a.weak_type,
+            sharding=a.sharding if a.committed else None)
+    if isinstance(a, np.ndarray):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype)
+    return a
+
+
+def program_scopes(name: str | None = None) -> list:
+    """Which ``dslib.`` scopes each compiled instruction lies under: one
+    row for each program that :func:`profiled_jit` compiled in this process
+    (of the label ``name``, or of every label), ``{"program": label,
+    "scopes": {instruction: chain}, "temp_bytes", "argument_bytes",
+    "output_bytes"}``.
+
+    ``scopes`` maps every instruction of the compiled text, named as a
+    capture's timeline names its ops, to the ``dslib.`` components of its
+    ``op_name``, outer to inner, joined by ``/``
+    (``dslib.rsvd.power/dslib.tsqr.gram/dslib.pdot``); ``""`` where it
+    lies under none.  A fusion without metadata takes its root's (or what
+    all it holds agree on, where the root has none either).  The three
+    sizes are the compiler's ``memory_analysis()``: ``temp_bytes`` is
+    what the program needs beyond its arguments and results, which
+    :func:`memory_stats` does not show.
+
+    On demand only, and after the work that is timed: the first call for a
+    signature lowers and compiles it again (with the persistent cache on,
+    a load) and the row is kept; a second call compiles nothing.  It
+    dispatches nothing and touches no device buffer.  The lowering is AOT
+    access as ``dispatch.lower`` is: where JAX's own caches do not answer
+    it, it runs the traced body, which counts a trace under the label
+    (``trace_by``, ``trace_count()``) and bumps ``schedule_counters()``
+    where the body does: do not call it inside a region whose counters
+    are asserted.  A
+    signature lowers under the mesh and the ``jax.config`` of this call,
+    not of the call that compiled it.  The persistent cache's key holds
+    no scope name: an executable loaded from it names the scopes of the
+    tree that compiled it, and a row whose scopes are not the ones this
+    tree's lowering names comes with a warning that says so."""
+    with _COUNTERS_LOCK:
+        entries = [(key, entry) for key, entry in _PROGRAMS.items()
+                   if name is None or key[0] == name]
+    rows = []
+    for (label, tree, sig), entry in entries:
+        if entry[1] is None:
+            args, kwargs = jax.tree_util.tree_unflatten(tree, sig)
+            lowered = entry[0].lower(*args, **kwargs)
+            compiled = lowered.compile()
+            scopes = _scopes_of(compiled.as_text())
+            _warn_if_stale(label, lowered, scopes)
+            mem = compiled.memory_analysis()
+            entry[1] = {"program": label, "scopes": scopes,
+                        "temp_bytes": mem.temp_size_in_bytes,
+                        "argument_bytes": mem.argument_size_in_bytes,
+                        "output_bytes": mem.output_size_in_bytes}
+        rows.append(entry[1])
+    return rows
+
+
+def _warn_if_stale(label, lowered, scopes) -> None:
+    """The persistent cache's key holds no metadata, so an executable
+    loaded from it names the scopes of the tree that compiled it.  Where
+    those are not the scopes this tree's lowering names, say so."""
+    emitted = set(_SCOPE_RE.findall(lowered.as_text(debug_info=True)))
+    held = {name for chain in scopes.values() for name in chain.split("/")
+            if name}
+    if emitted != held:
+        warnings.warn(
+            f"program_scopes: {label!r} was compiled under other scope names "
+            f"than this tree gives it (only here: {sorted(emitted - held)}, "
+            f"only in the executable: {sorted(held - emitted)}); it came from "
+            "the compilation cache, whose key holds no scope name: point "
+            "JAX_COMPILATION_CACHE_DIR at an empty directory to read this "
+            "tree's scopes", stacklevel=3)
+
+
+def _scopes_of(text: str) -> dict:
+    """``{instruction: scope chain}`` of a compiled module's text."""
+    scopes, roots, inside, calls = {}, {}, {}, {}
+    computation = None
+    for line in text.splitlines():
+        m = _INSTRUCTION_RE.match(line)
+        if m is None:
+            c = _COMPUTATION_RE.match(line)
+            if c:
+                computation = c.group(1)
+            continue
+        root, inst = m.groups()
+        op_name = _OP_NAME_RE.search(line)
+        if op_name:
+            chain = "/".join(_SCOPE_RE.findall(op_name.group(1)))
+            inside.setdefault(computation, set()).add(chain)
+            if root:
+                roots[computation] = chain
+        else:
+            chain = ""
+            called = _FUSION_CALLS_RE.search(line)
+            if called:
+                calls[inst] = called.group(1)
+        scopes[inst] = chain
+    # a fusion the compiler made without metadata: its root's chain or,
+    # where the root has none either (a concatenate taken apart into
+    # writes), the one chain that all it holds with metadata agree on
+    for inst, called in calls.items():
+        agreed = inside.get(called, ())
+        scopes[inst] = roots[called] if called in roots \
+            else next(iter(agreed)) if len(agreed) == 1 else ""
+    return scopes
+
+
+def clear_programs() -> None:
+    """Empty the catalogue (tests).  ``reset_counters()`` leaves it alone:
+    it is no tally, it describes what the jit cache holds."""
+    with _COUNTERS_LOCK:
+        _PROGRAMS.clear()

@@ -593,7 +593,7 @@ def test_the_m_step_counter_counts_full_covariances_only(packing, cov_type):
 
 @pytest.mark.parametrize("scope", ["dslib.gm.chol", "dslib.gm.e_step",
                                    "dslib.gm.m_step", "dslib.gm.close",
-                                   "dslib.pdot"])
+                                   "dslib.gm.pass", "dslib.pdot"])
 def test_device_scope_is_in_an_op_name(scope):
     x = ds.random_array((64, D), random_state=0)
     text = profiling.op_graph(

@@ -123,7 +123,8 @@ SECTIONS = [
       "profiled_jit", "dispatch_count",
       "trace_count", "transfer_count", "counters", "reset_counters",
       "count_resilience", "resilience_counters",
-      "count_schedule", "schedule_counters"]),
+      "count_schedule", "schedule_counters",
+      "program_scopes", "clear_programs"]),
     ("Distributed (multi-host)", "dislib_tpu.parallel.distributed",
      ["initialize", "is_initialized", "process_info", "shutdown"]),
 ]
