@@ -334,8 +334,9 @@ def phase_mixture(run):
                                   **start).fit(x)
     gm, first_s = _wall(fit)
     _, warm_s = _wall(fit)
-    steps, e_steps, m_steps = (_routes(prof, key) for key in
-                               ("gm_step", "gm_e_step", "gm_m_step"))
+    steps, e_steps, m_steps, moments = (
+        _routes(prof, key) for key in
+        ("gm_step", "gm_e_step", "gm_m_step", "gm_m_moments"))
 
     want = (start["weights_init"].astype(np.float64),
             start["means_init"].astype(np.float64),
@@ -380,6 +381,7 @@ def phase_mixture(run):
                 float(np.abs(gm.covariances_ - want[2]).max()),
             "predict_agreement": agree,
             "gm_step": steps, "gm_e_step": e_steps, "gm_m_step": m_steps,
+            "gm_m_moments": moments,
             "collectives": collectives,
             "peak_balance_after_fit": _balanced(run.peak_bytes(),
                                                 "after the mixture fit")}

@@ -359,8 +359,10 @@ def _gm_fit(xp, shape, k, cov_type, reg_covar, tol, max_iter,
     _count_schedule("gm_e_step", "triangle" if _ops.em_cuts(cov_type, xp.dtype)
                     else "whole")
     if cov_type == "full":
-        _count_schedule("gm_m_step", "packed" if _ops.em_packs(n, xp.dtype)
-                        else "six_pass")
+        packs = _ops.em_packs(n, xp.dtype)
+        _count_schedule("gm_m_step", "packed" if packs else "six_pass")
+        # the second moments' upper triangle alone, mirrored, where packed
+        _count_schedule("gm_m_moments", "upper" if packs else "whole")
     weights0, means0, covs0 = overrides
     if start is not None:
         # the first parameters, where the caller gave not all three: an

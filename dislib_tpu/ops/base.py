@@ -377,17 +377,47 @@ def _em_log_prob(xb, wh: Whitener):
 def em_packs(d: int, dtype) -> bool:
     """Whether the M-step of full covariances packs its product
     (:func:`precision.pdot_tall`, d + 1 columns wide): what a trace can
-    observe, and what decides how a block's wide operand is laid out."""
+    observe, and what decides how a block's wide operand is laid out and
+    whether its second moments are cut along their symmetry
+    (:func:`em_moment_groups`)."""
     return px.packs_tall(d + 1, dtype)
+
+
+# Whole MXU tiles of the wide operand's columns (p, j) in one group of
+# :func:`em_moment_groups`: two won over one at d = 50, k = 16 (PERF.md,
+# PR 39: a product's cost has a floor that goes with its wide side).
+_MOMENT_TILES = 2
+
+
+def em_moment_groups(d: int, k: int, dtype) -> tuple:
+    """``((p0, p1), ...)``: how the full-covariance M-step cuts the d
+    values of p in the wide operand's columns (p, j) into groups, one
+    product (:func:`precision.pdot_tall`) each.
+
+    S_j[p, q] is symmetric, so the rows (p, j) of group ``[p0, p1)`` need
+    the narrow operand's columns q >= p0 alone (and its column of ones).
+    The product holds the wide operand in the MXU in tiles of
+    ``_MXU_COLUMNS`` of its columns and streams the narrow one's past
+    each: a group is ``_MOMENT_TILES`` whole tiles, ``128 / k`` values of p
+    a tile (d = 50, k = 16: [0, 16), [16, 32), [32, 48), [48, 50), 213
+    tile-columns for the whole product's 357).  Where the product is not
+    packed there is one group of all d, and the moments are taken whole."""
+    if not em_packs(d, dtype):
+        return ((0, d),)
+    span = max(_MOMENT_TILES * px._MXU_COLUMNS // k, 1)
+    return tuple((p0, min(p0 + span, d)) for p0 in range(0, d, span))
 
 
 def _em_zero_sums(k, d, cov_type, dtype):
     """Zeros shaped like the M-step's sums ``(nk, s, second)`` of
     :func:`_em_block_sums`."""
-    wide = d * k if em_packs(d, dtype) else k * (d + -d % 8)
-    second = {"full": (wide, d + 1), "tied": (d, d)}.get(cov_type, (k, d))
-    return (jnp.zeros((k,), dtype), jnp.zeros((k, d), dtype),
-            jnp.zeros(second, dtype))
+    if cov_type == "full" and em_packs(d, dtype):
+        second = tuple(jnp.zeros(((p1 - p0) * k, d - p0 + 1), dtype)
+                       for p0, p1 in em_moment_groups(d, k, dtype))
+    else:
+        second = jnp.zeros({"full": (k * (d + -d % 8), d + 1),
+                            "tied": (d, d)}.get(cov_type, (k, d)), dtype)
+    return (jnp.zeros((k,), dtype), jnp.zeros((k, d), dtype), second)
 
 
 def _em_block_sums(xc, w, resp, about, cov_type):
@@ -399,26 +429,32 @@ def _em_block_sums(xc, w, resp, about, cov_type):
     diagonals (diag, spherical) or, tied, the rows' own ``sum w x x^T``
     from which the sum over j follows.  ``resp`` (block, k) holds the
     weights already.  For full covariances the k weighted differences of
-    a block lie side by side and ONE product against the block's rows
+    a block lie side by side and a product against the block's rows
     contracts over the rows (:func:`precision.pdot_tall`).  Where that
-    packs, the differences are (block, d, k), unpadded since k fills
-    whole sublane tiles, and ONE fusion writes them once: the barrier
-    keeps the reshape below it, which XLA otherwise pulls up to the two
-    broadcasts and then writes each out as a tile of its own (36 ms of a
-    289 ms iteration, PERF.md, PR 30).  Elsewhere they are (block, k d8)
-    as the six-pass product takes them."""
+    packs, the differences are (block, p, k), unpadded since k fills
+    whole sublane tiles, one array a group of :func:`em_moment_groups`,
+    each against the block's columns q >= p0 and the ones: ``second`` is
+    a tuple of the groups' products.  ONE fusion writes the groups'
+    arrays once: the barrier keeps the reshape below it, which XLA
+    otherwise pulls up to the two broadcasts and then writes each out as
+    a tile of its own (36 ms of a 289 ms iteration, PERF.md, PR 30).
+    Elsewhere they are (block, k d8) as the six-pass product takes them,
+    in ONE product of all d + 1 columns."""
     nk = jnp.sum(resp, axis=0)
     if cov_type == "full":
-        if em_packs(xc.shape[1], xc.dtype):
-            wd = lax.optimization_barrier(
-                resp[:, None, :] * (xc[:, :, None] - about.T[None]))
-        else:
-            # columns padded as in :func:`em_whitener`, for the same reason
-            wd = resp[:, :, None] * (_pad8(xc)[:, None, :]
-                                     - _pad8(about)[None])
         x1 = jnp.concatenate([xc, jnp.ones_like(xc[:, :1])], axis=1)
-        return nk, jnp.zeros(about.shape, about.dtype), px.pdot_tall(
-            wd.reshape(xc.shape[0], -1), x1)
+        zero = jnp.zeros(about.shape, about.dtype)
+        if em_packs(xc.shape[1], xc.dtype):
+            groups = em_moment_groups(xc.shape[1], about.shape[0], xc.dtype)
+            wd = lax.optimization_barrier(tuple(
+                resp[:, None, :] * (xc[:, p0:p1, None] - about.T[None, p0:p1])
+                for p0, p1 in groups))
+            return nk, zero, tuple(
+                px.pdot_tall(part.reshape(xc.shape[0], -1), x1[:, p0:])
+                for part, (p0, _) in zip(wd, groups))
+        # columns padded as in :func:`em_whitener`, for the same reason
+        wd = resp[:, :, None] * (_pad8(xc)[:, None, :] - _pad8(about)[None])
+        return nk, zero, px.pdot_tall(wd.reshape(xc.shape[0], -1), x1)
     diff = xc[:, None, :] - about[None]
     wd = resp[:, :, None] * diff
     s = jnp.sum(wd, axis=0)
@@ -430,13 +466,26 @@ def _em_block_sums(xc, w, resp, about, cov_type):
 def _em_sums_about(sums, about, cov_type):
     """``(nk, s, S)`` with every moment about ``about``, from what the
     pass accumulated (full: the GEMM's products against the centred rows
-    and its column of ones; tied: the rows' own second moment)."""
+    and its column of ones; tied: the rows' own second moment).  Where
+    the full product was cut along the symmetry (:func:`em_moment_groups`)
+    a group's rows give S_j[p, q] for q >= p0 and s_j[p] for its p: the
+    upper triangle is taken from them and mirrored, so S_j is exactly
+    symmetric."""
     nk, s, second = sums
     k, d = about.shape
     if cov_type == "full":
-        g = jnp.swapaxes(second.reshape(d, k, d + 1), 0, 1) \
-            if em_packs(d, second.dtype) \
-            else second.reshape(k, -1, d + 1)[:, :d]
+        if isinstance(second, tuple):
+            groups = em_moment_groups(d, k, about.dtype)
+            rows = [jnp.swapaxes(part.reshape(p1 - p0, k, d - p0 + 1), 0, 1)
+                    for part, (p0, p1) in zip(second, groups)]
+            s = jnp.concatenate([r[:, :, -1] for r in rows], axis=1)
+            g = jnp.concatenate([
+                jnp.pad(r[:, :, :-1], ((0, 0), (0, 0), (p0, 0)))
+                for r, (p0, _) in zip(rows, groups)], axis=1)
+            upper = g - s[:, :, None] * about[:, None, :]
+            return nk, s, jnp.where(jnp.triu(jnp.ones((d, d), bool)),
+                                    upper, jnp.swapaxes(upper, 1, 2))
+        g = second.reshape(k, -1, d + 1)[:, :d]
         s = g[:, :, d]
         return nk, s, g[:, :, :d] - s[:, :, None] * about[:, None, :]
     if cov_type == "tied":

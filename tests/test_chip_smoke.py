@@ -63,6 +63,8 @@ def test_rehearsal_runs_every_phase_stamped(tmp_path):
     assert by["mixture"]["gm_e_step"] == ["whole"]
     # its M-step's product is XLA's own six passes here; a TPU reads "packed"
     assert by["mixture"]["gm_m_step"] == ["six_pass"]
+    # ... and its second moments are taken whole; a TPU reads "upper"
+    assert by["mixture"]["gm_m_moments"] == ["whole"]
     assert by["mixture"]["collectives"] == {"all-reduce": 1}
     assert by["mixture"]["predict_agreement"] >= 0.9999
     # the randomized SVD's panels take the Householder tree here; a TPU

@@ -231,16 +231,30 @@ def packing(monkeypatch):
     jax.clear_caches()                  # no packed program is left behind
 
 
+@pytest.fixture
+def small_mxu(monkeypatch):
+    """An MXU six columns wide, so that the M-step's wide operand at d = 6,
+    k = 3 (18 columns (p, j)) fills more than one of its tiles and its
+    product is cut in more than one group of p (the E-step's groups stay
+    one: a factor's 6 columns are one chunk)."""
+    from dislib_tpu.ops import precision as px
+    monkeypatch.setattr(px, "_MXU_COLUMNS", 6)
+
+
 @pytest.mark.parametrize("devices", [1, 8])
 def test_the_fit_with_the_packed_products_agrees_with_the_reference(
-        small_blocks, packing, devices):
+        small_blocks, packing, small_mxu, devices):
     _mesh_of(devices)
+    assert len(_ops.em_moment_groups(D, K, np.dtype(np.float32))) > 1
     x, mu = _data(3)
     start, covs0 = _start(mu, "full")
     profiling.reset_counters()
     gm = _fit(x, start, "full", 10)
     assert profiling.schedule_counters()["gm_m_step:packed"] == 1
+    assert profiling.schedule_counters()["gm_m_moments:upper"] == 1
     assert profiling.schedule_counters()["gm_e_step:triangle"] == 1
+    assert np.array_equal(gm.covariances_,
+                          np.swapaxes(gm.covariances_, 1, 2))
     xr, block = _reference_rows(x)
     ref_start = (start[0], start[1], covs0)
     w, m, c, hist = ref.fit(xr, ref_start, 10, block)
@@ -261,11 +275,11 @@ def _start_sums(x, about, labels):
 
 @pytest.mark.parametrize("devices", [1, 8])
 def test_the_start_through_the_packed_product_gives_its_present_answers(
-        small_blocks, monkeypatch, devices):
+        small_blocks, small_mxu, monkeypatch, devices):
     """``em_start`` runs the M-step's sums without an E-step: the packed
-    product and the wide operand in its other order give what the
-    six-pass one gives, and that is the float64 sums of the labelled
-    rows."""
+    product, cut in groups of p along the moments' symmetry, and the wide
+    operand in its other order give what the six-pass one gives, and that
+    is the float64 sums of the labelled rows."""
     from dislib_tpu.ops import precision as px
     _mesh_of(devices)
     x, mu = _data(17)
@@ -273,6 +287,7 @@ def test_the_start_through_the_packed_product_gives_its_present_answers(
     about = mu + 0.25
     plain = _start_sums(x, about, labels)
     monkeypatch.setattr(px, "_PACK_BACKENDS", (jax.default_backend(),))
+    assert len(_ops.em_moment_groups(D, K, np.dtype(np.float32))) > 1
     jax.clear_caches()
     try:
         packed = _start_sums(x, about, labels)
@@ -286,6 +301,90 @@ def test_the_start_through_the_packed_product_gives_its_present_answers(
         assert got.shape == was.shape == truth.shape
         assert np.linalg.norm(got - was) <= 2e-6 * np.linalg.norm(truth)
         assert np.linalg.norm(got - truth) <= 2e-6 * np.linalg.norm(truth)
+
+
+# -- (a'') the M-step cut along the second moments' symmetry ------------------
+
+def _block_moments(x, about, resp):
+    """``(nk, s, S)`` of one block's M-step sums about ``about``, as the
+    backend at hand takes them."""
+    f32 = [jnp.asarray(a, jnp.float32) for a in (x, about, resp)]
+    return [np.asarray(v) for v in jax.jit(
+        lambda xc, a, r: _ops._em_sums_about(
+            _ops._em_block_sums(xc, None, r, a, "full"), a, "full"))(
+        f32[0], f32[1], f32[2])]
+
+
+@pytest.mark.parametrize("k", [1, 3, 16])
+@pytest.mark.parametrize("d", [8, 16, 17, 50, 64, 100])
+def test_the_cut_m_step_is_the_whole_one(monkeypatch, d, k):
+    """The full-covariance M-step's sums cut along the symmetry of S_j (a
+    product a group of p, each against the columns q >= p0, the upper
+    triangle mirrored: what a backend that packs runs) against the ONE
+    product of every other backend and against float64: the same six
+    products of the same parts, so both within float32 rounding of the
+    truth; S exactly symmetric where the cut engages; and where the
+    product does not pack (d + 1 of 101 columns) one product, as before."""
+    from dislib_tpu.ops import precision as px
+    rng = np.random.RandomState(10 * d + k)
+    x = rng.randn(700, d).astype(np.float32)
+    about = (0.5 * rng.randn(k, d)).astype(np.float32)
+    resp = rng.rand(700, k).astype(np.float32)
+    diff = x.astype(np.float64)[:, None, :] - about.astype(np.float64)[None]
+    r64 = resp.astype(np.float64)
+    want = (r64.sum(0), np.einsum("bj,bjp->jp", r64, diff),
+            np.einsum("bj,bjp,bjq->jpq", r64, diff, diff))
+    whole = _block_moments(x, about, resp)
+    f32 = np.dtype(np.float32)
+    assert _ops.em_moment_groups(d, k, f32) == ((0, d),)
+    monkeypatch.setattr(px, "_PACK_BACKENDS", (jax.default_backend(),))
+    groups = _ops.em_moment_groups(d, k, f32)
+    cut = _block_moments(x, about, resp)
+    for got, was, truth in zip(cut, whole, want):
+        assert got.shape == was.shape == truth.shape
+        assert np.linalg.norm(got - truth) <= 2e-6 * np.linalg.norm(truth)
+        assert np.linalg.norm(got - was) <= 2e-6 * np.linalg.norm(truth)
+    if _ops.em_packs(d, f32):
+        assert np.array_equal(cut[2], np.swapaxes(cut[2], 1, 2))
+        assert len(groups) == -(-d // max(
+            _ops._MOMENT_TILES * px._MXU_COLUMNS // k, 1))
+    else:
+        assert d == 100 and groups == ((0, d),)
+        assert all(np.array_equal(g, w) for g, w in zip(cut, whole))
+
+
+def test_the_m_step_groups_follow_the_mxu_tiles(monkeypatch):
+    """The rule, from the shapes alone: groups of p that tile [0, d), each
+    as many p as fill ``_MOMENT_TILES`` whole tiles of the wide operand's
+    (p, j) columns and starting where the one before ended; a group's
+    product is (its p times k, d - p0 + 1): the narrow operand's columns
+    from its own p0 on, and the ones.  The cell's cut is pinned."""
+    from dislib_tpu.ops import precision as px
+    f32 = np.dtype(np.float32)
+    monkeypatch.setattr(px, "_PACK_BACKENDS", (jax.default_backend(),))
+    tile = _ops._MOMENT_TILES * px._MXU_COLUMNS
+    for d, k in [(8, 1), (17, 3), (50, 16), (64, 16), (50, 48), (50, 200),
+                 (84, 5)]:
+        groups = _ops.em_moment_groups(d, k, f32)
+        assert groups[0][0] == 0 and groups[-1][1] == d
+        assert all(a[1] == b[0] for a, b in zip(groups, groups[1:]))
+        span = groups[0][1] - groups[0][0]
+        assert span == min(max(tile // k, 1), d)
+        assert all(p0 % span == 0 and p1 - p0 <= span for p0, p1 in groups)
+        shapes = [z.shape for z in _ops._em_zero_sums(k, d, "full", f32)[2]]
+        assert shapes == [((p1 - p0) * k, d - p0 + 1) for p0, p1 in groups]
+        made = jax.eval_shape(
+            lambda xc, r, a: _ops._em_block_sums(xc, None, r, a, "full"),
+            jax.ShapeDtypeStruct((512, d), jnp.float32),
+            jax.ShapeDtypeStruct((512, k), jnp.float32),
+            jax.ShapeDtypeStruct((k, d), jnp.float32))[2]
+        assert [m.shape for m in made] == shapes
+    assert _ops.em_moment_groups(50, 16, f32) == (
+        (0, 16), (16, 32), (32, 48), (48, 50))
+    # where the M-step's product does not pack, one group of all d
+    assert _ops.em_moment_groups(85, 16, f32) == ((0, 85),)
+    monkeypatch.setattr(px, "_PACK_BACKENDS", ())
+    assert _ops.em_moment_groups(50, 16, f32) == ((0, 50),)
 
 
 # -- (a') the E-step cut along the factors' triangle --------------------------
@@ -433,9 +532,11 @@ def test_the_block_loop_holds_one_product_a_group_and_no_split(packing):
         assert [line for line in inside if re.match(
             rf"\s*{re.escape(arg)} = {re.escape(right)}\S* parameter\(",
             line)], (right, product)
-    # a block's rows are split in the loop; the factors are not
+    # a block's rows are split in the loop; the factors are not (the
+    # M-step's wide operand, 16 p's of 16 components, is as wide as one)
     made = [line for line in inside
-            if " convert(" in line or " concatenate(" in line]
+            if (" convert(" in line or " concatenate(" in line)
+            and "dslib.gm.m_step" not in line]
     assert any(re.search(r"= bf16\[7680,", line) for line in made)
     assert not [line for line in made
                 if re.search(r"= bf16\[(\d+,)*(256|288)\]", line)], made
@@ -553,6 +654,8 @@ def test_the_counter_and_the_spans_of_one_fit():
     assert "gm_e_step:triangle" not in c["schedules"]
     assert c["schedules"]["gm_m_step:six_pass"] == 1
     assert "gm_m_step:packed" not in c["schedules"]
+    assert c["schedules"]["gm_m_moments:whole"] == 1
+    assert "gm_m_moments:upper" not in c["schedules"]
     spans = c["spans"]
     for name in ("dslib.gm.fit", "dslib.gm.init", "dslib.fitloop.run",
                  "dslib.fitloop.chunk", "dslib.fitloop.commit"):
@@ -568,25 +671,28 @@ def test_the_counter_and_the_spans_of_one_fit():
     assert profiling.schedule_counters()["gm_step:blocked"] == 1
     assert profiling.schedule_counters()["gm_e_step:whole"] == 1
     assert profiling.schedule_counters()["gm_m_step:six_pass"] == 1
+    assert profiling.schedule_counters()["gm_m_moments:whole"] == 1
 
 
 @pytest.mark.parametrize("cov_type", ["tied", "diag", "spherical"])
 def test_the_m_step_counter_counts_full_covariances_only(packing, cov_type):
-    """The other covariance types have no product to pack: their fit says
-    nothing of one, packing backend or not; a full fit there says
-    ``packed``, once a trace."""
+    """The other covariance types have no product to pack nor moments to
+    mirror: their fit says nothing of either, packing backend or not; a
+    full fit there says ``packed`` and ``upper``, once a trace."""
     ds.init((1, 1), devices=jax.devices()[:1])
     x, mu = _data(9, rows=2000)
     profiling.reset_counters()
     _fit(x, _start(mu, cov_type)[0], cov_type, 2)
     assert not [key for key in profiling.schedule_counters()
-                if key.startswith("gm_m_step:")]
+                if key.startswith(("gm_m_step:", "gm_m_moments:"))]
     # nor a triangle to cut: their E-step is whole, packed or not
     assert profiling.schedule_counters()["gm_e_step:whole"] == 1
     for _ in range(2):
         _fit(x, _start(mu, "full")[0], "full", 2)
     assert profiling.schedule_counters()["gm_m_step:packed"] == 1
     assert "gm_m_step:six_pass" not in profiling.schedule_counters()
+    assert profiling.schedule_counters()["gm_m_moments:upper"] == 1
+    assert "gm_m_moments:whole" not in profiling.schedule_counters()
     assert profiling.schedule_counters()["gm_e_step:triangle"] == 1
     assert profiling.schedule_counters()["gm_e_step:whole"] == 1
 

@@ -626,38 +626,26 @@ class TestCompiledTallOrthonormalisation:
             mem.temp_size_in_bytes
 
 
-class TestCompiledMixtureEStep:
-    """What the TPU's compiler makes of the mixture's cut E-step
-    (``ops/base.py::_em_log_prob`` over ``precision.short_left``,
-    ``short_right``, ``pdot_packed``), at the widths of the cell
-    ``gmm_fit_sustained`` (it lives here for the fixture above)."""
-
-    def test_tpu_block_loop_has_three_products_and_no_split_of_the_factors(
-            self, describe_v5e, monkeypatch):
-        """``_gm_fit`` for a described v5e, 8 blocks of 7 680 rows, d =
-        50, k = 16.  In the loop over the blocks: three E-step products
-        and no more, plain GEMMs whose result is the whole (block, e k8)
-        array (not the convolution over e that the compiler folds a
-        group's sum into where nothing stands in its way), each against
-        an operand the loop carries (nothing in the loop writes anything
-        shaped like one: the factors are split and packed once an
-        iteration); the block's rows split in ONE fusion, packed by six
-        that only move the parts, and copied once into the chunk-major
-        order the products read; and no more ops a block than the
-        one-GEMM program had (27)."""
-        import importlib
-        from jax.sharding import Mesh, NamedSharding, PartitionSpec
-        gm = importlib.import_module("dislib_tpu.cluster.gm")
-        topo = describe_v5e("v5e:2x2")
-        mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1),
-                    (_mesh.ROWS, _mesh.COLS))
-        # a described chip is not the default backend: say that it packs
-        monkeypatch.setattr(px, "_PACK_BACKENDS", (jax.default_backend(),))
-        was = _mesh.get_mesh()
+@pytest.fixture(scope="module")
+def mixture_fit_v5e(describe_v5e):
+    """``(text, groups)``: ``_gm_fit`` compiled for a described v5e at the
+    widths of the cell ``gmm_fit_sustained`` (8 blocks of 7 680 rows, d =
+    50, k = 16) and the M-step's groups of p there.  A described chip is
+    not the default backend: the test says that it packs."""
+    import importlib
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    gm = importlib.import_module("dislib_tpu.cluster.gm")
+    ops = importlib.import_module("dislib_tpu.ops.base")
+    topo = describe_v5e("v5e:2x2")
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1),
+                (_mesh.ROWS, _mesh.COLS))
+    was = _mesh.get_mesh()
+    m, d, k = 8 * 7_680, 50, 16
+    rows = NamedSharding(mesh, PartitionSpec(_mesh.ROWS, None))
+    whole = NamedSharding(mesh, PartitionSpec())
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(px, "_PACK_BACKENDS", (jax.default_backend(),))
         _mesh.set_mesh(mesh)
-        m, d, k = 8 * 7_680, 50, 16
-        rows = NamedSharding(mesh, PartitionSpec(_mesh.ROWS, None))
-        whole = NamedSharding(mesh, PartitionSpec())
         try:
             with _compile_cache_off():
                 text = gm._gm_fit.lower(
@@ -669,16 +657,52 @@ class TestCompiledMixtureEStep:
                      jax.ShapeDtypeStruct((k, d, d), jnp.float32,
                                           sharding=whole))
                 ).compile().as_text()
+            groups = ops.em_moment_groups(d, k, np.dtype(np.float32))
         finally:
             _mesh.set_mesh(was)
             jax.clear_caches()          # no packed program is left behind
+    return text, groups
+
+
+def _block_loop_ops(text):
+    """``[(name, shape, opcode, line), ...]`` of the loop over the EM
+    blocks: the computation that holds the E-step's GEMMs."""
+    e_gemm = "dslib.gm.e_step/dslib.pdot/dot_general"
+    body = next(block for block in re.split(r"\n\}\n", text)
+                if re.search(rf" fusion\(.*kind=kOutput.*{e_gemm}", block))
+    ops = [_OP_RE.match(line) for line in body.splitlines()]
+    return [(m_.group(1), m_.group(2), m_.group(3), m_.string)
+            for m_ in ops if m_]
+
+
+def _device_ops(ops):
+    return [name for name, shape, op, _ in ops
+            if op in ("fusion", "copy", "slice", "convolution")
+            and not shape.startswith(("f32[]", "s32[]", "pred[]"))]
+
+
+class TestCompiledMixtureEStep:
+    """What the TPU's compiler makes of the mixture's cut E-step
+    (``ops/base.py::_em_log_prob`` over ``precision.short_left``,
+    ``short_right``, ``pdot_packed``), at the widths of the cell
+    ``gmm_fit_sustained`` (it lives here for the fixture above)."""
+
+    def test_tpu_block_loop_has_three_products_and_no_split_of_the_factors(
+            self, mixture_fit_v5e):
+        """``_gm_fit`` for a described v5e, 8 blocks of 7 680 rows, d =
+        50, k = 16.  In the loop over the blocks: three E-step products
+        and no more, plain GEMMs whose result is the whole (block, e k8)
+        array (not the convolution over e that the compiler folds a
+        group's sum into where nothing stands in its way), each against
+        an operand the loop carries (nothing in the loop writes anything
+        shaped like one: the factors are split and packed once an
+        iteration); the block's rows split in ONE fusion, packed by six
+        that only move the parts, and copied once into the chunk-major
+        order the products read; and no more ops a block outside the
+        M-step than the one-GEMM program had (21 of its 27)."""
+        text, _ = mixture_fit_v5e
         e_gemm = "dslib.gm.e_step/dslib.pdot/dot_general"
-        body = next(block for block in re.split(r"\n\}\n", text)
-                    if re.search(rf" fusion\(.*kind=kOutput.*{e_gemm}",
-                                 block))
-        ops = [_OP_RE.match(line) for line in body.splitlines()]
-        ops = [(m_.group(1), m_.group(2), m_.group(3), m_.string)
-               for m_ in ops if m_]
+        ops = _block_loop_ops(text)
         gemms = [name for name, _, op, line in ops
                  if op == "fusion" and "kind=kOutput" in line
                  and e_gemm in line]
@@ -700,10 +724,51 @@ class TestCompiledMixtureEStep:
         left = [(name, op) for name, shape, op, _ in ops
                 if shape.startswith("bf16[7680,4,6,16]")]
         assert [op for _, op in left] == ["fusion"] * 6 + ["copy"], left
-        device_ops = [name for name, shape, op, _ in ops
-                      if op in ("fusion", "copy", "slice", "convolution")
-                      and not shape.startswith(("f32[]", "s32[]", "pred[]"))]
-        assert len(device_ops) <= 27, device_ops
+        m_step = {name for name, _, _, line in ops
+                  if "dslib.gm.m_step" in line}
+        others = [name for name in _device_ops(ops) if name not in m_step]
+        assert len(others) <= 21, others
+
+
+class TestCompiledMixtureMStep:
+    """What the TPU's compiler makes of the mixture's M-step cut along the
+    symmetry of its second moments (``ops/base.py::_em_block_sums`` over
+    ``precision.pdot_tall``, a product a group of
+    :func:`em_moment_groups`), at the cell's widths."""
+
+    def test_tpu_block_loop_has_three_products_a_group_and_no_copy(
+            self, mixture_fit_v5e):
+        """In the loop over the blocks: three M-step GEMMs a group of p
+        and no more, group ``[p0, p1)``'s (rows (p, j), columns q >= p0
+        and the ones) a whole (p1 - p0) k by d - p0 + 1 sum; and the wide
+        operand's group arrays written by fusions that compute them, never
+        a ``copy`` or a (dynamic-)slice of a wider buffer."""
+        text, groups = mixture_fit_v5e
+        assert len(groups) > 1
+        ops = _block_loop_ops(text)
+        m_gemm = "dslib.gm.m_step/dslib.pdot/dot_general"
+        gemms = [(name, shape) for name, shape, op, line in ops
+                 if op == "fusion" and "kind=kOutput" in line
+                 and m_gemm in line]
+        assert len(gemms) == 3 * len(groups), gemms
+        d, k = 50, 16
+        have = sorted(re.search(r"f32\[\d+,\d+\]", shape).group(0)
+                      for _, shape in gemms)
+        assert have == sorted(
+            f"f32[{(p1 - p0) * k},{c * (d - p0 + 1)}]"
+            for p0, p1 in groups for c in (1, 2, 3)), have
+        # the wide operand's groups: (block, p1 - p0, k) arrays, made by
+        # fusions that compute them; nothing copies or slices one out
+        for p0, p1 in groups:
+            piece = f"f32[7680,{p1 - p0},{k}]"
+            made = [(name, op) for name, shape, op, _ in ops
+                    if piece in shape and op not in (
+                        "get-tuple-element", "bitcast", "parameter", "tuple")]
+            assert made and all(op == "fusion" for _, op in made), made
+            assert not [name for name, _ in made
+                        if "copy" in name or "slice" in name], made
+        assert not [name for name, shape, op, _ in ops if op == "copy"
+                    and re.match(r"\(?f32\[7680,\d+,16\]", shape)]
 
 
 # ---------------------------------------------------------------------------
