@@ -96,10 +96,10 @@ def test_a_fourth_cell_and_a_tenth_metric_are_files_and_entries_only(tmp_path):
     assert man.traffic("product_few_rows")["check_rows"] == 256
     # the new cell reports its own metric and, with no edit anywhere, every
     # metric that moves its rate and lists no cells: the whole step's mfu,
-    # the dispatches, the idle share
-    assert {m["name"] for m in man.per_layer_of("matmul_16k_few_rows")} \
-        == {"pdot_few_rows_roofline", "matmul.step_mfu_pct",
-            "array.dispatches_per_product", "device.matmul_idle_pct"}
+    # the dispatches, the idle share (and any that a later PR adds so)
+    assert {"pdot_few_rows_roofline", "matmul.step_mfu_pct",
+            "array.dispatches_per_product", "device.matmul_idle_pct"} \
+        <= {m["name"] for m in man.per_layer_of("matmul_16k_few_rows")}
     assert {m["name"] for m in man.end_to_end_of("matmul_16k_few_rows")} \
         == {"setup_s", "matmul_tflops_per_chip"}
     _assert_untouched(before)
@@ -129,6 +129,14 @@ def test_the_added_cell_runs_through_the_harness(tmp_path):
     _assert_untouched(before)
 
     import time
+
+    import jax
+    from dislib_tpu.utils import profiling
+    # the catalogue of compiled programs as the cell's own process has it:
+    # an instruction name that an earlier test's program places elsewhere
+    # would count toward no scope and quiet the scope metrics
+    profiling.clear_programs()
+    jax.clear_caches()
     ctx = harness.open_cell(root, "matmul_tiny_steady", seed=2_400_000_077,
                             seconds=0.2, trace=True, rehearsal=True)
     t0 = time.perf_counter()
@@ -136,11 +144,11 @@ def test_the_added_cell_runs_through_the_harness(tmp_path):
                                [("import_and_device_s", t0)])
     assert result["correct"] is True and result["attempted"] >= 1
     # the traced line carries every per-layer metric the manifest gives the
-    # new cell (pdot_roofline because its entry lists the cell, the others
-    # because they move its rate), and no reader found nothing
-    assert set(result["metrics"]) == {
-        "matmul.step_mfu_pct", "pdot_roofline",
-        "array.dispatches_per_product", "device.matmul_idle_pct"}
+    # new cell: pdot_roofline, and the one-chip cell's scope metrics, because
+    # their entries list the cell, the others because they move its rate
+    assert {"matmul.step_mfu_pct", "pdot_roofline",
+            "array.dispatches_per_product", "device.matmul_idle_pct"} \
+        <= set(result["metrics"])
     assert info["cell"] == "matmul_tiny_steady"
     assert info["silent_metrics"] == []
     _assert_untouched(before)

@@ -77,10 +77,10 @@ def test_the_new_entries_and_files_keep_the_rules():
     man = manifest.Manifest(ROOT)
     mine = {m["name"]: m for m in man.per_layer_of(CELL)}
     # its own four and, with no edit anywhere, the three that move the
-    # rate and list no cells
-    assert set(mine) == NEW_METRICS | {
+    # rate and list no cells; metrics appended later may list it too
+    assert NEW_METRICS | {
         "fit.step_mfu_pct", "fitloop.dispatches_per_iter",
-        "device.fit_idle_pct"}
+        "device.fit_idle_pct"} <= set(mine)
     for name in NEW_METRICS:
         assert mine[name]["workloads"] == [CELL]
         assert mine[name]["moves"] == "fit_iters_per_s"

@@ -125,6 +125,59 @@ def test_idle_gaps_are_named_by_the_innermost_host_span():
     assert rt.idle_gaps(tr) == [["no host span", pytest.approx(0.020)]]
 
 
+def _idle_gaps_by_scan(trace):
+    """What ``idle_gaps`` reads, the plain way: for each gap every span,
+    shortest first."""
+    dev = sorted(trace.ops)[0]
+    busy = rt.merge(rt.clip(trace.ops[dev], trace.t0, trace.t1))
+    host = sorted(trace.host, key=lambda e: e[2])
+    total = {}
+    for s, e in rt.subtract([(trace.t0, trace.t1)], busy):
+        mid = (s + e) / 2.0
+        name = next((hn for hn, hs, hd in host if hn != rt.WINDOW_SPAN
+                     and hs <= mid <= hs + hd), "no host span")
+        total[name] = total.get(name, 0.0) + (e - s)
+    return [[name, ns / 1e9] for name, ns in
+            sorted(total.items(), key=lambda kv: -kv[1])[:10]]
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_idle_gaps_name_each_gap_as_a_scan_of_every_span_would(seed):
+    """Nested spans, spans of equal length, spans that end or begin at a
+    gap's middle, gaps under no span: the sweep agrees with the scan."""
+    import random
+    rnd = random.Random(seed)
+    ops = []
+    t = 0
+    while t < 100 * MS:
+        d = rnd.choice([1, 2, 50, 1_000, 300_000])
+        ops.append(("f", t, d))
+        t += d + rnd.choice([1, 2, 3, 1_000, 2_000_000])
+    host = [(rt.WINDOW_SPAN, 0, 100 * MS)]
+    for j in range(400):
+        s = rnd.randrange(0, 100 * MS)
+        host.append((f"span{j % 37}", s, rnd.choice(
+            [1, 10, 1_000, 1_000_000, 5_000_000, 40_000_000])))
+    tr = _trace(ops={0: ops}, host=host)
+    assert len(rt.subtract([(0, 100 * MS)], rt.merge(rt.clip(
+        ops, 0, 100 * MS)))) > 100
+    assert rt.idle_gaps(tr) == _idle_gaps_by_scan(tr)
+
+
+def test_idle_gaps_take_one_sweep_over_many_gaps_and_spans():
+    # 100 000 gaps a nanosecond wide and 200 000 spans: a scan of the
+    # spans for each gap would make 10^10 comparisons
+    ops = [("f", 3 * i, 2) for i in range(100_000)]
+    host = [(rt.WINDOW_SPAN, 0, 300_000)] + [
+        (f"$frame{i % 5}", i, 7 + i % 3) for i in range(200_000)]
+    tr = rt.Trace(t0=0, t1=300_000, ops={0: ops}, host=host)
+    import time
+    t = time.perf_counter()
+    got = rt.idle_gaps(tr)
+    assert time.perf_counter() - t < 30
+    assert sum(s for _, s in got) == pytest.approx(100_000e-9)
+
+
 def test_a_while_spanning_its_body_is_left_out_by_the_loaders_pattern():
     import json
     import re
