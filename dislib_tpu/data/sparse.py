@@ -25,8 +25,10 @@ TPU-native design and its honest limits:
   * KMeans — native sparse path (`fit`/`predict` accept SparseArray; the
     distance cross-term and the per-cluster sums are `bcoo_dot_general`
     contractions).
-  * ALS — dense-with-mask (see `recommendation/als.py`: a zero rating IS
-    the mask; the normal-equation GEMMs need the dense mask anyway).
+  * ALS — sparse-native on a SparseArray (`recommendation/als.py`: the
+    row-sorted stream and its column-major order, `col_major`, laid out
+    in windows a length class at a time); dense-with-mask on an Array (a
+    zero rating IS the mask).
   * CascadeSVM — sparse-native: host-CSR-staged per-node sub-Grams feed
     the device dual solves; queries classify via one spmm cross-term
     (`classification/csvm.py`).
@@ -117,7 +119,8 @@ class ShardedSparse:
 
     __slots__ = ("data", "lrows", "cols", "_counts_dev", "counts",
                  "row_nnz", "shape", "mesh", "m_local", "nse", "_rowsq",
-                 "cols_host", "_pviews", "_ell", "_rsteps")
+                 "cols_host", "_pviews", "_ell", "_rsteps", "_col_counts",
+                 "plans")
 
     def __init__(self, data, lrows, cols, counts_dev, counts, row_nnz,
                  shape, mesh, cols_host=None):
@@ -137,6 +140,10 @@ class ShardedSparse:
         self._pviews = {}
         self._ell = None
         self._rsteps = {}
+        self._col_counts = None
+        # what an estimator derives from the layout and keeps with it
+        # (ALS's blocking of each order), keyed by the estimator
+        self.plans = {}
 
     @property
     def counts_dev(self):
@@ -360,6 +367,34 @@ class ShardedSparse:
                 self.mesh, tuple(plan), int(budget), self.m_local, starts)
         return self._rsteps[key]
 
+    # -- the column-major order of the same entries (ALS's item half-step) ---
+
+    def col_counts(self):
+        """Host int64 (p, n) per-shard live-entry histogram over the
+        columns: counted ON DEVICE and read once (p·n integers, never the
+        entry stream), cached.  With ``row_nnz`` it sizes every blocking
+        of either order without a look at the entries."""
+        if self._col_counts is None:
+            with _host_read():
+                self._col_counts = np.asarray(jax.device_get(
+                    _col_counts_kernel(self.cols, self.counts_dev, self.mesh,
+                                       self.shape[1])), np.int64)
+        return self._col_counts
+
+    def col_major(self):
+        """``(lrows, data)``, each (p, nse): every shard's live entries
+        sorted by column, stably (so rows ascend within a column), live
+        slots first and the pads (row 0, value 0) after them.  Column c of
+        shard s occupies slots ``[C[s, c], C[s, c] + col_counts()[s, c])``
+        with C the exclusive cumulative sum of :meth:`col_counts`, so the
+        column ids need no buffer of their own.  Built ON DEVICE in one
+        dispatch (one stable sort a shard).  Not cached: its consumer
+        keeps what it derives from it (ALS its windowed item layout, in
+        :attr:`plans`), and the sort's 2/3 of the entries' bytes would
+        otherwise stay beside that."""
+        return _col_major_kernel(self.data, self.lrows, self.cols,
+                                 self.counts_dev, self.mesh, self.shape[1])
+
 
 def _padded_rows(m, mesh):
     from dislib_tpu.data.array import _padded_shape
@@ -554,6 +589,44 @@ def _row_steps_kernel(data, lrows, cols, counts, mesh, plan, budget,
         check_vma=True,
     )(data, lrows, cols, counts)
     return (dta, lrl, ccl, jnp.asarray(row_off_np), jnp.asarray(rows_in_np))
+
+
+@partial(_pjit, static_argnames=("mesh", "n"), name="sparse_col_counts")
+def _col_counts_kernel(cols, counts, mesh, n):
+    from jax.sharding import PartitionSpec as P
+
+    def local(cc_s, cnt_s):
+        cc, cnt = cc_s[0], cnt_s[0]
+        live = jax.lax.broadcasted_iota(jnp.int32, cc.shape, 0) < cnt
+        return jax.ops.segment_sum(live.astype(jnp.int32), cc,
+                                   num_segments=n)[None]
+
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P(_mesh.ROWS), P(_mesh.ROWS)),
+                         out_specs=P(_mesh.ROWS), check_vma=True)(cols, counts)
+
+
+@partial(_pjit, static_argnames=("mesh", "n"), name="sparse_col_major")
+def _col_major_kernel(data, lrows, cols, counts, mesh, n):
+    """A shard's live entries sorted by column: ONE stable sort keyed on
+    the column, the pads keyed past the last column so that they stay at
+    the tail (their row and value zeroed: a poisoned pad cannot enter)."""
+    from jax.sharding import PartitionSpec as P
+
+    def local(d_s, lr_s, cc_s, cnt_s):
+        d, lr, cc, cnt = d_s[0], lr_s[0], cc_s[0], cnt_s[0]
+        with jax.named_scope("dslib.als.layout"):
+            live = jax.lax.broadcasted_iota(jnp.int32, cc.shape, 0) < cnt
+            keys = jnp.where(live, cc, n)
+            _, rows, vals = jax.lax.sort(
+                (keys, jnp.where(live, lr, 0),
+                 jnp.where(live, d, jnp.zeros((), d.dtype))),
+                num_keys=1, is_stable=True)
+        return rows[None], vals[None]
+
+    return jax.shard_map(local, mesh=mesh, in_specs=(P(_mesh.ROWS),) * 4,
+                         out_specs=(P(_mesh.ROWS),) * 2,
+                         check_vma=True)(data, lrows, cols, counts)
 
 
 class SparseArray:

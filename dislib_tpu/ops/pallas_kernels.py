@@ -436,3 +436,94 @@ def kmeans_step(x, x_sq, w, centers, block, chunk):
     )(x.T, lanes(x_sq), lanes(w), _pad2(centers, kp, d))
     return (jnp.sum(sums, axis=0)[:k], jnp.sum(cnt, axis=(0, 2))[:k],
             jnp.sum(inertia))
+
+
+# -- many small SPD systems at once: Cholesky and solve, a system a lane ----
+# (Appended at the end: the KMeans fit's program keeps its compile-cache key
+# only while the lines above stay where they are.)
+_CHOL_VMEM_LIMIT = 48 * 2 ** 20
+
+
+def chol_solve_lanes(a, b):
+    """``x`` (f, n) with ``A_t x_t = b_t`` for n symmetric positive
+    definite systems laid a system a lane: ``a`` (f, f, n), ``b`` (f, n).
+
+    XLA's batched Cholesky walks its columns in a loop whose every step
+    passes over the whole batch in HBM (13 µs a 100 x 100 system on the
+    v5e; PERF.md, section 6).  Here a block of 128 systems stays in VMEM for
+    the whole factorisation: step j reads column j, scales it by the root
+    of its diagonal, keeps it as L's column j in row j's place and takes
+    its outer product from the rows below; then one forward and one
+    backward substitution over the columns.  Every operation is
+    elementwise or a sum over sublanes, in float32 on the vector unit:
+    the MXU's passes play no part.  n is padded to whole blocks with
+    identity systems."""
+    f, _, n = a.shape
+    nb = -(-n // _LANES)
+    pad = nb * _LANES - n
+    if pad:
+        eye = jnp.broadcast_to(jnp.eye(f, dtype=a.dtype)[:, :, None],
+                               (f, f, pad))
+        a = jnp.concatenate([a, eye], axis=2)
+        b = jnp.pad(b, ((0, 0), (0, pad)))
+    a, b = _vary_alike(a, b)
+
+    def kern(a_ref, b_ref, x_ref):
+        @pl.when(pl.program_id(0) >= 0)     # see the module docstring
+        def _():
+            row = lax.broadcasted_iota(jnp.int32, (f, _LANES), 0)
+            lead = lax.broadcasted_iota(jnp.int32, (f, 1, 1), 0)
+
+            def at(v, j):                   # v[j] as (1, lanes)
+                return jnp.sum(jnp.where(row == j, v, 0.0), axis=0,
+                               keepdims=True)
+
+            def factor(j, carry):
+                col = a_ref[j]
+                lj = jnp.where(row >= j, col / jnp.sqrt(at(col, j)), 0.0)
+                a_ref[...] = jnp.where(lead > j, a_ref[...]
+                                       - lj[:, None, :] * lj[None, :, :],
+                                       a_ref[...])
+                a_ref[j] = lj
+                return carry
+
+            lax.fori_loop(0, f, factor, 0)
+
+            # both substitutions in place in x_ref: y, then x bottom up
+            x_ref[...] = b_ref[...]
+
+            def forward(j, carry):
+                lj, y = a_ref[j], x_ref[...]
+                yj = at(y, j) / at(lj, j)
+                x_ref[...] = jnp.where(row > j, y - lj * yj,
+                                       jnp.where(row == j, yj, y))
+                return carry
+
+            lax.fori_loop(0, f, forward, 0)
+
+            def backward(k, carry):
+                j = f - 1 - k
+                lj, x = a_ref[j], x_ref[...]
+                done = jnp.sum(jnp.where(row > j, lj * x, 0.0), axis=0,
+                               keepdims=True)
+                xj = (at(x, j) - done) / at(lj, j)
+                x_ref[...] = jnp.where(row == j, xj, x)
+                return carry
+
+            lax.fori_loop(0, f, backward, 0)
+
+    x = pl.pallas_call(
+        kern,
+        grid=(nb,),
+        in_specs=[pl.BlockSpec((f, f, _LANES), lambda i: (0, 0, i)),
+                  pl.BlockSpec((f, _LANES), lambda i: (0, i))],
+        out_specs=pl.BlockSpec((f, _LANES), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((f, nb * _LANES), a.dtype,
+                                       vma=jax.typeof(a).vma),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_CHOL_VMEM_LIMIT),
+        interpret=_interpret(),
+        name="dslib_chol_solve",
+    )(a, b)
+    return x[:, :n]

@@ -19,9 +19,15 @@ TPU-native redesign:
   is zero by invariant, so padded rows/cols solve to λI·x=0 → zero factors
   and never perturb the observed entries.
 - `SparseArray` ratings take a TRUE sparse path (`_als_fit_sparse`): the
-  normal equations are segment-sums over the observed (user, item, rating)
-  triplets — O(nnz·f²) work/memory, no densification — matching the
-  reference's CSR-block `_update_chunk` economics.
+  users and the items are sorted into length classes, each order of the
+  entries is laid out once per array in windows of its class's length
+  (`_plans`, cached on the ShardedSparse), and a block of segments' Grams
+  and moments are ONE batched product over its windows.  Users are solved
+  a block at a time (on a TPU a system a lane in VMEM,
+  `pallas_kernels.chol_solve_lanes`); the items' products are summed
+  over the shards and solved once whole.  O(nnz·f²) work, O(nnz) memory
+  for the two layouts beside the ratings, and a block's temporaries:
+  nothing of size (users, f, f) exists, no densification.
 - Convergence (|ΔRMSE| < tol, on train or held-out test ratings) is decided
   ON DEVICE inside the while_loop — host syncs once per fit, not per
   iteration (the reference syncs the RMSE scalar every iteration).
@@ -43,12 +49,18 @@ from dislib_tpu.base import BaseEstimator
 from dislib_tpu.data.array import Array, \
     ensure_canonical as _ensure_canonical
 from dislib_tpu.parallel import mesh as _mesh
+from dislib_tpu.ops import base as _ops
+from dislib_tpu.ops import precision as px
 from dislib_tpu.ops.base import precise
 from dislib_tpu.runtime import fetch as _fetch, repad_rows as _repad_rows
 from dislib_tpu.runtime import fitloop as _fitloop
 from dislib_tpu.runtime import health as _health
 from dislib_tpu.utils.dlog import verbose_logger
+from dislib_tpu.utils.profiling import count_schedule as _count_schedule
+from dislib_tpu.utils.profiling import new_call as _new_call, span as _span
 from dislib_tpu.utils.profiling import profiled_jit as _pjit
+
+_FIT = "dslib.als.fit"
 
 
 class ALS(BaseEstimator):
@@ -64,6 +76,13 @@ class ALS(BaseEstimator):
         Convergence threshold on |ΔRMSE| between iterations.
     max_iter : int, default 100
     random_state : int or None
+        Seeds the uniform draw of the item factors the first half-step
+        solves the users against, where ``items_init`` is None.
+    items_init : array (n_items, n_f) or None, default None
+        The item factors the first half-step solves the users against (the
+        fit's start; Zhou et al. start from each item's mean rating in
+        column 0 and small random numbers elsewhere).  None: a uniform
+        draw from ``random_state``.
     verbose : bool — log per-chunk RMSE under the dslib.als logger.
     arity : int — accepted and ignored (reference reduction-tree fan-in;
         reduction topology is XLA's job now).
@@ -79,7 +98,8 @@ class ALS(BaseEstimator):
     """
 
     def __init__(self, n_f=8, lambda_=0.065, tol=1e-4, max_iter=100,
-                 random_state=None, verbose=False, arity=48):
+                 random_state=None, verbose=False, arity=48,
+                 items_init=None):
         self.n_f = n_f
         self.lambda_ = lambda_
         self.tol = tol
@@ -87,6 +107,7 @@ class ALS(BaseEstimator):
         self.random_state = random_state
         self.verbose = verbose
         self.arity = arity
+        self.items_init = items_init
 
     def fit(self, x: Array, test=None, checkpoint=None, health=None):
         """Factorise the ratings matrix ``x`` (users × items, 0 = unobserved).
@@ -109,147 +130,159 @@ class ALS(BaseEstimator):
         ``lambda_`` per restart (the normal-equation ridge — ALS's
         damping knob against ill-conditioned solves).
         """
-        if self.max_iter < 1:
-            raise ValueError("max_iter must be >= 1")
-        from dislib_tpu.data.sparse import SparseArray
-        sparse_in = isinstance(x, SparseArray)
-        t_host = None
-        if not sparse_in and test is not None:
-            import scipy.sparse as sp
-            if isinstance(test, SparseArray):
-                t_host = np.asarray(test.collect().toarray())
-            else:
-                t = test.collect() if isinstance(test, Array) else test
-                t_host = np.asarray(t.toarray() if sp.issparse(t) else t)
-            if t_host.shape != x.shape:
-                raise ValueError(f"test ratings shape {t_host.shape} != "
-                                 f"ratings shape {x.shape}")
-        seed = self.random_state if self.random_state is not None else 0
-        box = {"x": x, "lam": float(self.lambda_), "rmse": np.inf}
+        with _span(_FIT, call=_new_call()):
+            if self.max_iter < 1:
+                raise ValueError("max_iter must be >= 1")
+            from dislib_tpu.data.sparse import SparseArray
+            sparse_in = isinstance(x, SparseArray)
+            items0 = self._items_start(x.shape[1])
+            t_host = None
+            if not sparse_in and test is not None:
+                import scipy.sparse as sp
+                if isinstance(test, SparseArray):
+                    t_host = np.asarray(test.collect().toarray())
+                else:
+                    t = test.collect() if isinstance(test, Array) else test
+                    t_host = np.asarray(t.toarray() if sp.issparse(t) else t)
+                if t_host.shape != x.shape:
+                    raise ValueError(f"test ratings shape {t_host.shape} != "
+                                     f"ratings shape {x.shape}")
+            seed = self.random_state if self.random_state is not None else 0
+            box = {"x": x, "lam": float(self.lambda_), "rmse": np.inf}
 
-        def _bind_test():
-            if sparse_in:
-                # true sparse path: row-panel-sharded buffers for the
-                # ratings AND the held-out test entries — O(nnz) storage,
-                # no densification ever happens
-                box["rep"] = box["x"].sharded()
-                if "t_sa" not in box:
-                    box["t_sa"] = None if test is None \
-                        else _test_sparse(test, x.shape)
-                box["trep"] = box["rep"] if box["t_sa"] is None \
-                    else box["t_sa"].sharded()
-            else:
-                box["test_p"] = box["x"]._data if t_host is None \
-                    else _pad_like(t_host, box["x"])
-        _bind_test()
+            def _bind_test():
+                if sparse_in:
+                    # true sparse path: row-panel-sharded buffers for the
+                    # ratings AND the held-out test entries — O(nnz) storage,
+                    # no densification ever happens
+                    box["rep"] = box["x"].sharded()
+                    if "t_sa" not in box:
+                        box["t_sa"] = None if test is None \
+                            else _test_sparse(test, x.shape)
+                    box["trep"] = box["rep"] if box["t_sa"] is None \
+                        else box["t_sa"].sharded()
+                else:
+                    box["test_p"] = box["x"]._data if t_host is None \
+                        else _pad_like(t_host, box["x"])
+            _bind_test()
 
-        def rebind(mesh):
-            if mesh is None:            # pre-switch: force pending chains
+            def rebind(mesh):
+                if mesh is None:            # pre-switch: force pending chains
+                    if not sparse_in:
+                        box["x"].force()
+                    return
                 if not sparse_in:
-                    box["x"].force()
-                return
-            if not sparse_in:
-                box["x"] = _ensure_canonical(box["x"])
-            _bind_test()                # sparse: reps reshard ON DEVICE
-                                        # through the sparse rechunk router
+                    box["x"] = _ensure_canonical(box["x"])
+                _bind_test()                # sparse: reps reshard ON DEVICE
+                                            # through the sparse rechunk router
 
-        log = verbose_logger("als", self.verbose)
-        loop = _fitloop.ChunkedFitLoop(
-            "als", checkpoint=checkpoint, health=health,
-            max_iter=self.max_iter, carry_names=("users", "items"),
-            carry_shapes=((x.shape[0], int(self.n_f)),
-                          (x.shape[1], int(self.n_f))),
-            # snapshots carry the LOGICAL factor dims (m, n) as scalars;
-            # the stored factor ROWS may be padded for a different mesh
-            # (elastic resume re-pads), so only the factor width is pinned
-            snapshot_expect={"m": int(x.shape[0]), "n": int(x.shape[1]),
-                             "users": (None, int(self.n_f)),
-                             "items": (None, int(self.n_f))},
-            elastic=rebind)
+            log = verbose_logger("als", self.verbose)
+            loop = _fitloop.ChunkedFitLoop(
+                "als", checkpoint=checkpoint, health=health,
+                max_iter=self.max_iter, carry_names=("users", "items"),
+                carry_shapes=((x.shape[0], int(self.n_f)),
+                              (x.shape[1], int(self.n_f))),
+                # snapshots carry the LOGICAL factor dims (m, n) as scalars;
+                # the stored factor ROWS may be padded for a different mesh
+                # (elastic resume re-pads), so only the factor width is pinned
+                snapshot_expect={"m": int(x.shape[0]), "n": int(x.shape[1]),
+                                 "users": (None, int(self.n_f)),
+                                 "items": (None, int(self.n_f))},
+                elastic=rebind)
 
-        def init(rem):
-            # ALS damping: the 'halve' tier raises the per-row ridge λ·n_u
-            # per attempt (ill-conditioned normal equations are the
-            # numeric failure mode of the batched Cholesky solves)
-            box["lam"] = float(self.lambda_) * rem.damping
-            box["rmse"] = np.inf
-            return _fitloop.LoopState(())   # fresh: the kernel seeds itself
+            def init(rem):
+                # ALS damping: the 'halve' tier raises the per-row ridge
+                # λ·n_u per attempt (ill-conditioned normal equations are
+                # the numeric failure mode of the batched Cholesky solves)
+                box["lam"] = float(self.lambda_) * rem.damping
+                box["rmse"] = np.inf
+                if sparse_in:
+                    from dislib_tpu.data.sparse import _padded_rows
+                    mesh = _mesh.get_mesh()
+                    return _fitloop.LoopState(_als_start(
+                        items0, int(seed), _padded_rows(x.shape[0], mesh),
+                        x.shape[1], int(self.n_f), mesh), extra=np.inf)
+                if items0 is not None:
+                    return _fitloop.LoopState(
+                        _dense_start(box["x"]._data, items0, int(self.n_f)),
+                        extra=np.inf)
+                # fresh: the kernel seeds itself
+                return _fitloop.LoopState(())
 
-        def restore(snap, rem):
-            # snapshot compatibility (logical dims + factor width) is
-            # declared via snapshot_expect and judged by the rollback
-            # funnel; elastic resume re-pads the factor rows for THIS
-            # mesh (runtime.repad_rows)
-            sm, sn = int(snap["m"]), int(snap["n"])
-            box["lam"] = float(self.lambda_) * rem.damping
-            box["rmse"] = float(snap["rmse"])
-            if sparse_in:
-                # the sharded kernel carries U padded to the CURRENT
-                # mesh's row quantum and V at its logical length
-                from dislib_tpu.data.sparse import _padded_rows
-                tu = _padded_rows(x.shape[0], _mesh.get_mesh())
-                tv = x.shape[1]
-            else:
-                tu = box["x"]._data.shape[0]
-                tv = box["x"]._data.shape[1]
-            return _fitloop.LoopState(
-                (jnp.asarray(rem.perturb(_repad_rows(snap["users"], sm, tu))),
-                 jnp.asarray(rem.perturb(_repad_rows(snap["items"], sn, tv)))),
-                it=int(snap["n_iter"]),
-                done=bool(snap.get("converged", False)),
-                extra=float(snap["rmse"]))
+            def restore(snap, rem):
+                # snapshot compatibility (logical dims + factor width) is
+                # declared via snapshot_expect and judged by the rollback
+                # funnel; elastic resume re-pads the factor rows for THIS
+                # mesh (runtime.repad_rows)
+                sm, sn = int(snap["m"]), int(snap["n"])
+                box["lam"] = float(self.lambda_) * rem.damping
+                box["rmse"] = float(snap["rmse"])
+                if sparse_in:
+                    # the sharded kernel carries U padded to the CURRENT
+                    # mesh's row quantum and V at its logical length
+                    from dislib_tpu.data.sparse import _padded_rows
+                    tu = _padded_rows(x.shape[0], _mesh.get_mesh())
+                    tv = x.shape[1]
+                else:
+                    tu = box["x"]._data.shape[0]
+                    tv = box["x"]._data.shape[1]
+                return _fitloop.LoopState(
+                    (jnp.asarray(rem.perturb(
+                        _repad_rows(snap["users"], sm, tu))),
+                     jnp.asarray(rem.perturb(
+                         _repad_rows(snap["items"], sn, tv)))),
+                    it=int(snap["n_iter"]),
+                    done=bool(snap.get("converged", False)),
+                    extra=float(snap["rmse"]))
 
-        def step(st, chunk):
-            state = (*st.carries, st.extra) if st.carries else None
-            if sparse_in:
-                rep, trep = box["rep"], box["trep"]
-                u, v, rmse_dev, n_done, conv, hist, hvec = _als_fit_sparse(
-                    rep.data, rep.lrows, rep.cols, rep.counts_dev,
-                    trep.data, trep.lrows, trep.cols, trep.counts_dev,
-                    x.shape[0], x.shape[1],
-                    int(self.n_f), box["lam"], float(self.tol),
-                    chunk, int(seed), _mesh.get_mesh(), init_state=state)
-            else:
-                u, v, rmse_dev, n_done, conv, hist, hvec = _als_fit(
-                    box["x"]._data, box["test_p"], x.shape, int(self.n_f),
-                    box["lam"], float(self.tol), chunk, int(seed),
-                    init_state=state)
+            def step(st, chunk):
+                state = (*st.carries, st.extra) if st.carries else None
+                if sparse_in:
+                    u, v, rmse_dev, n_done, conv, hist, hvec = _sparse_fit(
+                        box["rep"], box["trep"], state, box["lam"],
+                        float(self.tol), chunk)
+                else:
+                    u, v, rmse_dev, n_done, conv, hist, hvec = _als_fit(
+                        box["x"]._data, box["test_p"], x.shape, int(self.n_f),
+                        box["lam"], float(self.tol), chunk, int(seed),
+                        init_state=state)
 
-            def commit():
-                # deferred scalar syncs: the watchdogged hvec read stays
-                # the chunk's first force point
-                box["rmse"] = float(rmse_dev)
-                it = st.it + int(n_done)
-                log.info("iter %d: rmse=%.6g", it, box["rmse"])
-                return _fitloop.LoopState((u, v), it, bool(conv),
-                                          extra=box["rmse"])
+                def commit():
+                    # deferred scalar syncs: the watchdogged hvec read stays
+                    # the chunk's first force point
+                    box["rmse"] = float(rmse_dev)
+                    it = st.it + int(n_done)
+                    log.info("iter %d: rmse=%.6g", it, box["rmse"])
+                    return _fitloop.LoopState((u, v), it, bool(conv),
+                                              extra=box["rmse"])
 
-            return _fitloop.ChunkOutcome(
-                commit, hvec=hvec,
-                history=lambda: _fetch(hist)[: int(n_done)])
+                return _fitloop.ChunkOutcome(
+                    commit, hvec=hvec,
+                    history=lambda: _fetch(hist)[: int(n_done)])
 
-        def snapshot(st):
-            # the factors are DONATED to the next chunk's kernel call
-            # (their HBM is reused in place), so their device->host copies
-            # must land before that dispatch: fetch blocking, and offload
-            # only the checksum+write to the snapshot worker
-            return {"users": _fetch(st.carries[0]),
-                    "items": _fetch(st.carries[1]),
-                    "m": x.shape[0], "n": x.shape[1],
-                    "rmse": st.extra, "n_iter": st.it, "converged": st.done}
+            def snapshot(st):
+                # the factors are DONATED to the next chunk's kernel call
+                # (their HBM is reused in place), so their device->host copies
+                # must land before that dispatch: fetch blocking, and offload
+                # only the checksum+write to the snapshot worker
+                return {"users": _fetch(st.carries[0]),
+                        "items": _fetch(st.carries[1]),
+                        "m": x.shape[0], "n": x.shape[1],
+                        "rmse": st.extra, "n_iter": st.it,
+                        "converged": st.done}
 
-        st = loop.run(init=init, step=step, restore=restore,
-                      snapshot=snapshot)
-        u, v = st.carries
-        m, n = x.shape
-        self.users_ = np.asarray(jax.device_get(u))[:m]
-        self.items_ = np.asarray(jax.device_get(v))[:n]
-        self.rmse_ = float(box["rmse"])
-        self.n_iter_ = st.it
-        self.converged_ = st.done
-        self.history_ = np.asarray(loop.history, dtype=np.float64)
-        self.fit_info_ = loop.info
-        return self
+            st = loop.run(init=init, step=step, restore=restore,
+                          snapshot=snapshot)
+            u, v = st.carries
+            m, n = x.shape
+            self.users_ = np.asarray(_fetch(u))[:m]
+            self.items_ = np.asarray(_fetch(v))[:n]
+            self.rmse_ = float(box["rmse"])
+            self.n_iter_ = st.it
+            self.converged_ = st.done
+            self.history_ = np.asarray(loop.history, dtype=np.float64)
+            self.fit_info_ = loop.info
+            return self
 
     # async trial protocol (SURVEY §4.5): the no-test, no-checkpoint fit is
     # one jitted while_loop; the handle is its device output tuple.  Sparse
@@ -260,17 +293,21 @@ class ALS(BaseEstimator):
         from dislib_tpu.data.sparse import SparseArray
         seed = self.random_state if self.random_state is not None else 0
         if isinstance(x, SparseArray):
-            rep = x.sharded()
-            bufs = (rep.data, rep.lrows, rep.cols, rep.counts_dev)
-            out = _als_fit_sparse(*bufs, *bufs,
-                                  x.shape[0], x.shape[1], int(self.n_f),
-                                  float(self.lambda_), float(self.tol),
-                                  self.max_iter, int(seed),
-                                  _mesh.get_mesh())
+            from dislib_tpu.data.sparse import _padded_rows
+            rep, mesh = x.sharded(), _mesh.get_mesh()
+            start = _als_start(self._items_start(x.shape[1]), int(seed),
+                               _padded_rows(x.shape[0], mesh), x.shape[1],
+                               int(self.n_f), mesh)
+            out = _sparse_fit(rep, rep, (*start, np.inf),
+                              float(self.lambda_), float(self.tol),
+                              self.max_iter)
         else:
+            items0 = self._items_start(x.shape[1])
+            start = None if items0 is None else (
+                *_dense_start(x._data, items0, int(self.n_f)), np.inf)
             out = _als_fit(x._data, x._data, x.shape, int(self.n_f),
                            float(self.lambda_), float(self.tol),
-                           self.max_iter, int(seed))
+                           self.max_iter, int(seed), init_state=start)
         return (out, x.shape)
 
     def _fit_finalize(self, state):
@@ -347,6 +384,33 @@ class ALS(BaseEstimator):
         if not hasattr(self, "users_"):
             raise RuntimeError("ALS is not fitted")
 
+    def _items_start(self, n_items):
+        """``items_init`` checked against the ratings' items, as a float32
+        device array; None where there is none."""
+        if self.items_init is None:
+            return None
+        v0 = np.asarray(self.items_init, np.float32)
+        if v0.shape != (n_items, int(self.n_f)):
+            raise ValueError(f"items_init has shape {v0.shape}, the fit "
+                             f"needs ({n_items}, {int(self.n_f)})")
+        return jnp.asarray(v0)
+
+
+def _sparse_fit(rep, trep, state, lambda_, tol, max_iter):
+    """One chunk of the sparse fit on the sharded ratings ``rep`` from
+    the carries ``state`` (U, V, previous RMSE); the RMSE over ``trep``'s
+    entries where it is another array (held-out ratings), else over the
+    training ratings.  Both layouts come from ``rep``'s cache (built at
+    the first fit of the array)."""
+    uc, ic, users, items = _plans(rep)
+    test = (trep.data, trep.lrows, trep.cols, trep.counts_dev)
+    # the iterations are an operand and the history's room a power of two
+    # of at least 4, so that fits of 1 to 4 iterations share one program
+    room = max(4, 1 << (int(max_iter) - 1).bit_length())
+    return _als_fit_sparse(users, items, test, state, rep.shape[1], lambda_,
+                           tol, int(max_iter), room, rep.mesh, uc, ic,
+                           trep is not rep)
+
 
 def _test_sparse(test, want_shape):
     """Held-out ratings → a SparseArray (0 = unobserved) whose sharded
@@ -394,6 +458,14 @@ def _fold_in_pack(ratings, n_items):
         cols[i, : hi - lo] = t.indices[lo:hi]
         vals[i, : hi - lo] = t.data[lo:hi]
     return jnp.asarray(cols), jnp.asarray(vals)
+
+
+def _dense_start(rp, items, n_f):
+    """``(U, V)`` a dense fit starts from given ``items``: U zero (the first
+    half-step solves it before it is read), V padded like ``rp``'s
+    columns."""
+    return (jnp.zeros((rp.shape[0], n_f), jnp.float32),
+            jnp.pad(items, ((0, rp.shape[1] - items.shape[0]), (0, 0))))
 
 
 def _pad_like(t: np.ndarray, x: Array):
@@ -472,107 +544,352 @@ def _als_fit(rp, test_p, shape, n_f, lambda_, tol, max_iter, seed,
     return u, v, cur, n_iter, conv, hist, hvec
 
 
-@partial(_pjit, static_argnames=("m", "n", "n_f", "max_iter", "mesh"),
+# -- the sparse fit: normal equations a block of segments at a time ---------
+# A segment is one user's run of the row-sorted entry stream, or one item's
+# run of the same entries in column order (ShardedSparse.col_major), on one
+# shard.  Segments go into length classes (_class_sizes), and each order
+# is laid out once per array (_plans, cached on the ShardedSparse): a
+# class's segments side by side, each in a window of S slots, its entries
+# first and pads after them.  A pad names the factor table's one row past
+# its end, a zero row appended at each half-step, so a block of B
+# segments of a class is one contiguous (B, S) slice of that layout, and
+# ONE batched product of [factor rows, rating] with itself gives every
+# segment's Gram A and moment b at once; the layout keeps each segment's
+# number of observed entries beside its id.  Users are solved a block at
+# a time into U: nothing of size (users, f, f) exists.  An item's product
+# is written into the (items, f + 1, f + 1) table, summed over the shards
+# by ONE psum, and solved once it is whole.
+_PIECE = 2048            # most entries one product contracts (PERF.md, s. 6)
+_BLOCK_ENTRIES = 1 << 18  # slots of a block
+_BLOCK_SEGMENTS = 2048   # most segments a block solves
+_RMSE_BLOCK = 1 << 19    # entries a block of a held-out RMSE pass reads
+# where a block's systems are solved a system a lane in VMEM
+# (pallas_kernels.chol_solve_lanes); XLA's batched Cholesky elsewhere
+_LANE_BACKENDS = ("tpu",)
+
+
+def _class_sizes(longest: int) -> tuple:
+    """Window lengths S of the length classes, up to the first that holds
+    ``longest`` entries: fine steps while a window is short (from 256 on
+    a segment wastes at most a third of its window), then whole products
+    of ``_PIECE`` entries, 2^j and 3·2^(j-1) of them."""
+    sizes = [16, 32, 64, 128, 256, 384, 512, 768, 1024, 1536, _PIECE]
+    j = 1
+    while sizes[-1] < longest:
+        sizes += [_PIECE << j, _PIECE * 3 << (j - 1)]
+        j += 1
+    return tuple(sizes)
+
+
+def _segment_plan(counts):
+    """``(classes, tables)``: the blocking of one order of the entries,
+    from the per-shard segment lengths ``counts`` (host (p, segments)).
+    ``classes`` is static, ``((S, B, blocks, seg_off), ...)`` for each
+    class that holds a segment on some shard; ``tables`` (p, 3, L) int32
+    hold, a class's ``blocks * B`` columns from ``seg_off`` on, each
+    segment's id, first slot in the stream and length, in id order
+    (padding columns: id = segments, so that their writes drop, length
+    0).  Every shard gets the same shapes: a class's blocks are the most
+    any shard needs."""
+    counts = np.asarray(counts, np.int64)
+    p, nseg = counts.shape
+    first = np.cumsum(counts, axis=1) - counts
+    sizes = _class_sizes(int(counts.max(initial=1)))
+    cls = np.searchsorted(sizes, counts)
+    classes, parts, seg_off = [], [], 0
+    for c, size in enumerate(sizes):
+        mine = [np.flatnonzero((cls[s] == c) & (counts[s] > 0))
+                for s in range(p)]
+        most = max(len(ids) for ids in mine)
+        if not most:
+            continue
+        b = min(_BLOCK_SEGMENTS, max(1, _BLOCK_ENTRIES // size), most)
+        blocks = -(-most // b)
+        tab = np.zeros((p, 3, blocks * b), np.int64)
+        tab[:, 0] = nseg
+        for s, ids in enumerate(mine):
+            tab[s, :, :len(ids)] = ids, first[s, ids], counts[s, ids]
+        classes.append((int(size), int(b), int(blocks), seg_off))
+        parts.append(tab)
+        seg_off += blocks * b
+    if not parts:
+        parts = [np.zeros((p, 3, 1), np.int64)]
+    return tuple(classes), np.concatenate(parts, axis=2).astype(np.int32)
+
+
+def _plans(rep):
+    """``(user_classes, item_classes, user_layout, item_layout)`` of the
+    sharded ratings ``rep``, built on the device at the first fit of the
+    array and cached with it.  A layout is ``(ids, observed, others,
+    vals)``: the segments' ids and numbers of observed entries a class
+    block at a time (1-D, a shard's part after another's), and for each
+    class the (blocks * B, S) windows of the other side's ids (items for
+    the users, local rows for the items; a pad names the row past the
+    table) and of the ratings, a shard's rows after another's.  Built from
+    the order's tables, which are then dropped, as is ``col_major()``:
+    what stays is the two windowed copies of the entries."""
+    if "als" not in rep.plans:
+        sh = jax.sharding.NamedSharding(rep.mesh,
+                                        jax.sharding.PartitionSpec(_mesh.ROWS))
+        users = rep.row_nnz
+        users = np.concatenate(
+            [users, np.zeros(rep.p * rep.m_local - users.shape[0], np.int64)])
+        ic, it = _segment_plan(rep.col_counts())
+        rows_t, vals_t = rep.col_major()
+        items = _layout(rows_t, vals_t, jax.device_put(it, sh), ic, rep.mesh,
+                        jnp.int32, rep.m_local)
+        del rows_t, vals_t
+        uc, ut = _segment_plan(users.reshape(rep.p, rep.m_local))
+        # item ids as int16 where they and the pad fit: the largest copy
+        # shrinks by a quarter
+        small = rep.shape[1] < np.iinfo(np.int16).max
+        users = _layout(rep.cols, rep.data, jax.device_put(ut, sh), uc,
+                        rep.mesh, jnp.int16 if small else jnp.int32,
+                        rep.shape[1])
+        rep.plans["als"] = (uc, ic, users, items)
+    return rep.plans["als"]
+
+
+@partial(_pjit, static_argnames=("classes", "mesh", "ids", "pad"),
+         name="als_layout")
+def _layout(other, vals, tab, classes, mesh, ids, pad):
+    """One order's windowed layout (see ``_plans``): a class block's
+    windows are ``B`` slices of ``S`` slots of the stream, cut at each
+    segment's first slot; a slot past the segment's length, or of a
+    rating of 0, holds (``pad``, 0)."""
+    from jax.sharding import PartitionSpec as P
+    span = max(cls[0] for cls in classes) if classes else 1
+
+    def local(o_s, v_s, t_s):
+        with jax.named_scope("dslib.als.layout"):
+            o = jnp.concatenate([o_s[0], jnp.zeros((span,), o_s.dtype)])
+            v = jnp.concatenate([v_s[0], jnp.zeros((span,), v_s.dtype)])
+            t = t_s[0]
+            seen = _ops.varying_like(jnp.zeros(t.shape[1:], v.dtype), t)
+            others, ratings = [], []
+            for size, b, blocks, seg_off in classes:
+                def body(i, out, size=size, b=b, seg_off=seg_off):
+                    bufs, seen = out
+                    _, first, length = lax.dynamic_slice_in_dim(
+                        t, seg_off + i * b, b, 1)
+
+                    def win(a):
+                        return jax.vmap(lambda s: lax.dynamic_slice(
+                            a, (s,), (size,)))(first)
+                    r = win(v)
+                    keep = (lax.iota(jnp.int32, size)[None, :]
+                            < length[:, None]) & (r != 0)
+                    got = (jnp.where(keep, win(o), pad),
+                           jnp.where(keep, r, 0))
+                    seen = lax.dynamic_update_slice_in_dim(
+                        seen, jnp.sum(keep, axis=1).astype(seen.dtype),
+                        seg_off + i * b, 0)
+                    return tuple(
+                        lax.dynamic_update_slice_in_dim(
+                            buf, blk.astype(buf.dtype), i * b, 0)
+                        for buf, blk in zip(bufs, got)), seen
+                bufs = tuple(_ops.varying_like(
+                    jnp.zeros((blocks * b, size), dt), t)
+                    for dt in (ids, v.dtype))
+                bufs, seen = lax.fori_loop(0, blocks, body, (bufs, seen))
+                others.append(bufs[0])
+                ratings.append(bufs[1])
+            return t[0], seen, tuple(others), tuple(ratings)
+
+    return jax.shard_map(local, mesh=mesh, in_specs=(P(_mesh.ROWS),) * 3,
+                         out_specs=(P(_mesh.ROWS),) * 4,
+                         check_vma=True)(other, vals, tab)
+
+
+def _with_zero_row(factor):
+    """``factor`` and one zero row after it: the row a layout's pads
+    name."""
+    return jnp.concatenate([factor, jnp.zeros((1, factor.shape[1]),
+                                              factor.dtype)])
+
+
+def _block_grams(layout, k, cls, i, factor):
+    """``(ids, n, g)``: the ``i``-th block of class ``cls``, the ``k``-th
+    of a layout: its segments' ids and numbers of observed entries, and
+    the (B, f + 1, f + 1) products of [factor rows, rating] with
+    themselves (``factor`` ends in the zero row a pad names).  A window
+    longer than ``_PIECE`` is contracted a piece at a time: a float32
+    product that contracts many same-signed terms reads low on the chip,
+    so the pieces are added after an ``optimization_barrier`` (which keeps
+    XLA from folding them back into one product) in compensated sums."""
+    size, b, _, seg_off = cls
+    ids, seen, others, vals = layout
+    ids = lax.dynamic_slice_in_dim(ids, seg_off + i * b, b)
+    n = lax.dynamic_slice_in_dim(seen, seg_off + i * b, b)
+    o = lax.dynamic_slice_in_dim(others[k], i * b, b)
+    r = lax.dynamic_slice_in_dim(vals[k], i * b, b)
+    rows = jnp.take(factor, o, axis=0, mode="clip")
+    x = jnp.concatenate([rows, r[..., None].astype(rows.dtype)], axis=-1)
+    piece = min(size, _PIECE)
+    x = x.reshape(b, size // piece, piece, x.shape[-1])
+    g = px.peinsum("bpsf,bpsg->bpfg", x, x, px.FLOAT32)
+    if size == piece:
+        return ids, n, g[:, 0]
+    g = lax.optimization_barrier(g)
+
+    def add(j, carry):
+        total, lost = carry
+        part = g[:, j] - lost
+        grown = total + part
+        return grown, (grown - total) - part
+
+    zero = jnp.zeros_like(g[:, 0])
+    total, lost = lax.fori_loop(0, size // piece, add, (zero, zero))
+    return ids, n, total - lost
+
+
+def _solve_normal(g, n, lambda_, n_f):
+    """Factors from a stack of (f + 1, f + 1) products and the segments'
+    numbers of observed entries ``n``: the weighted-λ ridge λ·max(n, 1)
+    on A, then a Cholesky solve against b.  A segment with no observation
+    gets A = λ·I and b = 0: a zero factor."""
+    a = g[:, :n_f, :n_f]
+    reg = lambda_ * jnp.maximum(n, 1.0)
+    a = a + reg[:, None, None] * jnp.eye(n_f, dtype=g.dtype)
+    b = g[:, :n_f, n_f]
+    if jax.default_backend() in _LANE_BACKENDS:
+        from dislib_tpu.ops.pallas_kernels import chol_solve_lanes
+        return chol_solve_lanes(jnp.transpose(a, (1, 2, 0)), b.T).T
+    chol = jax.scipy.linalg.cho_factor(a)
+    return jax.scipy.linalg.cho_solve(chol, b[..., None])[..., 0]
+
+
+def _user_step(layout, classes, v, lambda_, u):
+    """U from V, a block of users at a time, every shard its own users,
+    written over the last U in place: a user with no rating is never
+    written and keeps the zero factor every fit starts it with."""
+    n_f = v.shape[1]
+    vz = _with_zero_row(v)
+    with jax.named_scope("dslib.als.users"):
+        for k, cls in enumerate(classes):
+            def body(i, u, k=k, cls=cls):
+                with jax.named_scope("dslib.als.gram"):
+                    ids, n, g = _block_grams(layout, k, cls, i, vz)
+                with jax.named_scope("dslib.als.solve"):
+                    return u.at[ids].set(_solve_normal(g, n, lambda_, n_f),
+                                         mode="drop")
+            u = lax.fori_loop(0, cls[2], body, u)
+    return u
+
+
+def _item_step(layout, classes, u, lambda_, n, block=_BLOCK_SEGMENTS):
+    """``(V, sse, count)`` from U: every item's product and number of
+    observed entries on each shard's own entries, the shards' partial
+    sums added by ONE psum, then the n solves a block of items at a time,
+    each block's squared training error (``_train_sse``) summed beside
+    them."""
+    n_f = u.shape[1]
+    uz = _with_zero_row(u)
+    with jax.named_scope("dslib.als.items"):
+        acc = tuple(_ops.varying_like(jnp.zeros(shape, u.dtype), layout[0])
+                    for shape in ((n, n_f + 1, n_f + 1), (n,)))
+        for k, cls in enumerate(classes):
+            def body(i, acc, k=k, cls=cls):
+                with jax.named_scope("dslib.als.gram"):
+                    ids, cnt, g = _block_grams(layout, k, cls, i, uz)
+                    return (acc[0].at[ids].set(g, mode="drop"),
+                            acc[1].at[ids].set(cnt, mode="drop"))
+            acc = lax.fori_loop(0, cls[2], body, acc)
+        acc, seen = lax.psum(acc, _mesh.ROWS)
+        b = min(block, n)
+
+        def solve(i, carry):
+            v, sse, cnt = carry
+            at = jnp.minimum(i * b, n - b)      # a ragged last block ends
+            g = lax.dynamic_slice_in_dim(acc, at, b)   # with the items
+            nb = lax.dynamic_slice_in_dim(seen, at, b)
+            with jax.named_scope("dslib.als.solve"):
+                vb = _solve_normal(g, nb, lambda_, n_f)
+            fresh = (at + lax.iota(jnp.int32, b)) >= i * b
+            e, c = _train_sse(vb, g, nb, fresh)
+            return (lax.dynamic_update_slice_in_dim(v, vb, at, 0),
+                    sse + e, cnt + c)
+
+        zero = jnp.zeros((), u.dtype)
+        return lax.fori_loop(0, -(-n // b), solve,
+                             (jnp.zeros((n, n_f), u.dtype), zero, zero))
+
+
+def _train_sse(v, g, n, fresh):
+    """Squared training error and count of a block of items from their
+    products: an item's sum of squared errors is Σr² - 2 v·b + vᵀA v over
+    its own entries, the numbers of its product with U; no entry is read
+    again.  ``fresh`` masks the items an earlier block has counted."""
+    n_f = v.shape[1]
+    with jax.named_scope("dslib.als.rmse"):
+        av = px.peinsum("nfg,ng->nf", g[:, :n_f, :n_f], v, px.FLOAT32)
+        se = g[:, n_f, n_f] + jnp.sum(v * (av - 2.0 * g[:, :n_f, n_f]),
+                                      axis=1)
+        w = fresh.astype(v.dtype)
+        return jnp.sum(w * se), jnp.sum(w * n)
+
+
+def _heldout_rmse(u, v, td, tlr, tcc, tcnt):
+    """RMSE over a shard's observed entries of held-out ratings, in blocks
+    whose partial sums add up compensated, then ONE psum."""
+    with jax.named_scope("dslib.als.rmse"):
+        def body(d, w, _start, lr, cc):
+            ok = w * (d != 0).astype(d.dtype)
+            pred = jnp.sum(jnp.take(u, lr, axis=0, mode="clip")
+                           * jnp.take(v, cc, axis=0, mode="clip"), axis=1)
+            return jnp.sum(ok * (pred - d) ** 2), jnp.sum(ok)
+
+        zero = (jnp.zeros((), td.dtype),) * 2
+        se, cnt = _ops.local_row_sums(td, (tlr, tcc), 0, tcnt,
+                                      min(td.shape[0], _RMSE_BLOCK), body,
+                                      zero)
+        se = lax.psum(se, _mesh.ROWS)
+        cnt = lax.psum(cnt, _mesh.ROWS)
+        return jnp.sqrt(se / jnp.maximum(cnt, 1.0))
+
+
+# init_state is DONATED (as in _als_fit): U's and V's buffers are reused
+# in place by the fit's output
+@partial(_pjit, static_argnames=("n", "room", "mesh", "user_classes",
+                                 "item_classes", "heldout"),
          donate_argnames=("init_state",), name="als_fit_sparse")
 @precise
-def _als_fit_sparse(data, lrows, cols, counts, tdata, tlrows, tcols, tcounts,
-                    m, n, n_f, lambda_, tol, max_iter, seed, mesh,
-                    init_state=None):
+def _als_fit_sparse(user_layout, item_layout, test, init_state, n, lambda_,
+                    tol, max_iter, room, mesh, user_classes, item_classes,
+                    heldout):
     """Sharded sparse ALS: ONE jitted ``shard_map`` over the row-sharded
-    :class:`~dislib_tpu.data.sparse.ShardedSparse` ratings buffers, the
-    whole while_loop inside (round-14 sparse PR — the fit rides the same
-    machinery as the SpMM fast path instead of the old replicated
-    single-program kernel).
+    ratings' two windowed layouts (``_plans``), the whole while_loop
+    inside.  DrJAX's per-shard-update + cross-shard-reduce decomposition
+    (arXiv:2403.07128): the USER half-step is shard-local (each shard
+    owns its users' entries; U stays row-sharded), and the ITEM half-step
+    is a shard-local partial product an item plus ONE ``psum`` over the
+    rows axis (V is the replicated small factor); so is the RMSE.
 
-    DrJAX's per-shard-update + cross-shard-reduce decomposition
-    (arXiv:2403.07128), literally: the USER half-step is fully
-    shard-local (each shard owns its users' entries, so their normal
-    equations — segment-sums of v_j v_jᵀ outer products streamed over nse
-    chunks, O(chunk·f²) peak — never leave the shard; U stays row-sharded
-    for the whole fit), and the ITEM half-step is a shard-local partial
-    A_i/b_i plus ONE ``psum`` over the rows axis (V is the replicated
-    small factor).  The convergence RMSE reduces the same way.  Per-shard
-    memory is O(nnz/p · f) + O(n·f²) — the factors of the paper-scale
-    recommender shard with the data.
+    A shard holds, beyond its entries in both layouts, U's (m/p, f) rows
+    and a copy of them with the pads' zero row, V and the items' (n,
+    f + 1, f + 1) products, and a block's temporaries:
+    ``_BLOCK_ENTRIES`` gathered factor rows and at most
+    ``_BLOCK_SEGMENTS`` (f, f) systems.  The convergence RMSE is over the
+    training ratings (from the items' products) or, where ``heldout``,
+    over ``test``'s observed entries (data, local rows, columns, live
+    count of a ShardedSparse; slots past the count are masked, so a
+    poisoned pad cannot enter)."""
+    _count_schedule("als_normal", "grouped")
+    _count_schedule("als_solve", "lanes" if jax.default_backend()
+                    in _LANE_BACKENDS else "xla")
 
-    Entry weights are ``(slot < count) & (value != 0)``: 0 = unobserved
-    (the dense-with-mask semantics) AND the nse pads — even poisoned
-    ones — carry weight zero (the slot mask, defense in depth over the
-    zero-value sentinel-column pad discipline)."""
-    p = mesh.shape[_mesh.ROWS]
-    from dislib_tpu.data.sparse import _padded_rows
-    m_local = _padded_rows(m, mesh) // p
-    nse = data.shape[1]
-    nse_t = tdata.shape[1]
-    chunk = max(1, min(nse, _SPARSE_CHUNK, _SPARSE_BUDGET // (n_f * n_f)))
-    n_chunks = -(-nse // chunk)
-    pad = n_chunks * chunk - nse
-
-    def shard_fn(d_s, lr_s, cc_s, cnt_s, td_s, tlr_s, tcc_s, tcnt_s, u0_s,
-                 v0_r, prev_r):
-        d_e, lr, cc, cnt = d_s[0], lr_s[0], cc_s[0], cnt_s[0]
-        td, tlr, tcc, tcnt = td_s[0], tlr_s[0], tcc_s[0], tcnt_s[0]
-        slot_ok = lax.broadcasted_iota(jnp.int32, (nse,), 0) < cnt
-        w = (slot_ok & (d_e != 0)).astype(d_e.dtype)
-        # chunk-pad the entry stream (pads carry weight 0 → additive no-op)
-        d_p = jnp.pad(d_e * w, (0, pad))
-        lr_p = jnp.pad(lr, (0, pad))
-        cc_p = jnp.pad(cc, (0, pad))
-        w_p = jnp.pad(w, (0, pad))
-        tok = lax.broadcasted_iota(jnp.int32, (nse_t,), 0) < tcnt
-        tw = (tok & (td != 0)).astype(d_e.dtype)
-        eye = jnp.eye(n_f, dtype=d_e.dtype)
-
-        def solve(seg_c, other, idx_c, nseg, reduce_rows):
-            """Normal equations streamed over nse chunks; the item step
-            (``reduce_rows``) combines per-shard partials with one psum."""
-
-            def body(acc, cx):
-                sc, ic, vc, wc = cx
-                g = other[ic] * wc[:, None]           # pad rows → all-zero
-                b = jax.ops.segment_sum(vc[:, None] * g, sc,
-                                        num_segments=nseg)
-                outer = (g[:, :, None] * g[:, None, :]) \
-                    .reshape(chunk, n_f * n_f)
-                a = jax.ops.segment_sum(outer, sc, num_segments=nseg)
-                cnt_ = jax.ops.segment_sum(wc, sc, num_segments=nseg)
-                return (acc[0] + a, acc[1] + b, acc[2] + cnt_), None
-
-            # both half-steps add shard-local terms (the psum comes after
-            # the scan), so the carry is rows-varying from the first chunk:
-            # seed it that way or the scan's carry type changes mid-loop
-            acc0 = tuple(
-                lax.pcast(jnp.zeros(s, d_e.dtype), (_mesh.ROWS,),
-                          to="varying")
-                for s in ((nseg, n_f * n_f), (nseg, n_f), (nseg,)))
-            (a, b, cnts), _ = lax.scan(
-                body, acc0,
-                (seg_c.reshape(n_chunks, chunk),
-                 idx_c.reshape(n_chunks, chunk),
-                 d_p.reshape(n_chunks, chunk),
-                 w_p.reshape(n_chunks, chunk)))
-            if reduce_rows:               # cross-shard reduce: the ONE psum
-                a = lax.psum(a, _mesh.ROWS)
-                b = lax.psum(b, _mesh.ROWS)
-                cnts = lax.psum(cnts, _mesh.ROWS)
-            a = a.reshape(nseg, n_f, n_f)
-            # unobserved rows: A = λ·I, b = 0 → zero factors (harmless)
-            reg = lambda_ * jnp.maximum(cnts, 1.0)
-            a = a + reg[:, None, None] * eye
-            chol = jax.scipy.linalg.cho_factor(a)
-            return jax.scipy.linalg.cho_solve(chol, b[..., None])[..., 0]
-
-        def rmse(u, v):
-            pred = jnp.sum(u[tlr] * v[tcc], axis=1)
-            se = lax.psum(jnp.sum(tw * (pred - td) ** 2), _mesh.ROWS)
-            cnt_t = lax.psum(jnp.sum(tw), _mesh.ROWS)
-            return jnp.sqrt(se / jnp.maximum(cnt_t, 1.0))
+    def shard_fn(ulay, ilay, test, u0, v0, prev0):
+        td, tlr, tcc, tcnt = test
 
         def step(carry):
             u, v, prev_rmse, it, _, hist = carry
-            u = solve(lr_p, v, cc_p, m_local, False)   # users: shard-local
-            v = solve(cc_p, u, lr_p, n, True)          # items: psum-reduced
-            cur = rmse(u, v)
+            u = _user_step(ulay, user_classes, v, lambda_, u)
+            v, sse, cnt = _item_step(ilay, item_classes, u, lambda_, n)
+            if heldout:
+                cur = _heldout_rmse(u, v, td[0], tlr[0], tcc[0], tcnt[0])
+            else:
+                with jax.named_scope("dslib.als.rmse"):
+                    cur = jnp.sqrt(sse / jnp.maximum(cnt, 1.0))
             conv = jnp.abs(prev_rmse - cur) < tol
             return u, v, cur, it + 1, conv, hist.at[it].set(cur)
 
@@ -580,55 +897,42 @@ def _als_fit_sparse(data, lrows, cols, counts, tdata, tlrows, tcols, tcounts,
             _, _, _, it, conv, _ = carry
             return (it < max_iter) & (~conv)
 
-        if u0_s is None:
-            key = jax.random.PRNGKey(seed)
-            ku, kv = jax.random.split(key)
-            ku = jax.random.fold_in(ku, lax.axis_index(_mesh.ROWS))
-            u0 = jax.random.uniform(ku, (m_local, n_f), d_e.dtype)
-            v0 = jax.random.uniform(kv, (n, n_f), d_e.dtype)
-            prev0 = jnp.asarray(jnp.inf, d_e.dtype)
-        else:
-            u0 = u0_s
-            v0 = v0_r
-            prev0 = jnp.asarray(prev_r, d_e.dtype)
-        # vma: a fresh u0 is rows-varying via the fold_in of axis_index;
-        # v0/prev0 are replicated (same key / same scalar on every rank)
-        init = (u0, v0, prev0, jnp.int32(0), jnp.asarray(False),
-                jnp.zeros((max_iter,), d_e.dtype))
+        dt = v0.dtype
+        init = (u0, v0, jnp.asarray(prev0, dt), jnp.int32(0),
+                jnp.asarray(False), jnp.zeros((room,), dt))
         u, v, cur, n_iter, conv, hist = lax.while_loop(cond, step, init)
         return u, v, cur, n_iter, conv, hist
 
     from jax.sharding import PartitionSpec as P
-    row_spec = (P(_mesh.ROWS),) * 4
-    if init_state is None:
-        extra_specs = ()
-        args = ()
-    else:
-        u0, v0, prev0 = init_state
-        extra_specs = (P(_mesh.ROWS), P(), P())
-        args = (u0, v0, jnp.asarray(prev0))
-
-    def wrapper(*ops):
-        if init_state is None:
-            return shard_fn(*ops, None, None, None)
-        return shard_fn(*ops)
-
+    rows = P(_mesh.ROWS)
+    u0, v0, prev0 = init_state
     u, v, cur, n_iter, conv, hist = jax.shard_map(
-        wrapper, mesh=mesh,
-        in_specs=row_spec + row_spec + extra_specs,
-        out_specs=(P(_mesh.ROWS), P(), P(), P(), P(), P()),
+        shard_fn, mesh=mesh,
+        in_specs=(rows, rows, rows, rows, P(), P()),
+        out_specs=(rows, P(), P(), P(), P(), P()),
         check_vma=True,
-    )(data, lrows, cols, counts, tdata, tlrows, tcols, tcounts, *args)
+    )(user_layout, item_layout, test, u0, v0, jnp.asarray(prev0))
     # fused health vector — same program, zero extra dispatches
     from dislib_tpu.runtime import health as _health
     hvec = _health.health_vec(carries=(u, v), hist=hist, n_done=n_iter)
     return u, v, cur, n_iter, conv, hist, hvec
 
 
-# nnz chunk cap for the streamed normal-equation sums, and the element
-# budget for the (chunk, f²) intermediate (chunk·f² ≤ _SPARSE_BUDGET)
-_SPARSE_CHUNK = 1 << 18
-_SPARSE_BUDGET = 1 << 22
+@partial(_pjit, static_argnames=("m_pad", "n", "n_f", "mesh"),
+         name="als_start")
+def _als_start(items, seed, m_pad, n, n_f, mesh):
+    """The carries a sparse fit starts from: U zero (the first half-step
+    solves every user against V before U is read) and V the given
+    ``items`` or, where there are none, a uniform draw from ``seed``."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    if items is None:
+        items = jax.random.uniform(
+            jax.random.split(jax.random.PRNGKey(seed))[1], (n, n_f),
+            jnp.float32)
+    u0 = jnp.zeros((m_pad, n_f), jnp.float32)
+    return (lax.with_sharding_constraint(
+                u0, NamedSharding(mesh, P(_mesh.ROWS, None))),
+            lax.with_sharding_constraint(items, NamedSharding(mesh, P())))
 
 
 def _fold_in_body(vals, cols, items, lambda_, n_f, policy, top_n=0):
