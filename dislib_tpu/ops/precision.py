@@ -384,6 +384,24 @@ def pdot_tall(a, b, policy: Policy = FLOAT32):
             + (by_hi[:, n:2 * n] + by_mid[:, :n]) + by_hi[:, :n]
 
 
+def pdot_pattern(w, b):
+    """``w @ b`` for a 0/1 pattern ``w`` (m, rows), boolean or numeric,
+    and a float32 ``b`` (rows, n), to the last term of a 'highest'
+    contraction.  A 0 or a 1 is exact in bfloat16, so the parts of ``w``
+    are (w, 0, 0) and three of the six products are zero: what is left is
+    ``w @ b_hi + w @ b_mid + w @ b_lo``, laid along the contraction as ONE
+    bfloat16 GEMM 3·rows deep accumulated in float32, ``[w | w | w] @
+    [b_hi; b_mid; b_lo]``.  Nothing is dropped: it is the six-pass product
+    in half the passes (ALS's dense items: the items' rating pattern
+    against the users' packed outer products)."""
+    with jax.named_scope("dslib.pdot"):
+        w = (w != 0).astype(jnp.dtype(jnp.bfloat16))
+        return jnp.matmul(jnp.concatenate([w] * 3, axis=-1),
+                          jnp.concatenate(highest_parts(b), axis=-2),
+                          precision=ONE_PASS,
+                          preferred_element_type=jnp.dtype(jnp.float32))
+
+
 # Rows of a packed short contraction that lie together: one bfloat16
 # sublane tile.  A chunk is a whole number of them.
 SHORT_CHUNK = 16

@@ -22,7 +22,10 @@ TPU-native redesign:
   users and the items are sorted into length classes, each order of the
   entries is laid out once per array in windows of its class's length
   (`_plans`, cached on the ShardedSparse), and a block of segments' Grams
-  and moments are ONE batched product over its windows.  Users are solved
+  and moments are ONE batched product over its windows; the items rated
+  by at least ``_DENSE_SHARE`` of the users are in no window, their Grams
+  ONE GEMM a piece of users of their 0/1 rating pattern against the
+  users' packed outer products (``_dense_grams``).  Users are solved
   a block at a time (on a TPU a system a lane in VMEM,
   `pallas_kernels.chol_solve_lanes`); the items' products are summed
   over the shards and solved once whole.  O(nnz·f²) work, O(nnz) memory
@@ -563,6 +566,17 @@ _PIECE = 2048            # most entries one product contracts (PERF.md, s. 6)
 _BLOCK_ENTRIES = 1 << 18  # slots of a block
 _BLOCK_SEGMENTS = 2048   # most segments a block solves
 _RMSE_BLOCK = 1 << 19    # entries a block of a held-out RMSE pass reads
+# An item rated by at least this share of the users leaves the windows:
+# its normal equations come from a dense column of its ratings (0 where
+# unrated) against U's rows as they lie (_dense_grams).  Cost: a gathered
+# slot costs about 13.5 ns on a v5e, a (user, item) pair of the dense
+# GEMM 0.2-0.3 ns, so dense wins from a density of about 1/50.  Memory:
+# the column takes 4 bytes a user, a window about 9.6 bytes a rating (an
+# id and a rating a slot, a fifth of them pads); at 1/8 the 355 densest
+# items of the Netflix-shaped cell take 2.05 GB for the 1.24 GB of
+# windows they leave (PERF.md, s. 6).  A lower share pays more memory
+# than it saves time.
+_DENSE_SHARE = 1 / 8
 # where a block's systems are solved a system a lane in VMEM
 # (pallas_kernels.chol_solve_lanes); XLA's batched Cholesky elsewhere
 _LANE_BACKENDS = ("tpu",)
@@ -581,7 +595,7 @@ def _class_sizes(longest: int) -> tuple:
     return tuple(sizes)
 
 
-def _segment_plan(counts):
+def _segment_plan(counts, skip=None):
     """``(classes, tables)``: the blocking of one order of the entries,
     from the per-shard segment lengths ``counts`` (host (p, segments)).
     ``classes`` is static, ``((S, B, blocks, seg_off), ...)`` for each
@@ -590,10 +604,14 @@ def _segment_plan(counts):
     segment's id, first slot in the stream and length, in id order
     (padding columns: id = segments, so that their writes drop, length
     0).  Every shard gets the same shapes: a class's blocks are the most
-    any shard needs."""
+    any shard needs.  The segments where the boolean (segments,) ``skip``
+    is set count as empty: they are in no class, and the others keep
+    their first slots in the stream."""
     counts = np.asarray(counts, np.int64)
     p, nseg = counts.shape
     first = np.cumsum(counts, axis=1) - counts
+    if skip is not None:
+        counts = np.where(skip, 0, counts)
     sizes = _class_sizes(int(counts.max(initial=1)))
     cls = np.searchsorted(sizes, counts)
     classes, parts, seg_off = [], [], 0
@@ -627,18 +645,35 @@ def _plans(rep):
     the users, local rows for the items; a pad names the row past the
     table) and of the ratings, a shard's rows after another's.  Built from
     the order's tables, which are then dropped, as is ``col_major()``:
-    what stays is the two windowed copies of the entries."""
+    what stays is the two windowed copies of the entries.
+
+    The items rated by at least ``_DENSE_SHARE`` of the users (summed over
+    the shards, so every shard has the same set) are in no window: the
+    item layout ends in their dense part (``_dense_layout``), None where
+    no item reaches the share."""
     if "als" not in rep.plans:
         sh = jax.sharding.NamedSharding(rep.mesh,
                                         jax.sharding.PartitionSpec(_mesh.ROWS))
         users = rep.row_nnz
         users = np.concatenate(
             [users, np.zeros(rep.p * rep.m_local - users.shape[0], np.int64)])
-        ic, it = _segment_plan(rep.col_counts())
+        per_item = rep.col_counts()
+        heavy = per_item.sum(axis=0) >= _DENSE_SHARE * rep.shape[0]
+        ic, it = _segment_plan(per_item, skip=heavy)
         rows_t, vals_t = rep.col_major()
         items = _layout(rows_t, vals_t, jax.device_put(it, sh), ic, rep.mesh,
                         jnp.int32, rep.m_local)
         del rows_t, vals_t
+        dense = None
+        if heavy.any():
+            ids = np.flatnonzero(heavy).astype(np.int32)
+            at = np.full(rep.shape[1], ids.size, np.int32)
+            at[ids] = np.arange(ids.size)
+            dense = (jax.device_put(np.tile(ids, (rep.p, 1)), sh),
+                     *_dense_layout(rep.data, rep.lrows, rep.cols,
+                                    rep.counts_dev, jnp.asarray(at),
+                                    rep.mesh, rep.m_local, int(ids.size)))
+        items = (*items, dense)
         uc, ut = _segment_plan(users.reshape(rep.p, rep.m_local))
         # item ids as int16 where they and the pad fit: the largest copy
         # shrinks by a quarter
@@ -701,6 +736,131 @@ def _layout(other, vals, tab, classes, mesh, ids, pad):
                          check_vma=True)(other, vals, tab)
 
 
+@partial(_pjit, static_argnames=("mesh", "m_local", "h"),
+         name="als_dense_layout")
+def _dense_layout(data, lrows, cols, counts, at, mesh, m_local, h):
+    """The dense part of the item layout but the items' ids, ``(observed,
+    squares, ratings)`` a shard at a time: per dense item the number of
+    its nonzero ratings and their sum of squares, constants of the fit
+    that the dense Grams (``_dense_grams``) leave to the plan, and the
+    (h, m_local) ratings of the ``h`` dense items by the shard's users (0
+    where unrated; ``at`` (items,) is an item's row there, h for the
+    others), an item's users along the minor axis as the GEMM reads
+    them."""
+    from jax.sharding import PartitionSpec as P
+
+    def local(d_s, lr_s, cc_s, cnt_s):
+        d, lr, cc, cnt = d_s[0], lr_s[0], cc_s[0], cnt_s[0]
+        with jax.named_scope("dslib.als.layout"):
+            row = at[cc]
+            keep = (lax.iota(jnp.int32, d.shape[0]) < cnt) & (row < h)
+            flat = jnp.where(keep, row * m_local + lr, h * m_local)
+            r = _ops.varying_like(jnp.zeros((h * m_local,), d.dtype), d)
+            r = r.at[flat].set(d, mode="drop").reshape(h, m_local)
+            seen = jnp.sum(r != 0, axis=1).astype(d.dtype)
+            sq = _piece_sums(m_local, lambda start, piece, fresh: jnp.sum(
+                jnp.where(fresh, lax.dynamic_slice_in_dim(
+                    r, start, piece, axis=1), 0) ** 2, axis=1),
+                _ops.varying_like(jnp.zeros((h,), d.dtype), d))
+        return seen[None], sq[None], r
+
+    return jax.shard_map(local, mesh=mesh, in_specs=(P(_mesh.ROWS),) * 4,
+                         out_specs=(P(_mesh.ROWS),) * 3,
+                         check_vma=True)(data, lrows, cols, counts)
+
+
+def _piece_sums(n, body, zero):
+    """The sum over the pieces of ``_PIECE`` of ``n`` users of ``body(start,
+    piece, fresh)``, a pytree shaped like ``zero``: a ragged last piece
+    starts early, so that it ends with the users, and ``fresh`` (piece,)
+    marks the users no earlier piece has had.  Each piece's sums are
+    added after an ``optimization_barrier`` (which keeps XLA from folding
+    the pieces back into one product) in compensated sums, as
+    ``_block_grams`` adds its pieces: a float32 product that contracts
+    many same-signed terms reads low on the chip."""
+    piece = min(n, _PIECE)
+
+    def one(i, carry):
+        total, lost = carry
+        start = jnp.minimum(i * piece, n - piece)
+        fresh = start + lax.iota(jnp.int32, piece) >= i * piece
+        part = jax.tree.map(jnp.subtract, lax.optimization_barrier(
+            body(start, piece, fresh)), lost)
+        grown = jax.tree.map(jnp.add, total, part)
+        return grown, jax.tree.map(lambda g, t, p: (g - t) - p,
+                                   grown, total, part)
+
+    total, lost = lax.fori_loop(0, -(-n // piece), one, (zero, zero))
+    return jax.tree.map(jnp.subtract, total, lost)
+
+
+def _packed_outer(u):
+    """(rows, t): each row's outer product with itself, one triangle of
+    it and a little more (t and where each entry lies: ``_packed_index``),
+    a band of 8 columns at a time: the band's products with the columns
+    from its first on, ``u[:, a] * u[:, c]`` for a >= 8 i, c in [8 i,
+    8 i + 8).  A band's (rows, f - 8 i, 8) lies, with the rows on the
+    lanes as the chip holds them, a whole sublane tile to each of its
+    columns, so it flattens to (rows, 8 (f - 8 i)) as it lies, with no
+    gather and no relayout (5 392 columns for the triangle's 5 050 at
+    f = 100)."""
+    n_f = u.shape[1]
+    return jnp.concatenate(
+        [(u[:, c:, None] * u[:, None, c:c + 8]).reshape(u.shape[0], -1)
+         for c in range(0, n_f, 8)], axis=1)
+
+
+def _packed_index(n_f):
+    """(f, f) int32: where ``_packed_outer`` keeps each entry of the outer
+    product, (a, c) and (c, a) at the same column."""
+    out = np.zeros((n_f, n_f), np.int32)
+    at = 0
+    for c in range(0, n_f, 8):
+        w = min(8, n_f - c)
+        rows = np.arange(c, n_f)[:, None]
+        cols = np.arange(c, c + w)[None, :]
+        here = at + (rows - c) * w + (cols - c)
+        keep = rows >= cols
+        out[np.broadcast_to(rows, here.shape)[keep],
+            np.broadcast_to(cols, here.shape)[keep]] = here[keep]
+        at += (n_f - c) * w
+    return np.maximum(out, out.T), at
+
+
+def _dense_grams(dense, u):
+    """``(ids, n, g)`` of the dense items on this shard: their ids, numbers
+    of observed entries and (h, f + 1, f + 1) products of [U's rows,
+    rating] with themselves over the shard's users, from the dense part of
+    the item layout.  A is one GEMM of the items' 0/1 rating pattern
+    against the users' packed outer products (``precision.pdot_pattern``:
+    a 0/1 is exact in bfloat16, so three bfloat16 products carry every
+    term of the six-pass one), b a 'highest' product of the ratings with
+    U, Σr² and n the plan's.  Users are taken ``_PIECE`` at a time
+    (``_piece_sums``); a piece's rating pattern is derived from its own
+    slice of the ratings, and its outer products exist for that piece
+    alone."""
+    ids, seen, sq, r = dense
+    h, n_f = r.shape[0], u.shape[1]
+
+    def body(start, piece, fresh):
+        ub = lax.dynamic_slice_in_dim(u, start, piece)
+        rb = lax.optimization_barrier(
+            lax.dynamic_slice_in_dim(r, start, piece, axis=1))
+        rb = jnp.where(fresh, rb, 0)
+        return (px.pdot_pattern(rb, _packed_outer(ub)),
+                px.peinsum("hp,pf->hf", rb, ub, px.FLOAT32))
+
+    where, size = _packed_index(n_f)
+    a, b = _piece_sums(u.shape[0], body, tuple(
+        _ops.varying_like(jnp.zeros(shape, u.dtype), u)
+        for shape in ((h, size), (h, n_f))))
+    full = jnp.take(a, jnp.asarray(where.ravel()), axis=1)
+    g = jnp.concatenate([full.reshape(h, n_f, n_f), b[:, :, None]], axis=2)
+    g = jnp.concatenate(
+        [g, jnp.concatenate([b, sq[0][:, None]], axis=1)[:, None]], axis=1)
+    return ids[0], seen[0], g
+
+
 def _with_zero_row(factor):
     """``factor`` and one zero row after it: the row a layout's pads
     name."""
@@ -718,7 +878,7 @@ def _block_grams(layout, k, cls, i, factor):
     so the pieces are added after an ``optimization_barrier`` (which keeps
     XLA from folding them back into one product) in compensated sums."""
     size, b, _, seg_off = cls
-    ids, seen, others, vals = layout
+    ids, seen, others, vals = layout[:4]
     ids = lax.dynamic_slice_in_dim(ids, seg_off + i * b, b)
     n = lax.dynamic_slice_in_dim(seen, seg_off + i * b, b)
     o = lax.dynamic_slice_in_dim(others[k], i * b, b)
@@ -779,10 +939,11 @@ def _user_step(layout, classes, v, lambda_, u):
 
 def _item_step(layout, classes, u, lambda_, n, block=_BLOCK_SEGMENTS):
     """``(V, sse, count)`` from U: every item's product and number of
-    observed entries on each shard's own entries, the shards' partial
-    sums added by ONE psum, then the n solves a block of items at a time,
-    each block's squared training error (``_train_sse``) summed beside
-    them."""
+    observed entries on each shard's own entries (the windowed items' a
+    class block at a time, the dense items' in one pass over U's rows),
+    the shards' partial sums added by ONE psum, then the n solves a block
+    of items at a time, each block's squared training error
+    (``_train_sse``) summed beside them."""
     n_f = u.shape[1]
     uz = _with_zero_row(u)
     with jax.named_scope("dslib.als.items"):
@@ -795,6 +956,12 @@ def _item_step(layout, classes, u, lambda_, n, block=_BLOCK_SEGMENTS):
                     return (acc[0].at[ids].set(g, mode="drop"),
                             acc[1].at[ids].set(cnt, mode="drop"))
             acc = lax.fori_loop(0, cls[2], body, acc)
+        if layout[4] is not None:
+            with jax.named_scope("dslib.als.gram"), \
+                    jax.named_scope("dslib.als.dense"):
+                ids, cnt, g = _dense_grams(layout[4], u)
+                acc = (acc[0].at[ids].set(g, mode="drop"),
+                       acc[1].at[ids].set(cnt, mode="drop"))
         acc, seen = lax.psum(acc, _mesh.ROWS)
         b = min(block, n)
 
@@ -875,6 +1042,8 @@ def _als_fit_sparse(user_layout, item_layout, test, init_state, n, lambda_,
     count of a ShardedSparse; slots past the count are masked, so a
     poisoned pad cannot enter)."""
     _count_schedule("als_normal", "grouped")
+    _count_schedule("als_items", "gathered" if item_layout[4] is None
+                    else "dense")
     _count_schedule("als_solve", "lanes" if jax.default_backend()
                     in _LANE_BACKENDS else "xla")
 

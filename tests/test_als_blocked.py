@@ -72,8 +72,19 @@ def small_blocks(monkeypatch):
     jax.clear_caches()
 
 
-@pytest.mark.parametrize("rows", [1, 2], ids=["one_device", "psum_of_two"])
-def test_blocked_fit_matches_the_float64_oracle(small_blocks, rows):
+# (devices, dense share): the module's share (nearly every item of
+# _ratings goes dense), one above 1 (none does: every item is gathered)
+# and a quarter (14 items, item 3 among them, beside 25 gathered)
+_FITS = {"one_device": (1, None), "psum_of_two": (2, None),
+         "one_device-gathered": (1, 2.0), "psum_of_two-gathered": (2, 2.0),
+         "one_device-quarter": (1, 0.25), "psum_of_two-quarter": (2, 0.25)}
+
+
+@pytest.mark.parametrize("rows, share", list(_FITS.values()), ids=list(_FITS))
+def test_blocked_fit_matches_the_float64_oracle(small_blocks, monkeypatch,
+                                                rows, share):
+    if share is not None:
+        monkeypatch.setattr(als_mod, "_DENSE_SHARE", share)
     ds.init((rows, 1), devices=jax.devices()[:rows])
     r = _ratings()
     f, lam = 5, 0.1
@@ -88,7 +99,61 @@ def test_blocked_fit_matches_the_float64_oracle(small_blocks, rows):
     np.testing.assert_allclose(als.history_, hist, rtol=2e-5)
     assert als.rmse_ == pytest.approx(hist[-1], rel=2e-5)
     assert not als.users_[7].any() and not als.items_[11].any()
-    assert profiling.schedule_counters()["als_normal:grouped"] >= 1
+    counters = profiling.schedule_counters()
+    assert counters["als_normal:grouped"] >= 1
+    dense = (r != 0).sum(axis=0) >= als_mod._DENSE_SHARE * r.shape[0]
+    assert dense[3] != (share == 2.0)
+    assert share != 0.25 or 3 < dense.sum() < r.shape[1] // 2
+    route = "dense" if dense.any() else "gathered"
+    assert counters[f"als_items:{route}"] >= 1
+    assert not counters.get("als_items:" + ({"dense", "gathered"} - {
+        route}).pop())
+
+
+@pytest.mark.parametrize("rows", [1, 2], ids=["one_device", "two_shards"])
+def test_every_rating_sits_once_in_a_window_or_the_dense_part(
+        small_blocks, monkeypatch, rows):
+    """The items rated by at least the share of the users are exactly the
+    dense ones, their segments are in no class of the item layout, and
+    every rating sits once in a window or in the dense ratings, with the
+    plan's counts and sums of squares beside them."""
+    monkeypatch.setattr(als_mod, "_DENSE_SHARE", 0.25)
+    ds.init((rows, 1), devices=jax.devices()[:rows])
+    r = _ratings()
+    rows_, cols_ = np.nonzero(r)
+    rep = ShardedSparse.build(rows_, cols_, r[rows_, cols_], r.shape)
+    _, ic, _, items = als_mod._plans(rep)
+    want = np.flatnonzero((r != 0).sum(axis=0) >= 0.25 * r.shape[0])
+    assert 3 < want.size < r.shape[1] // 2 and 3 in want
+    seg_ids, _, others, vals, dense = items
+    ids, seen, sq, dr = (np.asarray(a) for a in dense)
+    for s in range(rows):
+        np.testing.assert_array_equal(ids[s], want)
+    seg_ids = np.asarray(seg_ids).reshape(rows, -1)
+    ml = rep.m_local
+    got = []
+    for k, (size, b, blocks, seg_off) in enumerate(ic):
+        o = np.asarray(others[k]).reshape(rows, blocks * b, size)
+        v = np.asarray(vals[k]).reshape(rows, blocks * b, size)
+        for s in range(rows):
+            seg = seg_ids[s, seg_off:seg_off + blocks * b]
+            assert not np.isin(seg, want).any()
+            for j, item in enumerate(seg):
+                live = v[s, j] != 0
+                assert (o[s, j, ~live] == ml).all()
+                got += [(s * ml + int(lr), int(item), float(x))
+                        for lr, x in zip(o[s, j, live], v[s, j, live])]
+    dr = dr.reshape(rows, want.size, ml)
+    for s in range(rows):
+        k, lr = np.nonzero(dr[s])
+        got += [(s * ml + int(a), int(want[c]), float(dr[s, c, a]))
+                for c, a in zip(k, lr)]
+        mine = r[s * ml:(s + 1) * ml][:, want]
+        np.testing.assert_array_equal(seen[s], (mine != 0).sum(axis=0))
+        np.testing.assert_allclose(sq[s], (mine.astype(np.float64) ** 2)
+                                   .sum(axis=0), rtol=1e-6)
+    assert sorted(got) == sorted(zip(rows_.tolist(), cols_.tolist(),
+                                     r[rows_, cols_].tolist()))
 
 
 def test_the_plan_covers_every_segment_once_in_its_class(small_blocks):
@@ -144,7 +209,9 @@ def test_col_major_sorts_each_shard_by_column(rng):
 
 def test_the_lane_solve_is_the_xla_solve(small_blocks, monkeypatch):
     """The chip's route (a system a lane, pallas_kernels.chol_solve_lanes,
-    interpreted here) gives the fit the CPU's XLA Cholesky gives."""
+    interpreted here) gives the fit the CPU's XLA Cholesky gives, both
+    solving the products of the windowed items' route."""
+    monkeypatch.setattr(als_mod, "_DENSE_SHARE", 2.0)
     r = _ratings(seed=5, m=150)
     v0 = np.random.default_rng(2).random((r.shape[1], 6)).astype(np.float32)
     x = SparseArray.from_scipy(sp.csr_matrix(r))
@@ -197,3 +264,43 @@ def test_the_compiled_fit_holds_no_users_by_f_squared(monkeypatch):
     assert nnz * 12 < 16e6
     assert mem.temp_size_in_bytes < nnz * 12, mem.temp_size_in_bytes
     assert m * f * f * 4 > 3 * nnz * 12
+
+
+def test_the_compiled_dense_pass_holds_no_users_by_packed_outer(monkeypatch):
+    """The fit compiled at 50 000 users x 400 items, f = 16, with 12 items
+    that every second user rated: the dense pass's temporaries hold a
+    piece of users' packed outer products at a time, and no (users, f (f
+    + 1) / 2) or (users, dense items, f) array, each of which alone would
+    take more than all the temporaries."""
+    monkeypatch.setattr(als_mod, "_BLOCK_ENTRIES", 8192)
+    monkeypatch.setattr(als_mod, "_BLOCK_SEGMENTS", 256)
+    ds.init((1, 1), devices=jax.devices()[:1])
+    m, n, f, h = 50_000, 400, 16, 12
+    rng = np.random.default_rng(1)
+    counts = np.clip(np.exp(rng.normal(np.log(16), 1.0, m)), 1, n - h)
+    counts = counts.astype(np.int64)
+    rows = np.repeat(np.arange(m), counts)
+    cols = h + (rng.integers(0, n - h, rows.size) + np.arange(rows.size)) \
+        % (n - h)
+    heavy = rng.random((m, h)) < 0.5
+    hr, hc = np.nonzero(heavy)
+    rows, cols = np.concatenate([rows, hr]), np.concatenate([cols, hc])
+    rep = ShardedSparse.build(rows, cols, np.ones(rows.size, np.float32),
+                              (m, n))
+    uc, ic, users, items = als_mod._plans(rep)
+    assert np.asarray(items[4][0]).tolist() == [list(range(h))]
+    test = (rep.data, rep.lrows, rep.cols, rep.counts_dev)
+    start = als_mod._als_start(None, 0, rep.p * rep.m_local, n, f, rep.mesh)
+    compiled = als_mod._als_fit_sparse.lower(
+        users, items, test, (*start, np.inf), n, 0.065, 0.0, 3, 4,
+        rep.mesh, uc, ic, False).compile()
+    packed = f * (f + 1) // 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < m * packed * 4 and temp < m * h * f * 4, temp
+    text = compiled.as_text()
+    assert f"[{h},{rep.m_local}]" in text      # the dense ratings, whole
+    ml, width = rep.m_local, als_mod._packed_index(f)[1]
+    for shape in (f"[{ml},{packed}]", f"[{packed},{ml}]", f"[{ml},{width}]",
+                  f"[{width},{ml}]", f"[{ml},{h},{f}]", f"[{ml},{f},{h}]",
+                  f"[{h},{ml},{f}]"):
+        assert shape not in text, shape

@@ -533,3 +533,29 @@ class TestPadTailHygiene:
         s_clean = np.asarray(ds.svd(a, compute_uv=False).collect())
         s_bad = np.asarray(ds.svd(bad, compute_uv=False).collect())
         np.testing.assert_array_equal(s_clean, s_bad)
+
+
+class TestPattern:
+    """``pdot_pattern``: a 0/1 pattern's product with a float32 operand,
+    three bfloat16 products that carry every term of the six-pass one."""
+
+    @pytest.mark.parametrize("kind", ["ratings", "boolean"])
+    def test_matches_float64_of_the_pattern(self, rng, kind):
+        import jax
+        r = rng.randint(0, 6, (9, 700)).astype(np.float32)
+        r[r < 3] = 0
+        w = r if kind == "ratings" else r != 0
+        b = (rng.standard_normal((700, 33)) * 1e3).astype(np.float32)
+        got = np.asarray(jax.jit(px.pdot_pattern)(w, b))
+        want = (r != 0).astype(np.float64) @ b.astype(np.float64)
+        scale = np.abs(b.astype(np.float64)).sum(axis=0)
+        assert got.dtype == np.float32
+        assert (np.abs(got - want) <= 1e-6 * scale).all()
+
+    def test_parts_of_b_below_bfloat16_are_kept(self):
+        """A b whose value lies below bfloat16's eight bits: a one-pass
+        bfloat16 product would lose it, the three parts do not."""
+        import jax
+        b = np.full((4, 2), 1.0 + 2.0 ** -12, np.float32)
+        got = np.asarray(jax.jit(px.pdot_pattern)(np.ones((1, 4), bool), b))
+        np.testing.assert_array_equal(got, np.full((1, 2), 4 * b[0, 0]))
